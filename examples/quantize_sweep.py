@@ -15,12 +15,14 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.data.pipeline import DataSpec, batch_at
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.models.config import QuantConfig
 from repro.train.trainer import TrainConfig, Trainer
 
 
 def main():
+    enable_compile_cache()
     cfg = get_config("llama3-8b").reduced(
         n_layers=2, d_model=128, n_heads=4, n_kv_heads=2, d_head=32,
         d_ff=256, vocab=512)

@@ -16,7 +16,9 @@ import numpy as np
 
 from repro.core import bipolar
 from repro.kernels import ops, ref
+from repro.launch.compile_cache import enable_compile_cache
 
+enable_compile_cache()
 rng = np.random.default_rng(0)
 M, N, K = 64, 96, 300   # deliberately unaligned: pad correction in action
 

@@ -44,6 +44,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.models.config import QuantConfig
 from repro.serving import engine as E
@@ -94,6 +95,7 @@ def serve(params, cfg, prompts, quant, new_tokens, *, paged=False,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--new-tokens", type=int, default=12)
     ap.add_argument("--paged", action="store_true",

@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.data.pipeline import DataSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim.optimizer import AdamWConfig
 from repro.train.trainer import (SimulatedPreemption, TrainConfig, Trainer)
 
@@ -23,6 +24,7 @@ CKPT = "/tmp/repro_example_wsd"
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     args = ap.parse_args()

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.kernels.compat import shard_map
 
 
 def compressed_psum(tree, axis_name: str, *, bits: int = 8):
@@ -66,7 +65,7 @@ def dp_train_step(loss_fn, mesh: Mesh, *, axis_name: str = "data",
     pspec = P()          # params replicated
     bspec = P(axis_name)  # batch sharded on leading dim
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(pspec, bspec),
         out_specs=(pspec, pspec),

@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.kernels.compat import shard_map
 
 
 def pipeline_apply(stage_fn, n_stages: int, n_micro: int, axis: str = "pipe"):
@@ -68,7 +67,7 @@ def pipeline_apply(stage_fn, n_stages: int, n_micro: int, axis: str = "pipe"):
         return outs
 
     def run(mesh: Mesh, stage_params, x):
-        f = shard_map(
+        f = jax.shard_map(
             per_stage, mesh=mesh,
             in_specs=(P(axis), P()),
             out_specs=P(),

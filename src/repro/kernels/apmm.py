@@ -69,30 +69,58 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import bipolar
-from repro.kernels import compat, ref
+from repro.kernels import ref
 
-# Default tile sizes: MXU-aligned (multiples of 128 on the GEMM dims) and
-# sized so packed tiles + unpacked int8 tiles + the int32 accumulator fit
-# v5e VMEM (~128 MiB) with double buffering:
-#   packed A/B      n * 256 * (512/32) * 4 B  = n * 16 KiB each
-#   unpacked int8   2 * 256 * 512             = 256 KiB
-#   acc int32       256 * 256 * 4             = 256 KiB (x n_a*n_b bitserial)
+# Default tile sizes: MXU-aligned (multiples of 128 on the GEMM dims).
+# The reduction axis is never tiled: every kernel takes the whole packed
+# row of ``Kw`` words as one block (the block's last dim then equals the
+# array's, which Mosaic accepts for any ``Kw``), and walks K inside the
+# kernel one bit position at a time (:func:`_bits`).
 DEFAULT_BM = 256
 DEFAULT_BN = 256
-DEFAULT_BK = 512
+
+# Scoped-VMEM limit for the APMM kernels.  v5e has 128 MiB of VMEM per
+# core; Mosaic's default scoped limit (16 MiB on v5e) is below what a
+# whole-K block needs at prefill.  StableLM-3B's largest case, bm = bn =
+# 256, K = 6912 (Kw = 216, padded to 256 lanes), 8-bit weights, dual
+# gate/up, all blocks double-buffered:
+#   x row block      2 * 32 * 256 * 256 * 2 B (bf16)     =  8 MiB
+#   weight planes    2 * 2 * 8 * 256 * 256 * 4 B         =  8 MiB
+#   out + residual   2 * 2 * 256 * 256 * 2 B             = 0.5 MiB
+#   accumulators     2 * 256 * 256 * 4 B (fused)         = 0.5 MiB
+#                    (bitserial: n_a * n_b = 64x that = 32 MiB)
+#   per-bit temps    ~4 * 8 * 256 * 256 * 4 B            =  8 MiB
+# K = 14336 (llama3-8b's down projection) doubles the first, second and
+# last lines.  100 MiB covers both with the bitserial accumulators and
+# keeps 28 MiB of VMEM for the surrounding XLA program.
+VMEM_LIMIT_BYTES = 100 * 2**20
 
 _NT = (((1,), (1,)), ((), ()))  # contract last dims (A @ B^T)
 
 
-def _unpack(p, n_bits: int, r: int, bk: int):
-    """(n, r, bk//32) uint32 -> (n, r, bk) int32 bit planes in {0,1}.
+def _bits(words, b):
+    """Bit ``b`` of every packed word: ``(n, r, Kw)`` uint32 -> ``(n, r,
+    Kw)`` int32 planes in {0,1}.
 
-    Element k = 32*w + b of a row is bit b of word w: unpack the 32 bits of
-    each word onto a trailing axis and merge it with the word axis.
+    Word ``w`` holds K elements ``32*w .. 32*w + 31`` (bit ``i`` is
+    element ``32*w + i``), so bit ``b`` of every word is the K subset
+    ``{32*w + b}``.  The kernels contract K one such subset at a time
+    (a ``fori_loop`` over ``b``): integer products summed in any order
+    are the same sum, the planes keep the packed layout (no lane
+    relayout), and the loop body -- hence Mosaic's compile time -- stays
+    small whatever K is.
     """
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, 1, 32), 3)
-    bits = (p[..., None] >> shifts) & jnp.uint32(1)        # (n, r, bk32, 32)
-    return bits.reshape(n_bits, r, bk).astype(jnp.int32)
+    return ((words >> b.astype(jnp.uint32)) & jnp.uint32(1)).astype(jnp.int32)
+
+
+def _bit_major(x):
+    """``(..., R, Kp)`` -> ``(..., 32, R, Kp/32)`` with ``[..., b, r, w] =
+    x[..., r, 32*w + b]``: the float activation columns each
+    :func:`_bits` step contracts, as one contiguous slab per bit."""
+    *lead, r, kp = x.shape
+    x = x.reshape(*lead, r, kp // bipolar.PACK_WIDTH, bipolar.PACK_WIDTH)
+    nd = x.ndim
+    return jnp.moveaxis(x, nd - 1, nd - 3)
 
 
 def _recover_int8(planes, lo: int, size: int):
@@ -108,85 +136,106 @@ def _recover_int8(planes, lo: int, size: int):
     return (acc - bipolar.max_value(size)).astype(jnp.int8)
 
 
-def _kernel(ap_ref, bp_ref, as_ref, bs_ref, out_ref, acc_ref, *,
-            n_a: int, n_b: int, bm: int, bn: int, bk: int,
-            n_pad: int, variant: str, dequant: bool):
-    k_idx = pl.program_id(2)
-    n_k = pl.num_programs(2)
+def _accumulate(a_u, bpl, acc_ref, *, n_a: int, n_b: int, variant: str):
+    """Add one bit position's products to the accumulator(s).
 
-    ap = _unpack(ap_ref[...], n_a, bm, bk)         # {0,1} int32
-    bp = _unpack(bp_ref[...], n_b, bn, bk)
-
+    ``a_u`` is the activations' unsigned bit field ``(bm, Kw)`` (one
+    integer per K element of the subset), ``bpl`` the weight planes
+    ``(n_b, bn, Kw)`` in {0,1}.  ``fused``: operand-level recovery, one
+    int8 MXU GEMM per <=7-bit plane-group pair.  ``bitserial``: one GEMM
+    per bit pair into its own accumulator (paper §3.2/§4.2)."""
     if variant == "fused":
-        # Operand-level recovery: one int8 MXU GEMM per <=7-bit plane-group
-        # pair (a single GEMM for the common n <= 7 case).
-        @pl.when(k_idx == 0)
-        def _init():
-            acc_ref[...] = jnp.full(
-                (bm, bn),
-                n_pad * bipolar.max_value(n_a) * bipolar.max_value(n_b),
-                jnp.int32)
-
         for lo_a, sz_a in ref.plane_groups(n_a):
-            a8 = _recover_int8(ap, lo_a, sz_a)     # (bm, bk) int8
+            mask = (1 << sz_a) - 1
+            va = ((((a_u >> lo_a) & mask) << 1)
+                  - bipolar.max_value(sz_a)).astype(jnp.int8)
             for lo_b, sz_b in ref.plane_groups(n_b):
-                b8 = _recover_int8(bp, lo_b, sz_b)  # (bn, bk) int8
+                b8 = _recover_int8(bpl, lo_b, sz_b)
                 y = jax.lax.dot_general(
-                    a8, b8, _NT, preferred_element_type=jnp.int32)
+                    va, b8, _NT, preferred_element_type=jnp.int32)
                 acc_ref[...] += y << (lo_a + lo_b)
     else:
-        # Paper-faithful §3.2: one GEMM per bit pair; per-pair accumulators
-        # live in VMEM scratch ("recovery in shared memory", §4.2).
-        @pl.when(k_idx == 0)
-        def _init():
-            acc_ref[...] = jnp.full((n_a * n_b, bm, bn), n_pad, jnp.int32)
-
         for i in range(n_a):                        # §4.2 ④ loop order:
-            a8 = (2 * ap[i] - 1).astype(jnp.int8)   # one A plane ...
+            a8 = (((a_u >> i) & 1) * 2 - 1).astype(jnp.int8)   # one A plane
             for j in range(n_b):                    # ... x all B planes
-                b8 = (2 * bp[j] - 1).astype(jnp.int8)
+                b8 = (2 * bpl[j] - 1).astype(jnp.int8)
                 acc_ref[i * n_b + j] += jax.lax.dot_general(
                     a8, b8, _NT, preferred_element_type=jnp.int32)
 
-    @pl.when(k_idx == n_k - 1)
-    def _finish():
-        if variant == "fused":
-            y = acc_ref[...]
-        else:
-            # Shift-add recovery Y = sum_ij 2^{i+j} Y^(ij)  (paper Fig. 2).
-            y = jnp.zeros((bm, bn), jnp.int32)
-            for i in range(n_a):
-                for j in range(n_b):
-                    y = y + (acc_ref[i * n_b + j] << (i + j))
-        if dequant:
-            yf = y.astype(jnp.float32) * as_ref[...] * bs_ref[...]
-            out_ref[...] = yf.astype(out_ref.dtype)
-        else:
-            out_ref[...] = y
+
+def _init_acc(n_a: int, n_b: int, n_pad: int, variant: str, bm: int,
+              bn: int):
+    """Accumulator start value: the closed-form K-pad correction."""
+    if variant == "fused":
+        return jnp.full(
+            (bm, bn), n_pad * bipolar.max_value(n_a) * bipolar.max_value(n_b),
+            jnp.int32)
+    return jnp.full((n_a * n_b, bm, bn), n_pad, jnp.int32)
+
+
+def _recover_acc(acc_ref, n_a: int, n_b: int, variant: str):
+    """Accumulator(s) -> the int32 product ``Y``.  Bitserial: shift-add
+    recovery ``Y = sum_ij 2^{i+j} Y^(ij)`` (paper Fig. 2)."""
+    if variant == "fused":
+        return acc_ref[...]
+    y = acc_ref[0]
+    for i in range(n_a):
+        for j in range(n_b):
+            if i or j:
+                y = y + (acc_ref[i * n_b + j] << (i + j))
+    return y
+
+
+def _kernel(ap_ref, bp_ref, as_ref, bs_ref, out_ref, acc_ref, *,
+            n_a: int, n_b: int, bm: int, bn: int, n_pad: int,
+            variant: str, dequant: bool):
+    acc_ref[...] = _init_acc(n_a, n_b, n_pad, variant, bm, bn)
+
+    def bit_step(b, carry):
+        apl = _bits(ap_ref[...], b)
+        a_u = apl[0]                    # planes -> unsigned bit field
+        for i in range(1, n_a):
+            a_u = a_u + (apl[i] << i)
+        _accumulate(a_u, _bits(bp_ref[...], b), acc_ref, n_a=n_a, n_b=n_b,
+                    variant=variant)
+        return carry
+
+    jax.lax.fori_loop(0, bipolar.PACK_WIDTH, bit_step, 0)
+    y = _recover_acc(acc_ref, n_a, n_b, variant)
+    if dequant:
+        yf = y.astype(jnp.float32) * as_ref[...] * bs_ref[...]
+        out_ref[...] = yf.astype(out_ref.dtype)
+    else:
+        out_ref[...] = y
 
 
 # ---------------------------------------------------------------------------
 # One-kernel quantized linear: quantize-pack prologue + epilogue in VMEM
 # ---------------------------------------------------------------------------
 
-def _quantize_tile(x, s, n_a: int, k_lo, k_orig: int):
-    """Float tile ``(bm, bk)`` + per-row scale ``(bm, 1)`` -> unsigned
-    bipolar bit field (int32) -- the §4.1 quantize + encode performed in
-    VMEM (same math as :mod:`repro.kernels.pack`).  Columns at absolute
-    index >= ``k_orig`` (K padding) are forced to the all-zero-bit value
-    ``-maxv``, matching the activation pad-bit-0 convention of the
-    closed-form pad correction."""
+def _quantize_tile(x, s, n_a: int, col, k_orig: int):
+    """Float tile + per-row scale ``(rows, 1)`` -> unsigned bipolar bit
+    field (int32) -- the §4.1 quantize + encode performed in VMEM (same
+    math as :mod:`repro.kernels.pack`), dividing in f32.  ``col`` holds
+    each column's absolute K index; columns at ``>= k_orig`` (K padding)
+    are forced to the all-zero-bit value ``-maxv``, matching the
+    activation pad-bit-0 convention of the closed-form pad correction."""
     q = bipolar.quantize_values(x.astype(jnp.float32), n_a, s)
-    col = k_lo + jax.lax.broadcasted_iota(jnp.int32, q.shape, 1)
     q = jnp.where(col < k_orig, q, -bipolar.max_value(n_a))
     return bipolar.encode(q, n_a)
+
+
+def _word_base(rows: int, kw: int):
+    """``(rows, Kw)`` int32: absolute K index of bit 0 of each word."""
+    return bipolar.PACK_WIDTH * jax.lax.broadcasted_iota(
+        jnp.int32, (rows, kw), 1)
 
 
 _apply_act = ref.apply_act
 
 
 def _fused_linear_kernel(*refs, n_a: int, n_b: int, bm: int, bn: int,
-                         bk: int, k_orig: int, n_pad: int, variant: str,
+                         kw: int, k_orig: int, n_pad: int, variant: str,
                          act: str, dual: bool, has_bias: bool,
                          has_res: bool):
     it = iter(refs)
@@ -199,80 +248,50 @@ def _fused_linear_kernel(*refs, n_a: int, n_b: int, bm: int, bn: int,
     out_ref = next(it)
     accs = list(it)                       # 1 or 2 scratch accumulators
 
-    k_idx = pl.program_id(2)
-    n_k = pl.num_programs(2)
-
-    # -- prologue: quantize + bit-decompose the float A tile in VMEM -----
-    # x_ref holds the whole-K row block (index map depends only on i), so
-    # the float activations stream from HBM ONCE per M tile -- not once
-    # per (j, k) grid cell -- and the quantize recompute is VPU-only
-    xk = x_ref[:, pl.dslice(k_idx * bk, bk)]
-    ua = _quantize_tile(xk, as_ref[...], n_a, k_idx * bk, k_orig)
-
-    @pl.when(k_idx == 0)
-    def _init():
-        if variant == "fused":
-            init = jnp.full(
-                (bm, bn),
-                n_pad * bipolar.max_value(n_a) * bipolar.max_value(n_b),
-                jnp.int32)
-        else:
-            init = jnp.full((n_a * n_b, bm, bn), n_pad, jnp.int32)
-        for aref in accs:
-            aref[...] = init
-
+    init = _init_acc(n_a, n_b, n_pad, variant, bm, bn)
+    for aref in accs:
+        aref[...] = init
     streams = [(bp_ref, accs[0])] + ([(bp2_ref, accs[1])] if dual else [])
-    for bref, aref in streams:
-        bpl = _unpack(bref[...], n_b, bn, bk)
-        if variant == "fused":
-            for lo_a, sz_a in ref.plane_groups(n_a):
-                mask = (1 << sz_a) - 1
-                va = ((((ua >> lo_a) & mask) << 1)
-                      - bipolar.max_value(sz_a)).astype(jnp.int8)
-                for lo_b, sz_b in ref.plane_groups(n_b):
-                    b8 = _recover_int8(bpl, lo_b, sz_b)
-                    y = jax.lax.dot_general(
-                        va, b8, _NT, preferred_element_type=jnp.int32)
-                    aref[...] += y << (lo_a + lo_b)
-        else:
-            for i in range(n_a):
-                a8 = (((ua >> i) & 1) * 2 - 1).astype(jnp.int8)
-                for j in range(n_b):
-                    b8 = (2 * bpl[j] - 1).astype(jnp.int8)
-                    aref[i * n_b + j] += jax.lax.dot_general(
-                        a8, b8, _NT, preferred_element_type=jnp.int32)
+    base = _word_base(bm, kw)
 
-    @pl.when(k_idx == n_k - 1)
-    def _finish():
-        od = out_ref.dtype
+    def bit_step(b, carry):
+        # prologue: quantize + bit-decompose this bit position's float
+        # activation columns in VMEM.  x_ref holds the whole-K row block
+        # (index map depends only on i), so the float activations stream
+        # from HBM ONCE per M tile -- not once per N tile -- and the
+        # quantize recompute is VPU-only
+        a_u = _quantize_tile(x_ref[b], as_ref[...], n_a, base + b, k_orig)
+        for bref, aref in streams:
+            _accumulate(a_u, _bits(bref[...], b), aref, n_a=n_a, n_b=n_b,
+                        variant=variant)
+        return carry
 
-        def recover_acc(aref):
-            if variant == "fused":
-                return aref[...]
-            y = jnp.zeros((bm, bn), jnp.int32)
-            for i in range(n_a):
-                for j in range(n_b):
-                    y = y + (aref[i * n_b + j] << (i + j))
-            return y
+    jax.lax.fori_loop(0, bipolar.PACK_WIDTH, bit_step, 0)
 
-        # epilogue stages round-trip through out_dtype exactly where the
-        # unfused composition casts, so fused == unfused bitwise
-        yf = recover_acc(accs[0]).astype(jnp.float32) \
-            * as_ref[...] * bs_ref[...]
-        if has_bias:
-            yf = yf + bias_ref[...]
-        yo = yf.astype(od)
-        if dual:
-            y2 = recover_acc(accs[1]).astype(jnp.float32) \
-                * as_ref[...] * b2s_ref[...]
-            h = _apply_act(yo.astype(jnp.float32), act) \
-                * y2.astype(od).astype(jnp.float32)
-            yo = h.astype(od)
-        elif act != "none":
-            yo = _apply_act(yo.astype(jnp.float32), act).astype(od)
-        if has_res:
-            yo = yo + res_ref[...]
-        out_ref[...] = yo
+    # epilogue stages round-trip through out_dtype exactly where the
+    # unfused composition casts, so fused == unfused bitwise
+    od = out_ref.dtype
+    yf = _recover_acc(accs[0], n_a, n_b, variant).astype(jnp.float32) \
+        * as_ref[...] * bs_ref[...]
+    if has_bias:
+        yf = yf + bias_ref[...]
+    yo = yf.astype(od)
+    if dual:
+        y2 = _recover_acc(accs[1], n_a, n_b, variant).astype(jnp.float32) \
+            * as_ref[...] * b2s_ref[...]
+        h = _apply_act(yo.astype(jnp.float32), act) \
+            * y2.astype(od).astype(jnp.float32)
+        yo = h.astype(od)
+    elif act != "none":
+        yo = _apply_act(yo.astype(jnp.float32), act).astype(od)
+    if has_res:
+        yo = yo + res_ref[...]
+    out_ref[...] = yo
+
+
+def _check_tiles(m: int, n: int, bm: int, bn: int) -> None:
+    if m % bm or n % bn:
+        raise ValueError(f"({m},{n}) not tiled by ({bm},{bn})")
 
 
 @functools.partial(
@@ -283,14 +302,14 @@ def apmm_fused_linear(x: jax.Array, a_scale: jax.Array, bp: jax.Array,
                       b_scale, *, bp2=None, b2_scale=None, bias=None,
                       residual=None, n_a: int, n_b: int, k_orig: int,
                       variant: str = "fused", act: str = "none",
-                      block: tuple = (DEFAULT_BM, DEFAULT_BN, DEFAULT_BK),
+                      block: tuple = (DEFAULT_BM, DEFAULT_BN),
                       out_dtype=jnp.float32,
                       interpret: bool = False) -> jax.Array:
     """One-kernel quantized linear ``Y = epilogue(Q(X) @ B^T)``.
 
     Args:
-      x: ``(M, Kp)`` float activations (K already padded to the packed
-        word width; pad columns are masked in-kernel).
+      x: ``(M, Kw*32)`` float activations (K padded to the packed word
+        width; pad columns are masked in-kernel).
       a_scale: ``(M, 1)`` f32 per-row activation scales.
       bp: ``(n_b, N, Kw)`` uint32 packed weight planes (pad bit 1).
       b_scale: ``(N, 1)`` f32 per-output-channel weight scales.
@@ -303,20 +322,16 @@ def apmm_fused_linear(x: jax.Array, a_scale: jax.Array, bp: jax.Array,
         activation, in out_dtype arithmetic.
       act: "none" | "silu" | "gelu" epilogue activation.
       k_orig: unpadded reduction length.
+      block: ``(bm, bn)`` output tile; K is never tiled (see
+        :func:`_bits`).
 
     Shapes must tile exactly (:mod:`repro.kernels.ops` pads and unpads).
     """
     m, kp = x.shape
     n_b_, n, kw = bp.shape
     assert n_b_ == n_b and kp == kw * bipolar.PACK_WIDTH, (x.shape, bp.shape)
-    bm, bn, bk = block
-    bm, bn = min(bm, m), min(bn, n)
-    bk = min(bk, kp)
-    if bk % bipolar.PACK_WIDTH:
-        raise ValueError(f"bk={bk} must be a multiple of {bipolar.PACK_WIDTH}")
-    if m % bm or n % bn or kp % bk:
-        raise ValueError(f"({m},{n},{kp}) not tiled by ({bm},{bn},{bk})")
-    bk32 = bk // bipolar.PACK_WIDTH
+    bm, bn = min(block[0], m), min(block[1], n)
+    _check_tiles(m, n, bm, bn)
     dual = bp2 is not None
     if dual:
         assert b2_scale is not None and bp2.shape == bp.shape, \
@@ -324,43 +339,44 @@ def apmm_fused_linear(x: jax.Array, a_scale: jax.Array, bp: jax.Array,
     a_scale = a_scale.reshape(m, 1).astype(jnp.float32)
     b_scale = b_scale.reshape(1, n).astype(jnp.float32)
 
-    operands = [x, a_scale, bp, b_scale]
+    operands = [_bit_major(x), a_scale, bp, b_scale]
     in_specs = [
         # whole-K row block, re-fetched only when i changes: activations
         # cost M*K*itemsize of HBM traffic total, independent of N/bn
-        pl.BlockSpec((bm, kp), lambda i, j, k: (i, 0)),
-        pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
-        pl.BlockSpec((n_b, bn, bk32), lambda i, j, k: (0, j, k)),
-        pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
+        pl.BlockSpec((bipolar.PACK_WIDTH, bm, kw), lambda i, j: (0, i, 0)),
+        pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
+        pl.BlockSpec((n_b, bn, kw), lambda i, j: (0, j, 0)),
+        pl.BlockSpec((1, bn), lambda i, j: (0, j)),
     ]
     if dual:
         operands += [bp2, b2_scale.reshape(1, n).astype(jnp.float32)]
         in_specs += [
-            pl.BlockSpec((n_b, bn, bk32), lambda i, j, k: (0, j, k)),
-            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
+            pl.BlockSpec((n_b, bn, kw), lambda i, j: (0, j, 0)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
         ]
     if bias is not None:
         operands.append(bias.reshape(1, n).astype(jnp.float32))
-        in_specs.append(pl.BlockSpec((1, bn), lambda i, j, k: (0, j)))
+        in_specs.append(pl.BlockSpec((1, bn), lambda i, j: (0, j)))
     if residual is not None:
         operands.append(residual.reshape(m, n).astype(out_dtype))
-        in_specs.append(pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)))
+        in_specs.append(pl.BlockSpec((bm, bn), lambda i, j: (i, j)))
 
     acc_shape = ((bm, bn) if variant == "fused" else (n_a * n_b, bm, bn))
     scratch = [pltpu.VMEM(acc_shape, jnp.int32) for _ in range(1 + dual)]
     kernel = functools.partial(
-        _fused_linear_kernel, n_a=n_a, n_b=n_b, bm=bm, bn=bn, bk=bk,
+        _fused_linear_kernel, n_a=n_a, n_b=n_b, bm=bm, bn=bn, kw=kw,
         k_orig=k_orig, n_pad=kp - k_orig, variant=variant, act=act,
         dual=dual, has_bias=bias is not None, has_res=residual is not None)
     return pl.pallas_call(
         kernel,
-        grid=(m // bm, n // bn, kp // bk),
+        grid=(m // bm, n // bn),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=scratch,
-        compiler_params=compat.compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*operands)
 
@@ -372,7 +388,7 @@ def apmm_fused_linear(x: jax.Array, a_scale: jax.Array, bp: jax.Array,
 def apmm_packed(ap: jax.Array, bp: jax.Array, a_scale, b_scale, *,
                 n_a: int, n_b: int, k_orig: int,
                 variant: str = "fused",
-                block: tuple = (DEFAULT_BM, DEFAULT_BN, DEFAULT_BK),
+                block: tuple = (DEFAULT_BM, DEFAULT_BN),
                 out_dtype=jnp.float32,
                 interpret: bool = False) -> jax.Array:
     """Packed-layout arbitrary-precision NT GEMM: ``Y = A @ B^T``.
@@ -386,23 +402,18 @@ def apmm_packed(ap: jax.Array, bp: jax.Array, a_scale, b_scale, *,
       k_orig: unpadded reduction length (pad columns are corrected in
         closed form).
       variant: "fused" | "bitserial" (see module docstring).
-      block: ``(bm, bn, bk)`` tile sizes; ``bk % 32 == 0``.
+      block: ``(bm, bn)`` output tile; K is never tiled (see
+        :func:`_bits`).
 
-    Shapes must tile exactly: ``M % bm == N % bn == (Kw*32) % bk == 0``
-    (the :mod:`repro.kernels.ops` wrapper pads and unpads).
+    Shapes must tile exactly: ``M % bm == N % bn == 0`` (the
+    :mod:`repro.kernels.ops` wrapper pads and unpads).
     """
     n_a_, m, kw = ap.shape
     n_b_, n, kw2 = bp.shape
     assert (n_a_, n_b_) == (n_a, n_b) and kw == kw2, (ap.shape, bp.shape)
-    bm, bn, bk = block
-    bm, bn = min(bm, m), min(bn, n)
+    bm, bn = min(block[0], m), min(block[1], n)
+    _check_tiles(m, n, bm, bn)
     kp = kw * bipolar.PACK_WIDTH
-    bk = min(bk, kp)
-    if bk % bipolar.PACK_WIDTH:
-        raise ValueError(f"bk={bk} must be a multiple of {bipolar.PACK_WIDTH}")
-    if m % bm or n % bn or kp % bk:
-        raise ValueError(f"({m},{n},{kp}) not tiled by ({bm},{bn},{bk})")
-    bk32 = bk // bipolar.PACK_WIDTH
     dequant = a_scale is not None
     if dequant:
         assert b_scale is not None
@@ -414,24 +425,24 @@ def apmm_packed(ap: jax.Array, bp: jax.Array, a_scale, b_scale, *,
         a_scale = jnp.ones((m, 1), jnp.float32)
         b_scale = jnp.ones((1, n), jnp.float32)
 
-    grid = (m // bm, n // bn, kp // bk)
     acc_shape = ((bm, bn) if variant == "fused" else (n_a * n_b, bm, bn))
     kernel = functools.partial(
-        _kernel, n_a=n_a, n_b=n_b, bm=bm, bn=bn, bk=bk,
+        _kernel, n_a=n_a, n_b=n_b, bm=bm, bn=bn,
         n_pad=kp - k_orig, variant=variant, dequant=dequant)
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(m // bm, n // bn),
         in_specs=[
-            pl.BlockSpec((n_a, bm, bk32), lambda i, j, k: (0, i, k)),
-            pl.BlockSpec((n_b, bn, bk32), lambda i, j, k: (0, j, k)),
-            pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
-            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
+            pl.BlockSpec((n_a, bm, kw), lambda i, j: (0, i, 0)),
+            pl.BlockSpec((n_b, bn, kw), lambda i, j: (0, j, 0)),
+            pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM(acc_shape, jnp.int32)],
-        compiler_params=compat.compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(ap, bp, a_scale, b_scale)

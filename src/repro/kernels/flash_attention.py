@@ -38,10 +38,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import bipolar
-from repro.kernels import compat
 
 DEFAULT_BQ = 512
 DEFAULT_BK = 512
+# The kernels' products are f32 by design (dequantized K/V tiles, f32
+# probabilities); without an explicit precision a TPU's MXU may round f32
+# operands to bf16.
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _online_softmax_update(s, valid, v, m_ref, l_ref, acc_ref):
@@ -59,7 +62,7 @@ def _online_softmax_update(s, valid, v, m_ref, l_ref, acc_ref):
     m_ref[...] = m_new
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
         p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=_F32, preferred_element_type=jnp.float32)
 
 
 def _position_mask(qpos, kpos, causal: bool, window):
@@ -85,7 +88,7 @@ def _kernel(qp_ref, kp_ref, q_ref, k_ref, v_ref, out_ref,
 
     q = q_ref[0]                                  # (bq, d)
     k = k_ref[0]                                  # (bk, d)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), precision=_F32,
                             preferred_element_type=jnp.float32) * scale
 
     qpos = qp_ref[0][:, None]                     # (bq, 1) int32
@@ -136,7 +139,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q_pos, kv_pos, q, k, v)
@@ -183,7 +186,7 @@ def _kernel_quant(qp_ref, kp_ref, ks_ref, vs_ref, q_ref, kq_ref, vq_ref,
 
     q = q_ref[0]                                  # (bq, dp), zero pad cols
     s = jax.lax.dot_general(q.astype(jnp.float32), k,
-                            (((1,), (1,)), ((), ())),
+                            (((1,), (1,)), ((), ())), precision=_F32,
                             preferred_element_type=jnp.float32) * scale
     qpos = qp_ref[0][:, None]
     kpos = kp_ref[0][None, :]
@@ -250,7 +253,7 @@ def flash_attention_quantized(q: jax.Array,
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, dp), jnp.float32)],
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q_pos, kv_pos, k_scale, v_scale, q, k_packed, v_packed)
@@ -263,11 +266,19 @@ def flash_attention_quantized(q: jax.Array,
 DEFAULT_PAGED_BQ = 256   # query rows per tile (suffix prefill can be long)
 
 
+def _head_column(tile, j):
+    """Column ``j`` of a ``(rows, H)`` tile as ``(rows, 1)`` -- exact (one
+    nonzero term per row), with ``j`` a traced grid index."""
+    hit = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1) == j
+    return jnp.sum(jnp.where(hit, tile, 0.0), axis=1, keepdims=True)
+
+
 def _kernel_paged(bt_ref, qp_ref, kp_ref, ks_ref, vs_ref, q_ref, kq_ref,
                   vq_ref, out_ref, m_ref, l_ref, acc_ref, *,
                   scale: float, causal: bool, window,
                   bq: int, bs: int, dp: int, n_bits: int):
     del bt_ref  # consumed by the index maps (scalar prefetch)
+    j = pl.program_id(1)
     jk = pl.program_id(3)
     nk = pl.num_programs(3)
 
@@ -279,8 +290,8 @@ def _kernel_paged(bt_ref, qp_ref, kp_ref, ks_ref, vs_ref, q_ref, kq_ref,
 
     # position mask first: causal + sliding window by absolute position,
     # invalid slots (pos -1: null block / freshly allocated) excluded
-    qpos = qp_ref[0][:, None]                     # (bq, 1)
-    kpos = kp_ref[0][None, :]                     # (1, bs)
+    qpos = qp_ref[0]                              # (bq, 1)
+    kpos = kp_ref[0]                              # (1, bs)
     valid = _position_mask(qpos, kpos, causal, window)
 
     # grid skip: a block none of this tile's queries may see -- the
@@ -292,15 +303,18 @@ def _kernel_paged(bt_ref, qp_ref, kp_ref, ks_ref, vs_ref, q_ref, kq_ref,
     # normally never reach the kernel at all (the scheduler reclaims
     # them and the rolling block table bounds the grid itself); this
     # guard covers the in-between steps and the padded table entries.
-    @pl.when(jnp.any(valid))
+    @pl.when(jnp.max(valid.astype(jnp.int32)) > 0)
     def _update():
         # one physical block of the pool, routed here by the block
-        # table: kq_ref block is (1, bs, 1, n_bits, dw) -> (bs, n_bits, dw)
-        k = _dequant_tile(kq_ref[0][:, 0], ks_ref[0], n_bits, bs, dp)
-        v = _dequant_tile(vq_ref[0][:, 0], vs_ref[0], n_bits, bs, dp)
+        # table: kq_ref block is (1, bs, 1, n_bits, dw) -> (bs, n_bits,
+        # dw); the scale blocks carry every head's (bs, H) row
+        k = _dequant_tile(kq_ref[0][:, 0], _head_column(ks_ref[0], j),
+                          n_bits, bs, dp)
+        v = _dequant_tile(vq_ref[0][:, 0], _head_column(vs_ref[0], j),
+                          n_bits, bs, dp)
         q = q_ref[0, 0]                           # (bq, dp), zero pad cols
         s = jax.lax.dot_general(q.astype(jnp.float32), k,
-                                (((1,), (1,)), ((), ())),
+                                (((1,), (1,)), ((), ())), precision=_F32,
                                 preferred_element_type=jnp.float32) * scale
         s = jnp.where(valid, s, -1e30)
         _online_softmax_update(s, valid, v, m_ref, l_ref, acc_ref)
@@ -374,16 +388,22 @@ def flash_attention_paged_quantized(q: jax.Array,
     kernel = functools.partial(
         _kernel_paged, scale=1.0 / np.sqrt(d), causal=causal, window=window,
         bq=bq, bs=bs, dp=dp, n_bits=n_bits)
+    # every block's last two dims are whole array dims or (8, 128)
+    # multiples, as Mosaic requires: positions carry a unit axis on the
+    # side that is not tiled, and the scale blocks take all H heads of
+    # a pool block (the kernel selects head j)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, h, gq // bq, nb),
         in_specs=[
-            pl.BlockSpec((1, bq), lambda i, j, q, k, bt: (i, q)),   # q_pos
-            pl.BlockSpec((1, bs), lambda i, j, q, k, bt: (bt[i, k], 0)),
-            pl.BlockSpec((1, bs, 1),
-                         lambda i, j, q, k, bt: (bt[i, k], 0, j)),  # k_scale
-            pl.BlockSpec((1, bs, 1),
-                         lambda i, j, q, k, bt: (bt[i, k], 0, j)),  # v_scale
+            pl.BlockSpec((1, bq, 1),
+                         lambda i, j, q, k, bt: (i, q, 0)),         # q_pos
+            pl.BlockSpec((1, 1, bs),
+                         lambda i, j, q, k, bt: (bt[i, k], 0, 0)),  # pool_pos
+            pl.BlockSpec((1, bs, h),
+                         lambda i, j, q, k, bt: (bt[i, k], 0, 0)),  # k_scale
+            pl.BlockSpec((1, bs, h),
+                         lambda i, j, q, k, bt: (bt[i, k], 0, 0)),  # v_scale
             pl.BlockSpec((1, 1, bq, dp),
                          lambda i, j, q, k, bt: (i, j, q, 0)),      # q
             pl.BlockSpec((1, bs, 1, n_bits, dw),
@@ -401,27 +421,31 @@ def flash_attention_paged_quantized(q: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, gq, dp), q.dtype),
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(block_tables, q_pos, pool_pos, k_scale, v_scale, q, k_pool, v_pool)
+    )(block_tables, q_pos[:, :, None], pool_pos[:, None, :], k_scale,
+      v_scale, q, k_pool, v_pool)
 
 
 def attention_reference(q, k, v, q_pos, kv_pos, *, causal=True, window=None):
     """Pure-jnp oracle in the folded (BH, S, D) kernel layout.
 
     Direct (non-online) softmax; fully-masked rows return 0, matching the
-    kernels' denominator clamp.
+    kernels' denominator clamp.  Both products run at full f32 precision
+    on every backend (a TPU's default f32 matmul rounds its inputs to
+    bf16), as an oracle should.
     """
     d = q.shape[-1]
+    hi = jax.lax.Precision.HIGHEST
     s = jnp.einsum("bqd,btd->bqt", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) / np.sqrt(d)
+                   k.astype(jnp.float32), precision=hi) / np.sqrt(d)
     valid = _position_mask(q_pos[:, :, None], kv_pos[:, None, :],
                            causal, window)
     s = jnp.where(valid, s, -1e30)
     m = jnp.maximum(jnp.max(s, -1, keepdims=True), -1e30)
     p = jnp.where(valid, jnp.exp(s - m), 0.0)
-    o = jnp.einsum("bqt,btd->bqd", p, v.astype(jnp.float32))
+    o = jnp.einsum("bqt,btd->bqd", p, v.astype(jnp.float32), precision=hi)
     o = o / jnp.maximum(p.sum(-1, keepdims=True), 1e-20)
     return o.astype(q.dtype)

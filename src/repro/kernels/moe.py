@@ -64,29 +64,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import bipolar
-from repro.kernels import compat, ref
-from repro.kernels.apmm import (DEFAULT_BK, DEFAULT_BM, DEFAULT_BN, _NT,
-                                _recover_int8, _unpack)
-
-
-def _quantize_tile(x, s, n_a: int, k_lo, k_orig: int):
-    """Quantize a float tile ``(bc, bk)`` with per-row f32 scales
-    ``(bc, 1)`` to the unsigned bipolar bit field, dividing in f32.
-
-    ``layers._expert_quantize`` upcasts the materialized activations to
-    f32 and runs the whole scale/divide/round chain there (single
-    rounding); matching it exactly is what makes the grouped kernel
-    bit-identical to the ``_expert_matmul`` oracle.  K-pad columns are
-    forced to the all-zero-bit value ``-maxv`` (closed-form pad
-    correction)."""
-    q = bipolar.quantize_values(x.astype(jnp.float32), n_a, s)
-    col = k_lo + jax.lax.broadcasted_iota(jnp.int32, q.shape, 1)
-    q = jnp.where(col < k_orig, q, -bipolar.max_value(n_a))
-    return bipolar.encode(q, n_a)
+from repro.kernels import ref
+from repro.kernels.apmm import (DEFAULT_BM, DEFAULT_BN, VMEM_LIMIT_BYTES,
+                                _accumulate, _bit_major, _bits, _init_acc,
+                                _quantize_tile, _recover_acc, _word_base)
 
 
 def _moe_kernel(cnt_ref, *refs, n_a: int, n_b: int, bc: int, bn: int,
-                bk: int, k_orig: int, n_pad: int, variant: str, act: str,
+                kw: int, k_orig: int, n_pad: int, variant: str, act: str,
                 dual: bool):
     it = iter(refs)
     x_ref, as_ref = next(it), next(it)
@@ -98,8 +83,6 @@ def _moe_kernel(cnt_ref, *refs, n_a: int, n_b: int, bc: int, bn: int,
 
     eg = pl.program_id(0)
     ci = pl.program_id(1)
-    k_idx = pl.program_id(3)
-    n_k = pl.num_programs(3)
 
     # scalar-prefetched live-row count of this (expert, group) segment;
     # the tile is live iff its first capacity row is below the count
@@ -107,69 +90,40 @@ def _moe_kernel(cnt_ref, *refs, n_a: int, n_b: int, bc: int, bn: int,
     live = cnt > ci * bc
     live_ref[0, 0] = live.astype(jnp.int32)
 
-    @pl.when(live & (k_idx == 0))
-    def _init():
-        if variant == "fused":
-            init = jnp.full(
-                (bc, bn),
-                n_pad * bipolar.max_value(n_a) * bipolar.max_value(n_b),
-                jnp.int32)
-        else:
-            init = jnp.full((n_a * n_b, bc, bn), n_pad, jnp.int32)
-        for aref in accs:
-            aref[...] = init
-
     @pl.when(live)
     def _compute():
-        # prologue: quantize + bit-decompose the dispatched float rows in
-        # VMEM.  x_ref holds the whole-K row block (index map ignores j
-        # and k), so activations stream from HBM once per row tile.
-        xk = x_ref[0, :, pl.dslice(k_idx * bk, bk)]
-        ua = _quantize_tile(xk, as_ref[0], n_a, k_idx * bk, k_orig)
+        init = _init_acc(n_a, n_b, n_pad, variant, bc, bn)
+        for aref in accs:
+            aref[...] = init
         streams = [(wp_ref, accs[0])] \
             + ([(wp2_ref, accs[1])] if dual else [])
-        for bref, aref in streams:
-            bpl = _unpack(bref[:, 0], n_b, bn, bk)
-            if variant == "fused":
-                for lo_a, sz_a in ref.plane_groups(n_a):
-                    mask = (1 << sz_a) - 1
-                    va = ((((ua >> lo_a) & mask) << 1)
-                          - bipolar.max_value(sz_a)).astype(jnp.int8)
-                    for lo_b, sz_b in ref.plane_groups(n_b):
-                        b8 = _recover_int8(bpl, lo_b, sz_b)
-                        y = jax.lax.dot_general(
-                            va, b8, _NT, preferred_element_type=jnp.int32)
-                        aref[...] += y << (lo_a + lo_b)
-            else:
-                for i in range(n_a):
-                    a8 = (((ua >> i) & 1) * 2 - 1).astype(jnp.int8)
-                    for j in range(n_b):
-                        b8 = (2 * bpl[j] - 1).astype(jnp.int8)
-                        aref[i * n_b + j] += jax.lax.dot_general(
-                            a8, b8, _NT, preferred_element_type=jnp.int32)
+        base = _word_base(bc, kw)
 
-    @pl.when((k_idx == n_k - 1) & live)
-    def _finish():
-        od = out_ref.dtype
+        def bit_step(b, carry):
+            # prologue: quantize + bit-decompose this bit position's
+            # dispatched float columns in VMEM.  x_ref holds the whole-K
+            # row block (index map ignores j), so activations stream
+            # from HBM once per row tile.
+            a_u = _quantize_tile(x_ref[0, b], as_ref[0], n_a, base + b,
+                                 k_orig)
+            for bref, aref in streams:
+                _accumulate(a_u, _bits(bref[:, 0], b), aref, n_a=n_a,
+                            n_b=n_b, variant=variant)
+            return carry
 
-        def recover_acc(aref):
-            if variant == "fused":
-                return aref[...]
-            y = jnp.zeros((bc, bn), jnp.int32)
-            for i in range(n_a):
-                for j in range(n_b):
-                    y = y + (aref[i * n_b + j] << (i + j))
-            return y
+        jax.lax.fori_loop(0, bipolar.PACK_WIDTH, bit_step, 0)
 
         # dequant + epilogue in f32 with ONE output-dtype cast -- the
         # same cast point as the legacy f32 composition in moe_apply
         # (bit-identity; intermediate narrowing casts would not be
         # compilation-stable on the jnp side)
+        od = out_ref.dtype
         a_s = as_ref[0]                                   # (bc, 1) f32
-        yf = recover_acc(accs[0]).astype(jnp.float32) * a_s * ws_ref[0]
+        yf = _recover_acc(accs[0], n_a, n_b, variant).astype(jnp.float32) \
+            * a_s * ws_ref[0]
         if dual:
-            y2 = recover_acc(accs[1]).astype(jnp.float32) \
-                * a_s * w2s_ref[0]
+            y2 = _recover_acc(accs[1], n_a, n_b, variant).astype(
+                jnp.float32) * a_s * w2s_ref[0]
             yf = ref.apply_act(yf, act) * y2
         elif act != "none":
             yf = ref.apply_act(yf, act)
@@ -178,7 +132,7 @@ def _moe_kernel(cnt_ref, *refs, n_a: int, n_b: int, bc: int, bn: int,
         row = ci * bc + jax.lax.broadcasted_iota(jnp.int32, (bc, bn), 0)
         out_ref[0] = jnp.where(row < cnt, yo, jnp.zeros((), od))
 
-    @pl.when((k_idx == n_k - 1) & jnp.logical_not(live))
+    @pl.when(jnp.logical_not(live))
     def _skip():
         out_ref[0] = jnp.zeros((bc, bn), out_ref.dtype)
 
@@ -192,14 +146,14 @@ def moe_expert_linear(x: jax.Array, a_scale: jax.Array, counts: jax.Array,
                       w2_scale=None, n_a: int, n_b: int, k_orig: int,
                       n_groups: int, variant: str = "fused",
                       act: str = "none",
-                      block: tuple = (DEFAULT_BM, DEFAULT_BN, DEFAULT_BK),
+                      block: tuple = (DEFAULT_BM, DEFAULT_BN),
                       out_dtype=jnp.bfloat16, interpret: bool = False):
     """Grouped quantized expert GEMM, one launch for all experts.
 
     Args:
-      x: ``(EG, Cp, Kp)`` float dispatched activations -- ``EG = E *
+      x: ``(EG, Cp, Kw*32)`` float dispatched activations -- ``EG = E *
         n_groups`` row segments of padded capacity ``Cp``, K padded to
-        the tile boundary (pad columns masked in-kernel).
+        the packed word width (pad columns masked in-kernel).
       a_scale: ``(EG, Cp, 1)`` f32 per-row activation scales (the f32
         quantize chain -- see :func:`_quantize_tile`).
       counts: ``(EG,)`` int32 live-row counts (scalar-prefetched); rows
@@ -221,51 +175,47 @@ def moe_expert_linear(x: jax.Array, a_scale: jax.Array, counts: jax.Array,
     n_b_, e, n, kw = wp.shape
     assert n_b_ == n_b and kp == kw * bipolar.PACK_WIDTH, (x.shape, wp.shape)
     assert egs == e * n_groups, (egs, e, n_groups)
-    bm, bn, bk = block
-    bc, bn = min(bm, cp), min(bn, n)
-    bk = min(bk, kp)
-    if bk % bipolar.PACK_WIDTH:
-        raise ValueError(f"bk={bk} must be a multiple of {bipolar.PACK_WIDTH}")
-    if cp % bc or n % bn or kp % bk:
-        raise ValueError(f"({cp},{n},{kp}) not tiled by ({bc},{bn},{bk})")
-    bk32 = bk // bipolar.PACK_WIDTH
+    bc, bn = min(block[0], cp), min(block[1], n)
+    if cp % bc or n % bn:
+        raise ValueError(f"({cp},{n}) not tiled by ({bc},{bn})")
     dual = wp2 is not None
     if dual:
         assert w2_scale is not None and wp2.shape == wp.shape, \
             (wp.shape, None if wp2 is None else wp2.shape)
     g = n_groups
 
-    operands = [x, a_scale, wp, w_scale]
+    operands = [_bit_major(x), a_scale, wp, w_scale]
     in_specs = [
         # whole-K row block, re-fetched only when (eg, ci) changes
-        pl.BlockSpec((1, bc, kp), lambda eg, ci, j, k, cc: (eg, ci, 0)),
-        pl.BlockSpec((1, bc, 1), lambda eg, ci, j, k, cc: (eg, ci, 0)),
+        pl.BlockSpec((1, bipolar.PACK_WIDTH, bc, kw),
+                     lambda eg, ci, j, cc: (eg, 0, ci, 0)),
+        pl.BlockSpec((1, bc, 1), lambda eg, ci, j, cc: (eg, ci, 0)),
         # expert index = eg // n_groups (groups share their expert's
         # weights; the weight tile is re-fetched only across experts)
-        pl.BlockSpec((n_b, 1, bn, bk32),
-                     lambda eg, ci, j, k, cc: (0, eg // g, j, k)),
-        pl.BlockSpec((1, 1, bn), lambda eg, ci, j, k, cc: (eg // g, 0, j)),
+        pl.BlockSpec((n_b, 1, bn, kw),
+                     lambda eg, ci, j, cc: (0, eg // g, j, 0)),
+        pl.BlockSpec((1, 1, bn), lambda eg, ci, j, cc: (eg // g, 0, j)),
     ]
     if dual:
         operands += [wp2, w2_scale]
         in_specs += [
-            pl.BlockSpec((n_b, 1, bn, bk32),
-                         lambda eg, ci, j, k, cc: (0, eg // g, j, k)),
+            pl.BlockSpec((n_b, 1, bn, kw),
+                         lambda eg, ci, j, cc: (0, eg // g, j, 0)),
             pl.BlockSpec((1, 1, bn),
-                         lambda eg, ci, j, k, cc: (eg // g, 0, j)),
+                         lambda eg, ci, j, cc: (eg // g, 0, j)),
         ]
 
     acc_shape = ((bc, bn) if variant == "fused" else (n_a * n_b, bc, bn))
     kernel = functools.partial(
-        _moe_kernel, n_a=n_a, n_b=n_b, bc=bc, bn=bn, bk=bk, k_orig=k_orig,
+        _moe_kernel, n_a=n_a, n_b=n_b, bc=bc, bn=bn, kw=kw, k_orig=k_orig,
         n_pad=kp - k_orig, variant=variant, act=act, dual=dual)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(egs, cp // bc, n // bn, kp // bk),
+        grid=(egs, cp // bc, n // bn),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, bc, bn), lambda eg, ci, j, k, cc: (eg, ci, j)),
-            pl.BlockSpec((1, 1), lambda eg, ci, j, k, cc: (eg, ci)),
+            pl.BlockSpec((1, bc, bn), lambda eg, ci, j, cc: (eg, ci, j)),
+            pl.BlockSpec((1, 1), lambda eg, ci, j, cc: (eg, ci)),
         ],
         scratch_shapes=[pltpu.VMEM(acc_shape, jnp.int32)
                         for _ in range(1 + dual)],
@@ -277,8 +227,8 @@ def moe_expert_linear(x: jax.Array, a_scale: jax.Array, counts: jax.Array,
             jax.ShapeDtypeStruct((egs, cp, n), out_dtype),
             jax.ShapeDtypeStruct((egs, cp // bc), jnp.int32),
         ],
-        compiler_params=compat.compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(counts, *operands)

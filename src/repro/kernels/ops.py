@@ -78,7 +78,9 @@ def default_impl() -> str:
         return _impl_override
     env = os.environ.get("REPRO_KERNEL_IMPL")
     if env:
-        assert env in _IMPLS, env
+        if env not in _IMPLS:
+            raise ValueError(
+                f"REPRO_KERNEL_IMPL={env!r}: expected one of {_IMPLS}")
         return env
     return "pallas" if jax.default_backend() == "tpu" else "reference"
 
@@ -209,22 +211,17 @@ def ap_matmul(a: BipolarTensor, b: BipolarTensor, *,
         return ref.apmm_dequant_ref(a, b, fused=(variant == "fused"),
                                     out_dtype=out_dtype)
     (m, k), (n, _) = a.shape, b.shape
-    ap, bp = a.packed, b.packed
-    kw = ap.shape[-1]
-    # --- pad to tile multiples ------------------------------------------
+    # --- pad M/N to tile multiples (K is never tiled) --------------------
     bm = min(apmm_kernel.DEFAULT_BM, _round_up(m, 8))
     bn = min(apmm_kernel.DEFAULT_BN, _round_up(n, 128))
     mp, np_ = _round_up(m, bm), _round_up(n, bn)
-    kp0 = kw * bipolar.PACK_WIDTH
-    bk = min(apmm_kernel.DEFAULT_BK, _round_up(kp0, 32))
-    kp = _round_up(kp0, bk)
-    ap = _pad_dim(_pad_dim(ap, 1, mp), 2, kp // 32, 0x00000000)  # A pads: bit 0
-    bp = _pad_dim(_pad_dim(bp, 1, np_), 2, kp // 32, 0xFFFFFFFF)  # B pads: bit 1
+    ap = _pad_dim(a.packed, 1, mp)
+    bp = _pad_dim(b.packed, 1, np_)
     a_scale = None if raw else _pad_dim(a.scale.reshape(m, 1), 0, mp, 1.0)
     b_scale = None if raw else _pad_dim(b.scale.reshape(n, 1), 0, np_, 1.0)
     y = apmm_kernel.apmm_packed(
         ap, bp, a_scale, b_scale, n_a=a.n_bits, n_b=b.n_bits, k_orig=k,
-        variant=variant, block=(bm, bn, bk), out_dtype=out_dtype,
+        variant=variant, block=(bm, bn), out_dtype=out_dtype,
         interpret=(impl == "interpret"))
     return y[:m, :n]
 
@@ -326,23 +323,16 @@ def ap_linear_fused(x: jax.Array, w: BipolarTensor, *, a_bits: int,
     w2p = w2.packed if w2 is not None else None
     kw = max(bipolar.packed_words(k), wp.shape[-1],
              w2p.shape[-1] if w2p is not None else 0)
-    wp = _pad_dim(wp, 2, kw, 0xFFFFFFFF)
-    if w2p is not None:
-        w2p = _pad_dim(w2p, 2, kw, 0xFFFFFFFF)
     bm = min(apmm_kernel.DEFAULT_BM, _round_up(m, 8))
     bn = min(apmm_kernel.DEFAULT_BN, _round_up(n, 128))
     mp, np_ = _round_up(m, bm), _round_up(n, bn)
-    kp0 = kw * bipolar.PACK_WIDTH
-    bk = min(apmm_kernel.DEFAULT_BK, _round_up(kp0, 32))
-    kp = _round_up(kp0, bk)
-    xp = _pad_dim(_pad_dim(x2, 1, kp), 0, mp)
+    xp = _pad_dim(_pad_dim(x2, 1, kw * bipolar.PACK_WIDTH), 0, mp)
     sp = _pad_dim(scale, 0, mp, 1.0)
-    wp = _pad_dim(_pad_dim(wp, 1, np_), 2, kp // 32, 0xFFFFFFFF)
+    wp = _pad_dim(_pad_dim(wp, 1, np_), 2, kw, 0xFFFFFFFF)
     ws = _pad_dim(w.scale.reshape(n, 1), 0, np_, 1.0)
     kw_args: dict = {}
     if w2p is not None:
-        kw_args["bp2"] = _pad_dim(_pad_dim(w2p, 1, np_), 2, kp // 32,
-                                  0xFFFFFFFF)
+        kw_args["bp2"] = _pad_dim(_pad_dim(w2p, 1, np_), 2, kw, 0xFFFFFFFF)
         kw_args["b2_scale"] = _pad_dim(w2.scale.reshape(n, 1), 0, np_, 1.0)
     if bias is not None:
         kw_args["bias"] = _pad_dim(
@@ -352,7 +342,7 @@ def ap_linear_fused(x: jax.Array, w: BipolarTensor, *, a_bits: int,
             _pad_dim(res2.astype(out_dtype), 1, np_), 0, mp)
     y = apmm_kernel.apmm_fused_linear(
         xp, sp, wp, ws, n_a=a_bits, n_b=w.n_bits, k_orig=k,
-        variant=variant, act=act, block=(bm, bn, bk), out_dtype=out_dtype,
+        variant=variant, act=act, block=(bm, bn), out_dtype=out_dtype,
         interpret=(impl == "interpret"), **kw_args)
     return y[:m, :n].reshape(*lead, n)
 
@@ -447,24 +437,21 @@ def ap_moe_expert_linear(x: jax.Array, w: BipolarTensor, *,
              w2p.shape[-1] if w2p is not None else 0)
     bn = min(apmm_kernel.DEFAULT_BN, _round_up(n, 128))
     np_ = _round_up(n, bn)
-    kp0 = kw * bipolar.PACK_WIDTH
-    bk = min(apmm_kernel.DEFAULT_BK, _round_up(kp0, 32))
-    kp = _round_up(kp0, bk)
     cp = n_ci * bc
-    xg = _pad_dim(_pad_dim(x.reshape(e * g, seg, k), 2, kp), 1, cp)
+    xg = _pad_dim(_pad_dim(x.reshape(e * g, seg, k), 2,
+                           kw * bipolar.PACK_WIDTH), 1, cp)
     sg = _pad_dim(a_scale.reshape(e * g, seg, 1), 1, cp, 1.0)
-    wp = _pad_dim(_pad_dim(wp, 2, np_), 3, kp // 32, 0xFFFFFFFF)
+    wp = _pad_dim(_pad_dim(wp, 2, np_), 3, kw, 0xFFFFFFFF)
     ws = _pad_dim(w.scale.reshape(e, 1, n).astype(jnp.float32), 2, np_, 1.0)
     kw_args: dict = {}
     if w2p is not None:
-        kw_args["wp2"] = _pad_dim(_pad_dim(w2p, 2, np_), 3, kp // 32,
-                                  0xFFFFFFFF)
+        kw_args["wp2"] = _pad_dim(_pad_dim(w2p, 2, np_), 3, kw, 0xFFFFFFFF)
         kw_args["w2_scale"] = _pad_dim(
             w2.scale.reshape(e, 1, n).astype(jnp.float32), 2, np_, 1.0)
     y, live = moe_kernel.moe_expert_linear(
         xg, sg, counts.reshape(e * g), wp, ws,
         n_a=a_bits, n_b=w.n_bits, k_orig=k, n_groups=g, variant=variant,
-        act=act, block=(bc, bn, bk), out_dtype=out_dtype,
+        act=act, block=(bc, bn), out_dtype=out_dtype,
         interpret=(impl == "interpret"), **kw_args)
     y = y[:, :seg, :n].reshape(e, c, n)
     return (y, live) if with_stats else y

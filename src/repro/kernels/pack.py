@@ -29,7 +29,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import bipolar
-from repro.kernels import compat
 
 DEFAULT_BR = 256
 DEFAULT_BK = 1024
@@ -76,7 +75,7 @@ def quantize_pack_rows(x: jax.Array, scale: jax.Array, *, n_bits: int,
         out_specs=pl.BlockSpec((n_bits, br, bk // 32),
                                lambda i, j: (0, i, j)),
         out_shape=jax.ShapeDtypeStruct((n_bits, r, k // 32), jnp.uint32),
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(x, scale)

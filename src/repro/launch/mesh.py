@@ -19,15 +19,26 @@ from __future__ import annotations
 import jax
 
 
+def _make_mesh(shape, axes):
+    # Auto axes: the sharding rules place arrays with device_put and
+    # with_sharding_constraint and let the partitioner propagate the
+    # rest.  jax.make_mesh defaults to Explicit axes, under which shardings
+    # are part of array types and an op such as the embedding gather
+    # (tokens over data, embedding columns over data) has no single
+    # output sharding and is refused at trace time.
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_host_mesh(n_data: int = 4, n_model: int = 2):
     """Small mesh over host platform devices (tests)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return _make_mesh((n_data, n_model), ("data", "model"))
 
 
 def dp_axes(mesh) -> tuple:

@@ -73,14 +73,16 @@ def _block_init(key, cfg: ModelConfig, mixer_kind: str, ffn_kind: str) -> dict:
 
 
 def _stack_init(key, cfg: ModelConfig, unit_plan, n_units: int):
-    """Stacked params for the scanned unit: leaves get a leading n_units dim."""
+    """Stacked params for the scanned unit: leaves get a leading n_units dim.
+
+    ``vmap`` over the unit keys draws the same values as one init per
+    key, but writes each leaf stacked once: per-unit trees followed by a
+    stack would hold the model twice (11 GB for StableLM-3B in bf16)."""
     def one_unit(k):
         ks = jax.random.split(k, len(unit_plan))
         return [_block_init(ks[i], cfg, mk, fk)
                 for i, (mk, fk) in enumerate(unit_plan)]
-    keys = jax.random.split(key, n_units)
-    units = [one_unit(k) for k in keys]
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *units)
+    return jax.vmap(one_unit)(jax.random.split(key, n_units))
 
 
 def init_params(cfg: ModelConfig, key) -> dict:
@@ -503,26 +505,42 @@ def _quantize_leaf(w: jax.Array, qcfg: QuantConfig,
     scan slice -- which peels the unit axis off every array leaf -- always
     hands ops a plane-leading tensor that ``bipolar.nested_slice`` can
     serve at any width k <= w_bits.
+
+    A stacked leaf is quantized one scan unit at a time, so its f32 copy
+    and the clip-search temporaries exist for one layer only: at
+    published widths a whole stacked leaf in f32 (StableLM-3B's gate,
+    32 x 6912 x 2560 x 4 B = 2.3 GB) would not fit beside the bf16 tree
+    on a 16 GB chip.  Every step is per row, so this equals quantizing
+    the leaf whole.  The ops run eagerly, not under one jit: XLA would
+    rewrite the per-row division in ``quantize_values`` into a
+    reciprocal multiply and move a few rounded integers.
     """
     shape = tuple(w.shape)
-    w2 = w.reshape(-1, shape[-1]).astype(jnp.float32)
-    t = ops.quantize_rows(w2, qcfg.w_bits, pad_bit=1, impl="reference",
-                          scale_search=True)
-    kw = t.packed.shape[-1]
-    packed = t.packed.reshape(qcfg.w_bits, *shape[:-1], kw)
-    scale = t.scale.reshape(*shape[:-1], 1)
+    n_bits = qcfg.w_bits
+    units = [w[u] for u in range(shape[0])] if stacked else [w]
+    parts = [ops.quantize_rows(u.reshape(-1, shape[-1]).astype(jnp.float32),
+                               n_bits, pad_bit=1, impl="reference",
+                               scale_search=True)
+             for u in units]
+    rest = shape[1:-1] if stacked else shape[:-1]
+    kw = parts[0].packed.shape[-1]
+    packed = [p.packed.reshape(n_bits, *rest, kw) for p in parts]
+    scale = [p.scale.reshape(*rest, 1) for p in parts]
     width_scales = None
-    if t.width_scales is not None:
-        width_scales = t.width_scales.reshape(
-            t.n_bits, *shape[:-1], 1)
+    if parts[0].width_scales is not None:
+        width_scales = [p.width_scales.reshape(n_bits, *rest, 1)
+                        for p in parts]
     if stacked:
-        packed = jnp.moveaxis(packed, 0, 1)  # (u, n_bits, *rest, Kw)
+        packed, scale = jnp.stack(packed), jnp.stack(scale)
         if width_scales is not None:
-            width_scales = jnp.moveaxis(width_scales, 0, 1)
+            width_scales = jnp.stack(width_scales)
         static_shape = shape[1:]
     else:
+        packed, scale = packed[0], scale[0]
+        if width_scales is not None:
+            width_scales = width_scales[0]
         static_shape = shape
-    return BipolarTensor(packed=packed, scale=scale, n_bits=qcfg.w_bits,
+    return BipolarTensor(packed=packed, scale=scale, n_bits=n_bits,
                          shape=static_shape,
                          pack_axis=len(static_shape) - 1,
                          width_scales=width_scales)

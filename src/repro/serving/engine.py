@@ -119,7 +119,7 @@ import numpy as np
 from repro.models import model as M
 from repro.models.config import ModelConfig, QuantConfig
 from repro.obs import NULL_OBS, MetricsRegistry, ServingObs
-from repro.serving.faults import NULL_FAULTS, RequestFault
+from repro.serving.faults import NULL_FAULTS, PoolExhausted, RequestFault
 
 
 # ---------------------------------------------------------------------------
@@ -1148,7 +1148,7 @@ class Engine:
                 plan = sch.ensure_step_capacity(sch.plan_step())
                 if not plan:
                     return self.faults.enabled and sch.has_work
-        except RuntimeError:
+        except PoolExhausted:
             # a transient pool fault the scheduler could not absorb by
             # preempting (e.g. injected exhaustion with one request
             # left).  Alloc is atomic and the rollback paths ran, so

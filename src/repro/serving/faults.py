@@ -13,7 +13,7 @@ Sites, by subsystem (each maps to a recovery path the chaos suite
 asserts):
 
 * **pool** -- ``alloc_fail`` / ``slot_fail`` raise the pool's own
-  exhaustion ``RuntimeError`` *before any state mutates* (alloc is
+  :class:`PoolExhausted` *before any state mutates* (alloc is
   atomic: it either completes or leaves the pool untouched), and
   ``forced_evict`` evicts one LRU-cached block on an otherwise
   satisfiable alloc (prefix-cache pressure: hits become misses, math is
@@ -50,7 +50,17 @@ from typing import Callable, Optional
 
 import numpy as np
 
-__all__ = ["FaultInjector", "NULL_FAULTS", "RequestFault"]
+__all__ = ["FaultInjector", "NULL_FAULTS", "PoolExhausted", "RequestFault"]
+
+
+class PoolExhausted(RuntimeError):
+    """The block pool or the state-slot pool cannot satisfy an
+    allocation (a real shortfall or the injector's ``alloc_fail`` /
+    ``slot_fail``).  Raised before any pool state mutates, so it is the
+    one transient fault the scheduler and the engine absorb: the
+    admission rolls back and re-queues, or the step is consumed and
+    retried.  Every other exception -- a device or compile error from
+    the forward pass included -- propagates to the caller."""
 
 
 class RequestFault(Exception):

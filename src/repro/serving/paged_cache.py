@@ -110,7 +110,7 @@ import numpy as np
 from repro.models import model as M
 from repro.models.config import ModelConfig, QuantConfig, effective_kv_bits
 from repro.obs.metrics import MetricsRegistry
-from repro.serving.faults import NULL_FAULTS
+from repro.serving.faults import NULL_FAULTS, PoolExhausted
 
 _KV_KEYS = ("k", "v", "k_scale", "v_scale", "pos")
 
@@ -236,7 +236,7 @@ class StateSlotPool:
 
     def alloc(self) -> int:
         if not self._free:
-            raise RuntimeError(
+            raise PoolExhausted(
                 f"slot pool exhausted: all {self.n_slots} state slots "
                 f"are owned by running requests")
         slot = self._free.pop()
@@ -533,11 +533,11 @@ class PagedKVPool:
         ``alloc_fail`` raises the exhaustion error on a satisfiable
         request; ``forced_evict`` evicts one LRU-cached block first."""
         if self.faults.alloc_fail(n):
-            raise RuntimeError(
+            raise PoolExhausted(
                 f"pool exhausted (injected fault): want {n} blocks, "
                 f"{self.free_blocks} free")
         if n > self.free_blocks:
-            raise RuntimeError(
+            raise PoolExhausted(
                 f"pool exhausted: want {n} blocks, {self.free_blocks} free")
         if self.faults.forced_evict() and self._lru:
             victim, _ = self._lru.popitem(last=False)       # LRU end
@@ -878,7 +878,7 @@ class PagedKVPool:
         before the slot pool mutates (admission rolls cleanly back)."""
         assert self.slots is not None, "pool has no state slot pool"
         if self.faults.slot_fail():
-            raise RuntimeError(
+            raise PoolExhausted(
                 f"slot pool exhausted (injected fault): "
                 f"{self.slots.free_slots} of {self.slots.n_slots} free")
         slot = self.slots.alloc()

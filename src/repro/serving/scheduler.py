@@ -69,7 +69,7 @@ from typing import Optional
 import numpy as np
 
 from repro.obs import NULL_OBS
-from repro.serving.faults import RequestFault
+from repro.serving.faults import PoolExhausted, RequestFault
 from repro.serving.paged_cache import ChainMemo, PagedKVPool
 
 
@@ -358,7 +358,7 @@ class Scheduler:
                 # a long prompt's leading blocks may already be fully out
                 # of the attention window: return them before decode
                 self._reclaim_seq(seq)
-            except Exception as e:
+            except (PoolExhausted, RequestFault) as e:
                 self._rollback_admission(req, seq, e, announced)
                 break
             self.running.append(seq)
@@ -371,7 +371,7 @@ class Scheduler:
         failure: every block reference ``seq`` holds returns through
         the refcount path and the state slot (if taken) is freed, so
         the pool is exactly as if the admission never started.  A
-        transient pool fault (exhaustion ``RuntimeError``) re-queues
+        transient pool fault (:class:`PoolExhausted`) re-queues
         the request at the head for the next step; a
         request-attributable :class:`RequestFault` (e.g. its first
         token's callback raised mid-prefill) finishes it with
@@ -443,7 +443,7 @@ class Scheduler:
             if self.pool.slots is not None:
                 try:
                     seq.slot = self.pool.alloc_slot()
-                except RuntimeError as e:
+                except PoolExhausted as e:
                     self._rollback_admission(req, seq, e, False)
                     break
             self.pool.record_hit(hit, len(tokens))
@@ -649,7 +649,7 @@ class Scheduler:
                         - seq.freed_prefix
                     if self.pool.refcount(seq.blocks[j]) > 1:
                         seq.blocks[j] = self.pool.cow(seq.blocks[j])
-            except RuntimeError:
+            except PoolExhausted:
                 # alloc or COW failed AFTER the capacity check (an
                 # injected exhaustion, or eviction pressure from a COW
                 # draw): alloc is atomic and partial grow/COW state is

@@ -33,6 +33,7 @@ from collections import Counter
 
 import jax
 import numpy as np
+import pytest
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -42,7 +43,7 @@ except ImportError:    # property tests skip (not error) without hypothesis
 from repro.configs import get_config
 from repro.models import model as M
 from repro.serving import engine as E
-from repro.serving.faults import FaultInjector, NULL_FAULTS
+from repro.serving.faults import FaultInjector, NULL_FAULTS, PoolExhausted
 from repro.serving.paged_cache import PagedKVPool
 from repro.serving.scheduler import Scheduler
 
@@ -91,7 +92,7 @@ def _chaos_stub_step(sch, chunk):
     try:
         sch.admit_chunked()
         plan = sch.ensure_step_capacity(sch.plan_step())
-    except RuntimeError:
+    except PoolExhausted:
         return
     for seq, n in plan:
         if seq.req.done:
@@ -647,3 +648,30 @@ def test_timeout_mid_chunk_releases_partial_chain():
     assert a.done and a.finish_reason == "length" and len(a.out) == 6
     eng.pool.validate()
     assert eng.pool.free_blocks == eng.pool.n_usable
+
+
+@pytest.mark.parametrize("chunk_tokens", [None, 8])
+@pytest.mark.parametrize("exc", [
+    jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: injected device OOM"),
+    ValueError("injected forward bug"),
+])
+def test_non_pool_errors_propagate_out_of_run(monkeypatch, chunk_tokens,
+                                              exc):
+    """Containment covers pool exhaustion and per-request faults only: a
+    device error (``JaxRuntimeError`` is a ``RuntimeError``) or a bug
+    raised by the forward pass leaves ``Engine.run`` instead of being
+    taken for a transient fault -- in whole-prompt mode it used to roll
+    the admission back and end the run quietly with work waiting."""
+    cfg, params = _setup("llama3-8b", n_layers=1)
+
+    def broken_step(*args, **kwargs):
+        raise exc
+
+    monkeypatch.setattr(E, "prefill_step_bucketed", broken_step)
+    monkeypatch.setattr(E, "serve_step", broken_step)
+    eng = E.Engine(params, cfg, n_slots=2, max_len=32, quant=_kv8(cfg),
+                   paged=True, block_size=8, chunk_tokens=chunk_tokens)
+    eng.submit(E.Request(prompt=np.arange(5, dtype=np.int32),
+                         max_new_tokens=3))
+    with pytest.raises(type(exc), match="injected"):
+        eng.run()

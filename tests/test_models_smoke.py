@@ -1,6 +1,8 @@
 """Per-architecture smoke tests: reduced config, one forward + train step
 on CPU, asserting output shapes and no NaNs (task spec deliverable f)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -80,3 +82,40 @@ def test_paper_w2a8_forward_finite(arch_setup):
                         patch_embeds=batch.get("patch_embeds"),
                         frames=batch.get("frames"))
     assert np.all(np.isfinite(np.asarray(x, dtype=np.float32))), cfg.name
+
+
+def test_quantize_params_matches_whole_leaf_quantization(arch_setup):
+    """``quantize_params`` packs scan-stacked leaves one unit at a time
+    under jit; every packed word, scale and width scale must equal the
+    eager whole-leaf quantization of the same leaf."""
+    from repro.core.bipolar import BipolarTensor
+    from repro.kernels import ops
+    cfg, params = arch_setup
+    qcfg = dataclasses.replace(cfg.quant, w_bits=cfg.quant.w_bits or 4)
+    qparams = M.quantize_params(params, qcfg)
+    flat = jax.tree_util.tree_flatten_with_path(
+        qparams, is_leaf=lambda x: isinstance(x, BipolarTensor))[0]
+    checked = 0
+    for path, q in flat:
+        if not isinstance(q, BipolarTensor):
+            continue
+        keys = [getattr(p, "key", getattr(p, "idx", None)) for p in path]
+        w = params
+        for k in keys:
+            w = w[k]
+        shape, n = w.shape, qcfg.w_bits
+        t = ops.quantize_rows(w.reshape(-1, shape[-1]).astype(jnp.float32),
+                              n, pad_bit=1, impl="reference",
+                              scale_search=True)
+        packed = t.packed.reshape(n, *shape[:-1], t.packed.shape[-1])
+        ws = t.width_scales.reshape(n, *shape[:-1], 1)
+        if any(k in ("blocks", "cross") for k in keys):
+            packed, ws = jnp.moveaxis(packed, 0, 1), jnp.moveaxis(ws, 0, 1)
+        np.testing.assert_array_equal(np.asarray(q.packed), np.asarray(packed))
+        np.testing.assert_array_equal(np.asarray(q.scale),
+                                      np.asarray(t.scale.reshape(
+                                          *shape[:-1], 1)))
+        np.testing.assert_array_equal(np.asarray(q.width_scales),
+                                      np.asarray(ws))
+        checked += 1
+    assert checked >= 2, (cfg.name, checked)

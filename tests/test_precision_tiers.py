@@ -40,7 +40,7 @@ from repro.models import model as M
 from repro.models.config import QuantConfig
 from repro.serving import engine as E
 from repro.serving.engine import tier_bits
-from repro.serving.faults import FaultInjector
+from repro.serving.faults import FaultInjector, PoolExhausted
 from repro.serving.paged_cache import PagedKVPool
 from repro.serving.scheduler import Scheduler
 
@@ -200,7 +200,7 @@ def _tier_stub_step(sch):
     try:
         sch.admit_chunked()
         plan = sch.ensure_step_capacity(sch.plan_step())
-    except RuntimeError:
+    except PoolExhausted:
         return
     for seq in sch.running:
         assert seq.precision == seq.req._tier_bits, \

@@ -93,19 +93,21 @@ def peak_hbm(dev) -> str:
     return "not reported" if peak is None else f"{peak / 2**30:.3f}GiB"
 
 
-def build(cfg, quant, seed: int):
-    """Seeded bf16 init, then per-layer packing; the bf16 tree is
-    dropped once the packed tree exists."""
+def build(cfg, quant, seed: int, dev):
+    """Seeded bf16 init under one jit (its program needs no temporaries),
+    then per-layer packing that frees each bf16 weight once it is packed.
+    Returns (packed params, init s, quantize s, peak HBM after init)."""
     import jax
     from repro.models import model as M
     t0 = time.perf_counter()
-    params = jax.block_until_ready(M.init_params(cfg,
-                                                 jax.random.PRNGKey(seed)))
+    params = jax.block_until_ready(jax.jit(M.init_params, static_argnums=0)(
+        cfg, jax.random.PRNGKey(seed)))
     t1 = time.perf_counter()
-    qparams = jax.block_until_ready(M.quantize_params(params, quant))
+    peak_init = peak_hbm(dev)
+    qparams = jax.block_until_ready(
+        M.quantize_params(params, quant, donate=True))
     t2 = time.perf_counter()
-    del params
-    return qparams, t1 - t0, t2 - t1
+    return qparams, t1 - t0, t2 - t1, peak_init
 
 
 def compile_decode(eng, cfg, quant, reqs):
@@ -206,9 +208,9 @@ def run(cfg, quant, *, seed: int, expect_impl: str, dev) -> None:
     from repro.kernels import ops
     from repro.serving import engine as E
 
-    qparams, t_init, t_quant = build(cfg, quant, seed)
+    qparams, t_init, t_quant, peak_init = build(cfg, quant, seed, dev)
     log("build", init_s=f"{t_init:.3f}", quantize_s=f"{t_quant:.3f}",
-        peak_hbm=peak_hbm(dev))
+        peak_hbm_after_init=peak_init, peak_hbm=peak_hbm(dev))
 
     reqs = make_requests(cfg.vocab, seed)
     # every request's whole lifetime fits at once (+ the null block), and

@@ -251,6 +251,20 @@ def pad_for_packing(planes: jax.Array, axis: int, pad_bit: int) -> jax.Array:
     return jnp.pad(planes, cfg, constant_values=pad_bit)
 
 
+@partial(jax.jit, static_argnames=("n_bits", "axis", "pad_bit"))
+def pack_values(values: jax.Array, n_bits: int, axis: int,
+                pad_bit: int) -> jax.Array:
+    """Bipolar values -> packed words: :func:`decompose`,
+    :func:`pad_for_packing` and :func:`pack_planes` as one program.
+
+    Integer-only, so compiling it changes no bit.  Run op by op, the
+    pack's ``(..., Kw, 32)`` intermediates are materialized, and a TPU
+    stores them in (8, 128) tiles at 4x their size; for a 50304 x 2560
+    lm_head on a v5e this program needs 0.48 GiB of temporaries."""
+    planes = pad_for_packing(decompose(values, n_bits), axis, pad_bit)
+    return pack_planes(planes, axis)
+
+
 def pad_correction(k: int, n_w: int, n_x: int) -> int:
     """Additive correction for W-pad-bit=1 / X-pad-bit=0 K padding.
 
@@ -311,9 +325,7 @@ def quantize_pack(x: jax.Array, n_bits: int, pack_axis: int,
     else:
         scale = absmax_scale(x, n_bits, axis=scale_axis, keepdims=True)
     q = quantize_values(x, n_bits, scale)
-    planes = decompose(q, n_bits)
-    planes = pad_for_packing(planes, pack_axis, pad_bit)
-    packed = pack_planes(planes, pack_axis)
+    packed = pack_values(q, n_bits, pack_axis, pad_bit)
     return BipolarTensor(packed=packed, scale=scale.astype(jnp.float32),
                          n_bits=n_bits, shape=tuple(x.shape),
                          pack_axis=pack_axis if pack_axis >= 0 else x.ndim + pack_axis)

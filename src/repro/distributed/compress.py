@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-
 def compressed_psum(tree, axis_name: str, *, bits: int = 8):
     """int-quantized mean-psum of a gradient tree over ``axis_name``.
 

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 
-
 def pipeline_apply(stage_fn, n_stages: int, n_micro: int, axis: str = "pipe"):
     """Build a pipelined forward: ``f(stage_params, x) -> y``.
 

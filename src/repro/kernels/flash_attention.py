@@ -264,6 +264,24 @@ def flash_attention_quantized(q: jax.Array,
 # ---------------------------------------------------------------------------
 
 DEFAULT_PAGED_BQ = 256   # query rows per tile (suffix prefill can be long)
+LANES = 128
+# VMEM at StableLM-3B widths (H=32, Dp=96 lane-padded to 128, kv_bits=8,
+# bs=16) for a 256-row query tile:
+#   q and out blocks   2 * 2 buffers * 32 * 256 * 128 * 2 B  =  8 MiB
+#   m, l, acc scratch  3 * 32 * 256 * 128 * 4 B               = 12 MiB
+#   unpacked bits      2 * 16 * 8 * 32 * 96 * 4 B             =  3 MiB
+# The default scoped limit (16 MiB) is too small; 64 MiB leaves the rest
+# of the 128 MiB VMEM to the surrounding XLA program.
+PAGED_VMEM_LIMIT_BYTES = 64 * 2**20
+
+
+def _head_group(h: int, dp: int) -> int:
+    """Heads per iteration of the paged kernel's head loop: the fewest
+    whose ``Dp`` columns together fill whole 128-lane tiles (4 at Dp=96),
+    so every iteration starts at an aligned dynamic lane offset; all
+    ``h`` heads in one iteration when they cannot be grouped so."""
+    grp = LANES // int(np.gcd(dp, LANES))
+    return grp if h % grp == 0 else h
 
 
 def _head_column(tile, j):
@@ -273,20 +291,40 @@ def _head_column(tile, j):
     return jnp.sum(jnp.where(hit, tile, 0.0), axis=1, keepdims=True)
 
 
+def _dequant_rows(words, n_bits: int, hdp: int):
+    """Pool token rows ``(bs, n_bits * H * Dw)`` uint32, plane-major, ->
+    every head's recovered integers ``(bs, H * Dp)`` f32 (unscaled).
+
+    Plane ``i`` of all heads is the word range ``[i * H * Dw, (i + 1) *
+    H * Dw)``.  The planes are summed while bits still sit on their own
+    axis, so one reshape (half the compile time of one per plane) moves
+    the values to lanes."""
+    bs = words.shape[0]
+    hw = hdp // bipolar.PACK_WIDTH
+    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, 32), 2)
+    acc = None
+    for i in range(n_bits):
+        plane = words[:, i * hw:(i + 1) * hw]
+        bits = (plane[:, :, None] >> shifts) & jnp.uint32(1)
+        term = (bits << (i + 1)).astype(jnp.int32)
+        acc = term if acc is None else acc + term
+    vals = acc.reshape(bs, hdp) - bipolar.max_value(n_bits)
+    return vals.astype(jnp.float32)
+
+
 def _kernel_paged(bt_ref, qp_ref, kp_ref, ks_ref, vs_ref, q_ref, kq_ref,
-                  vq_ref, out_ref, m_ref, l_ref, acc_ref, *,
+                  vq_ref, out_ref, m_ref, l_ref, acc_ref, k_ref, v_ref, *,
                   scale: float, causal: bool, window,
-                  bq: int, bs: int, dp: int, n_bits: int):
+                  h: int, dp: int, n_bits: int):
     del bt_ref  # consumed by the index maps (scalar prefetch)
-    j = pl.program_id(1)
-    jk = pl.program_id(3)
-    nk = pl.num_programs(3)
+    jk = pl.program_id(2)
+    nk = pl.num_programs(2)
 
     @pl.when(jk == 0)
     def _init():
-        m_ref[...] = jnp.full((bq, 1), -1e30, jnp.float32)
-        l_ref[...] = jnp.zeros((bq, 1), jnp.float32)
-        acc_ref[...] = jnp.zeros((bq, dp), jnp.float32)
+        m_ref[...] = jnp.full(m_ref.shape, -1e30, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
     # position mask first: causal + sliding window by absolute position,
     # invalid slots (pos -1: null block / freshly allocated) excluded
@@ -305,24 +343,37 @@ def _kernel_paged(bt_ref, qp_ref, kp_ref, ks_ref, vs_ref, q_ref, kq_ref,
     # guard covers the in-between steps and the padded table entries.
     @pl.when(jnp.max(valid.astype(jnp.int32)) > 0)
     def _update():
-        # one physical block of the pool, routed here by the block
-        # table: kq_ref block is (1, bs, 1, n_bits, dw) -> (bs, n_bits,
-        # dw); the scale blocks carry every head's (bs, H) row
-        k = _dequant_tile(kq_ref[0][:, 0], _head_column(ks_ref[0], j),
-                          n_bits, bs, dp)
-        v = _dequant_tile(vq_ref[0][:, 0], _head_column(vs_ref[0], j),
-                          n_bits, bs, dp)
-        q = q_ref[0, 0]                           # (bq, dp), zero pad cols
-        s = jax.lax.dot_general(q.astype(jnp.float32), k,
-                                (((1,), (1,)), ((), ())), precision=_F32,
-                                preferred_element_type=jnp.float32) * scale
-        s = jnp.where(valid, s, -1e30)
-        _online_softmax_update(s, valid, v, m_ref, l_ref, acc_ref)
+        # one physical block of the pool, routed here by the block table:
+        # every head's planes unpack at once into (bs, H * Dp) columns
+        k_ref[...] = _dequant_rows(kq_ref[0], n_bits, h * dp)
+        v_ref[...] = _dequant_rows(vq_ref[0], n_bits, h * dp)
+        ks, vs = ks_ref[0], vs_ref[0]             # (bs, H) scales
+        grp = _head_group(h, dp)
+
+        def heads(g, carry):
+            # a group of heads starts on a 128-lane boundary; the heads
+            # inside it are static lane slices
+            cols = pl.ds(pl.multiple_of(g * grp * dp, LANES), grp * dp)
+            kg, vg = k_ref[:, cols], v_ref[:, cols]
+            for j in range(grp):
+                hh = g * grp + j
+                kt = kg[:, j * dp:(j + 1) * dp] * _head_column(ks, hh)
+                vt = vg[:, j * dp:(j + 1) * dp] * _head_column(vs, hh)
+                q = q_ref[0, hh]                  # (bq, dp), zero pad cols
+                s = jax.lax.dot_general(
+                    q.astype(jnp.float32), kt, (((1,), (1,)), ((), ())),
+                    precision=_F32, preferred_element_type=jnp.float32) * scale
+                s = jnp.where(valid, s, -1e30)
+                _online_softmax_update(s, valid, vt, m_ref.at[hh],
+                                       l_ref.at[hh], acc_ref.at[hh])
+            return carry
+
+        jax.lax.fori_loop(0, h // grp, heads, 0)
 
     @pl.when(jk == nk - 1)
     def _done():
-        out_ref[0, 0] = (acc_ref[...]
-                         / jnp.maximum(l_ref[...], 1e-20)).astype(out_ref.dtype)
+        out_ref[0] = (acc_ref[...]
+                      / jnp.maximum(l_ref[...], 1e-20)).astype(out_ref.dtype)
 
 
 @functools.partial(
@@ -342,10 +393,12 @@ def flash_attention_paged_quantized(q: jax.Array,
 
     The pool stores fixed-size token blocks shared by all requests; each
     request addresses its blocks through a block table.  The table is a
-    scalar-prefetch operand: the Mosaic grid walks ``(B, H, Gq/bq,
+    scalar-prefetch operand: the Mosaic grid walks ``(B, Gq/bq,
     n_blocks)`` and the K/V block specs index the pool with
     ``table[b, j]``, so HBM only ever moves the blocks a request
     actually owns -- the gather never materializes a contiguous copy.
+    One grid step reads a whole pool block (every head) and loops over
+    the heads inside.
 
     Decode calls carry one query row per GQA group; block-table *suffix
     prefill* folds the suffix length into the query axis (``Gq = G *
@@ -367,7 +420,8 @@ def flash_attention_paged_quantized(q: jax.Array,
       q: ``(B, H, Gq, Dp)`` -- per-kv-head grouped queries (``Gq`` =
         group size x query tokens), zero-padded past the true head dim
         ``d`` (``Dp = 32*ceil(d/32)``); ``Gq`` must tile by ``block``.
-      k_pool/v_pool: ``(n_blocks, bs, H, n_bits, Dp/32)`` uint32 planes.
+      k_pool/v_pool: ``(n_blocks, bs, n_bits * H * Dp/32)`` uint32 token
+        rows, plane-major (:func:`repro.kernels.ops.kv_pool_rows`).
       k_scale/v_scale: ``(n_blocks, bs, H)`` f32 absmax scales.
       pool_pos: ``(n_blocks, bs)`` int32 absolute positions, -1 = empty
         slot (freshly allocated or null block 0).
@@ -378,52 +432,52 @@ def flash_attention_paged_quantized(q: jax.Array,
     Returns ``(B, H, Gq, Dp)``; the caller slices ``[..., :d]``.
     """
     b, h, gq, dp = q.shape
-    n_blocks, bs, hp, nb_bits, dw = k_pool.shape
+    n_blocks, bs, words = k_pool.shape
     nb = block_tables.shape[1]
-    assert (hp, nb_bits, dw * bipolar.PACK_WIDTH) == (h, n_bits, dp), (
-        k_pool.shape, q.shape)
+    assert words * bipolar.PACK_WIDTH == n_bits * h * dp, (
+        k_pool.shape, q.shape, n_bits)
     bq = min(block, gq)
     if gq % bq:
         raise ValueError(f"query rows {gq} not tiled by {bq}")
     kernel = functools.partial(
         _kernel_paged, scale=1.0 / np.sqrt(d), causal=causal, window=window,
-        bq=bq, bs=bs, dp=dp, n_bits=n_bits)
+        h=h, dp=dp, n_bits=n_bits)
     # every block's last two dims are whole array dims or (8, 128)
     # multiples, as Mosaic requires: positions carry a unit axis on the
-    # side that is not tiled, and the scale blocks take all H heads of
-    # a pool block (the kernel selects head j)
+    # side that is not tiled, and K/V/scale blocks are whole pool blocks
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, h, gq // bq, nb),
+        grid=(b, gq // bq, nb),
         in_specs=[
-            pl.BlockSpec((1, bq, 1),
-                         lambda i, j, q, k, bt: (i, q, 0)),         # q_pos
+            pl.BlockSpec((1, bq, 1), lambda i, q, k, bt: (i, q, 0)),  # q_pos
             pl.BlockSpec((1, 1, bs),
-                         lambda i, j, q, k, bt: (bt[i, k], 0, 0)),  # pool_pos
+                         lambda i, q, k, bt: (bt[i, k], 0, 0)),      # pool_pos
             pl.BlockSpec((1, bs, h),
-                         lambda i, j, q, k, bt: (bt[i, k], 0, 0)),  # k_scale
+                         lambda i, q, k, bt: (bt[i, k], 0, 0)),      # k_scale
             pl.BlockSpec((1, bs, h),
-                         lambda i, j, q, k, bt: (bt[i, k], 0, 0)),  # v_scale
-            pl.BlockSpec((1, 1, bq, dp),
-                         lambda i, j, q, k, bt: (i, j, q, 0)),      # q
-            pl.BlockSpec((1, bs, 1, n_bits, dw),
-                         lambda i, j, q, k, bt: (bt[i, k], 0, j, 0, 0)),
-            pl.BlockSpec((1, bs, 1, n_bits, dw),
-                         lambda i, j, q, k, bt: (bt[i, k], 0, j, 0, 0)),
+                         lambda i, q, k, bt: (bt[i, k], 0, 0)),      # v_scale
+            pl.BlockSpec((1, h, bq, dp),
+                         lambda i, q, k, bt: (i, 0, q, 0)),          # q
+            pl.BlockSpec((1, bs, words),
+                         lambda i, q, k, bt: (bt[i, k], 0, 0)),      # k rows
+            pl.BlockSpec((1, bs, words),
+                         lambda i, q, k, bt: (bt[i, k], 0, 0)),      # v rows
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, dp),
-                               lambda i, j, q, k, bt: (i, j, q, 0)),
-        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
-                        pltpu.VMEM((bq, 1), jnp.float32),
-                        pltpu.VMEM((bq, dp), jnp.float32)],
+        out_specs=pl.BlockSpec((1, h, bq, dp),
+                               lambda i, q, k, bt: (i, 0, q, 0)),
+        scratch_shapes=[pltpu.VMEM((h, bq, 1), jnp.float32),
+                        pltpu.VMEM((h, bq, 1), jnp.float32),
+                        pltpu.VMEM((h, bq, dp), jnp.float32),
+                        pltpu.VMEM((bs, h * dp), jnp.float32),
+                        pltpu.VMEM((bs, h * dp), jnp.float32)],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, gq, dp), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=PAGED_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(block_tables, q_pos[:, :, None], pool_pos[:, None, :], k_scale,
       v_scale, q, k_pool, v_pool)

@@ -139,9 +139,7 @@ def quantize_rows(x: jax.Array, n_bits: int, *, pad_bit: int,
         width_scales = bipolar.nested_width_scales(x, qv, n_bits, scale)
     if impl == "reference":
         q = bipolar.quantize_values(x, n_bits, scale)
-        planes = bipolar.decompose(q, n_bits)
-        planes = bipolar.pad_for_packing(planes, -1, pad_bit)
-        packed = bipolar.pack_planes(planes, -1)
+        packed = bipolar.pack_values(q, n_bits, -1, pad_bit)
     else:
         kp = _round_up(k, bipolar.PACK_WIDTH)
         maxv = bipolar.max_value(n_bits)
@@ -491,9 +489,7 @@ def quantize_kv(x: jax.Array, kv_bits: int):
     xf = x.astype(jnp.float32)
     scale = bipolar.absmax_scale(xf, kv_bits, axis=-1, keepdims=True)
     q = bipolar.quantize_values(xf, kv_bits, scale)
-    planes = bipolar.decompose(q, kv_bits)            # (kv_bits, ..., D)
-    planes = bipolar.pad_for_packing(planes, -1, 0)
-    packed = bipolar.pack_planes(planes, -1)          # (kv_bits, ..., Dw)
+    packed = bipolar.pack_values(q, kv_bits, -1, 0)   # (kv_bits, ..., Dw)
     return jnp.moveaxis(packed, 0, -2), scale
 
 
@@ -554,6 +550,26 @@ def kv_cache_attention(q: jax.Array,
     return out[:, :sq, :d]
 
 
+def kv_pool_rows(packed: jax.Array) -> jax.Array:
+    """Packed K/V planes ``(..., H, n_bits, Dw)`` -> the paged pool's
+    token rows ``(..., n_bits * H * Dw)``, plane-major: word ``(i * H +
+    h) * Dw + w`` is word ``w`` of head ``h``'s plane ``i``.
+
+    On a TPU an array's last two dims are stored in (8, 128) tiles, so
+    planes shaped ``(n_bits, Dw)`` = (8, 3) would take 43x their size in
+    HBM; a pool block ``(bs, n_bits * H * Dw)`` is lane-dense."""
+    *lead, h, n_bits, dw = packed.shape
+    return jnp.swapaxes(packed, -3, -2).reshape(*lead, n_bits * h * dw)
+
+
+def kv_pool_planes(rows: jax.Array, h: int, d: int) -> jax.Array:
+    """Inverse of :func:`kv_pool_rows` for ``h`` heads of dim ``d``."""
+    dw = bipolar.packed_words(d)
+    n_bits = rows.shape[-1] // (h * dw)
+    return jnp.swapaxes(
+        rows.reshape(*rows.shape[:-1], n_bits, h, dw), -3, -2)
+
+
 def paged_kv_cache_attention(q: jax.Array,
                              k_pool: jax.Array, k_scale: jax.Array,
                              v_pool: jax.Array, v_scale: jax.Array,
@@ -569,11 +585,12 @@ def paged_kv_cache_attention(q: jax.Array,
     folded in for block-table suffix prefill (causality is by absolute
     ``q_pos``, so multi-token causal queries need no extra plumbing).
     The pool holds fixed-size token blocks shared by every request:
-    ``k_pool/v_pool (n_blocks, bs, H, n_bits, Dw)`` uint32 planes,
-    ``k_scale/v_scale (n_blocks, bs, H, 1)`` f32, ``pool_pos
-    (n_blocks, bs)`` int32 (-1 = empty slot).  ``block_tables (B, NB)``
-    int32 maps each request's logical blocks to physical ids; rows pad
-    with 0, the reserved null block whose positions stay -1.
+    ``k_pool/v_pool (n_blocks, bs, n_bits * H * Dw)`` uint32 token rows
+    (:func:`kv_pool_rows`), ``k_scale/v_scale (n_blocks, bs, H)`` f32,
+    ``pool_pos (n_blocks, bs)`` int32 (-1 = empty slot).
+    ``block_tables (B, NB)`` int32 maps each request's logical blocks to
+    physical ids; rows pad with 0, the reserved null block whose
+    positions stay -1.
 
     Dispatch: pallas | interpret run the block-table-gathering flash
     kernel (the table is a scalar-prefetch operand indexing the pool
@@ -584,26 +601,26 @@ def paged_kv_cache_attention(q: jax.Array,
     """
     impl = impl or default_impl()
     b, h, g, _ = q.shape
-    n_blocks, bs = pool_pos.shape
-    n_bits = k_pool.shape[-2]
+    n_bits = k_pool.shape[-1] // (h * bipolar.packed_words(d))
     if impl == "reference":
         gath = partial(ref.gather_paged_kv, block_tables=block_tables)
         kv_pos = gath(pool_pos[:, :, None])[..., 0]
+        planes = lambda pool: fold_kv_heads(kv_pool_planes(gath(pool), h, d))
+        scales = lambda sc: fold_kv_heads(gath(sc)[..., None])
         o = kv_cache_attention(
             q.reshape(b * h, g, q.shape[-1]),
-            fold_kv_heads(gath(k_pool)), fold_kv_heads(gath(k_scale)),
-            fold_kv_heads(gath(v_pool)), fold_kv_heads(gath(v_scale)),
+            planes(k_pool), scales(k_scale), planes(v_pool), scales(v_scale),
             jnp.repeat(q_pos, h, 0), jnp.repeat(kv_pos, h, 0),
             d=d, causal=causal, window=window, impl=impl)
         return o.reshape(b, h, g, d)
-    dp = k_pool.shape[-1] * bipolar.PACK_WIDTH
+    dp = bipolar.packed_words(d) * bipolar.PACK_WIDTH
     gp = _round_up(g, 8)
     bq = min(q_block or flash_kernel.DEFAULT_PAGED_BQ, gp)
     gp = _round_up(gp, bq)
     qp_arr = _pad_dim(_pad_dim(q, 3, dp), 2, gp)
     q_pos_p = _pad_dim(q_pos, 1, gp, -1)          # pad rows fully masked
     out = flash_kernel.flash_attention_paged_quantized(
-        qp_arr, k_pool, k_scale[..., 0], v_pool, v_scale[..., 0],
+        qp_arr, k_pool, k_scale, v_pool, v_scale,
         pool_pos, block_tables, q_pos_p,
         d=d, n_bits=n_bits, causal=causal, window=window, block=bq,
         interpret=(impl == "interpret"))

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import bipolar
 from repro.core.bipolar import BipolarTensor
 from repro.kernels import ops
 from repro.kernels.ref import apply_act
@@ -313,9 +314,10 @@ def attention_apply(params: dict, x: jax.Array, cfg: ModelConfig, *,
     quant_kv = None           # (k_packed, k_scale, v_packed, v_scale) folded
     if cache is not None and "block_tables" in cache:
         # paged decode / suffix prefill: the cache is a block pool shared
-        # by every request (k/v (n_blocks, bs, H, kv_bits, Dw) planes +
-        # scales), addressed through this batch's block table.  The
-        # ``s`` new tokens of row b land at slots ``length[b] + i`` --
+        # by every request (k/v (n_blocks, bs, kv_bits*H*Dw) token rows +
+        # (n_blocks, bs, H) scales), addressed through this batch's block
+        # table.  The ``s`` new tokens of row b land at slots
+        # ``length[b] + i`` --
         # physically (table[slot // bs], slot % bs) -- then attention
         # runs through the table (ops.paged_kv_cache_attention) with the
         # suffix folded into the query axis; causality is by absolute
@@ -324,12 +326,13 @@ def attention_apply(params: dict, x: jax.Array, cfg: ModelConfig, *,
         # pow2 length bucketing or inactive lanes) are *dropped* at the
         # scatter (routed out of bounds), so they can never touch the
         # null block or a live block's slots.
-        kv_bits = cache["k"].shape[-2]
-        n_blocks = cache["k"].shape[0]
-        blk = cache["k"].shape[1]
+        n_blocks, blk, words = cache["k"].shape
+        kv_bits = words // (hk * bipolar.packed_words(dh))
         bt, ln = cache["block_tables"], cache["length"]
         k_q, k_s = ops.quantize_kv(k, kv_bits)
         v_q, v_s = ops.quantize_kv(v, kv_bits)
+        k_q, v_q = ops.kv_pool_rows(k_q), ops.kv_pool_rows(v_q)
+        k_s, v_s = k_s[..., 0], v_s[..., 0]
         slot = ln[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]  # (B,s)
         valid = pos2d >= 0
         logical = jnp.where(valid, slot // blk, 0)
@@ -559,7 +562,7 @@ def cross_attention_apply(params: dict, x: jax.Array, cfg: ModelConfig, *,
 
 
 def make_kv_cache(cfg: ModelConfig, batch: int, max_len: int, dtype,
-                  kv_bits: Optional[int] = None) -> dict:
+                  kv_bits: Optional[int] = None, paged: bool = False) -> dict:
     """Decode KV cache; for SWA archs the cache is a ring of ``window``.
 
     ``index`` is per batch row: under continuous batching each slot
@@ -569,6 +572,9 @@ def make_kv_cache(cfg: ModelConfig, batch: int, max_len: int, dtype,
     stores packed bipolar-INT bit planes ``(B, L, H, kv_bits, D/32)``
     uint32 + per-(token, head) absmax scales: ``kv_bits`` bits per cache
     element instead of 16, dequantized on read (repro.kernels.ops).
+    ``paged``: the serving pool's layout (``batch`` = blocks, ``max_len``
+    = block size): planes as lane-dense token rows ``(B, L, kv_bits * H
+    * D/32)`` (``ops.kv_pool_rows``) and scales ``(B, L, H)``.
     """
     kv_bits = cfg.kv_bits if kv_bits is None else kv_bits
     length = min(max_len, cfg.window) if cfg.window else max_len
@@ -579,12 +585,17 @@ def make_kv_cache(cfg: ModelConfig, batch: int, max_len: int, dtype,
     }
     if kv_bits:
         assert 1 <= kv_bits <= 8, f"kv_bits={kv_bits} outside 1..8"
-        from repro.core import bipolar
-        packed = shape[:3] + (kv_bits, bipolar.packed_words(cfg.head_dim))
+        dw = bipolar.packed_words(cfg.head_dim)
+        if paged:
+            packed = shape[:2] + (kv_bits * cfg.n_kv_heads * dw,)
+            scale = shape[:3]
+        else:
+            packed = shape[:3] + (kv_bits, dw)
+            scale = shape[:3] + (1,)
         cache["k"] = jnp.zeros(packed, jnp.uint32)
         cache["v"] = jnp.zeros(packed, jnp.uint32)
-        cache["k_scale"] = jnp.zeros(shape[:3] + (1,), jnp.float32)
-        cache["v_scale"] = jnp.zeros(shape[:3] + (1,), jnp.float32)
+        cache["k_scale"] = jnp.zeros(scale, jnp.float32)
+        cache["v_scale"] = jnp.zeros(scale, jnp.float32)
     else:
         cache["k"] = jnp.zeros(shape, dtype)
         cache["v"] = jnp.zeros(shape, dtype)
@@ -603,7 +614,6 @@ def make_cross_cache(cfg: ModelConfig, batch: int, enc_len: int, dtype,
     cache = {"pos": jnp.full((batch, enc_len), -1, jnp.int32)}
     if kv_bits:
         assert 1 <= kv_bits <= 8, f"kv_bits={kv_bits} outside 1..8"
-        from repro.core import bipolar
         packed = shape[:3] + (kv_bits, bipolar.packed_words(cfg.head_dim))
         cache["k"] = jnp.zeros(packed, jnp.uint32)
         cache["v"] = jnp.zeros(packed, jnp.uint32)

@@ -184,16 +184,17 @@ def _apply_block(p, x, cfg, mixer_kind, ffn_kind, *, positions, cache,
 
 
 def _make_cache_for(cfg: ModelConfig, kind: str, batch: int, max_len: int,
-                    dtype, kv_bits=None):
+                    dtype, kv_bits=None, paged: bool = False):
     if kind == "attn":
-        return L.make_kv_cache(cfg, batch, max_len, dtype, kv_bits=kv_bits)
+        return L.make_kv_cache(cfg, batch, max_len, dtype, kv_bits=kv_bits,
+                               paged=paged)
     return S.make_ssm_cache(cfg, batch, dtype)
 
 
 def init_caches(cfg: ModelConfig, batch: int, max_len: int,
                 enc_len: Optional[int] = None,
                 quant: Optional[QuantConfig] = None,
-                state_batch: Optional[int] = None):
+                state_batch: Optional[int] = None, paged: bool = False):
     """Decode caches: {'prelude': [..], 'blocks': stacked-unit caches,
     ['cross': stacked per-unit cross-KV]}.  ``enc_len`` (audio): encoder
     memory length for the projected cross-K/V cache.  ``quant``: its
@@ -203,7 +204,9 @@ def init_caches(cfg: ModelConfig, batch: int, max_len: int,
     The paged serving pool reuses this layout with ``batch=n_blocks,
     max_len=block_size``: every attention leaf's leading (batch, length)
     dims become (block, in-block slot) and requests address blocks
-    through per-request block tables (:mod:`repro.serving.paged_cache`).
+    through per-request block tables (:mod:`repro.serving.paged_cache`);
+    ``paged=True`` stores its packed planes as lane-dense token rows
+    (``layers.make_kv_cache``).
     ``state_batch`` sizes the *fixed-size per-request* state leaves
     independently of the block count: SSM conv+state and enc-dec
     cross-K/V caches get ``state_batch`` rows (the pool's slot rows,
@@ -218,7 +221,7 @@ def init_caches(cfg: ModelConfig, batch: int, max_len: int,
 
     def cache_for(mk):
         return _make_cache_for(cfg, mk, batch if mk == "attn" else sb,
-                               max_len, dt, kvb)
+                               max_len, dt, kvb, paged)
 
     caches = {}
     if prelude_plan:
@@ -455,10 +458,13 @@ def loss_fn(params: dict, batch: dict, cfg: ModelConfig, *,
 
 _QUANT_KEYS = ("wq", "wk", "wv", "wo", "w_up", "w_gate", "w_down",
                "in_proj", "out_proj", "lm_head", "frontend")
+# Elements per eager quantize call (``_quantize_leaf``): 128 MiB f32
+# temporaries, a few alive at a time.
+QUANT_CHUNK_ELEMS = 2**25
 
 
 def quantize_params(params: Any, qcfg: QuantConfig, stacked: bool = False,
-                    _key: str = "") -> Any:
+                    _key: str = "", *, donate: bool = False) -> Any:
     """Replace every quantizable linear weight with packed bipolar planes.
 
     ``stacked=True`` marks subtrees whose leaves carry a leading
@@ -466,6 +472,11 @@ def quantize_params(params: Any, qcfg: QuantConfig, stacked: bool = False,
     out ``(n_units, n_bits, ..., Kw)`` so ``lax.scan`` slices the unit
     axis and each slice is a well-formed packed tensor whose *static*
     metadata (shape, n_bits) describes the per-unit weight.
+
+    ``donate=True`` deletes each float weight as soon as it is packed,
+    so the float and packed trees never exist whole at once (a
+    full-size model on one chip); ``params`` is unusable afterwards,
+    except the leaves it shares with the result.
 
     Router, norms, embeddings and SSM state/conv params stay in bf16
     (DESIGN.md §4 caveats).
@@ -478,21 +489,24 @@ def quantize_params(params: Any, qcfg: QuantConfig, stacked: bool = False,
             sub_stacked = stacked or k in ("blocks", "cross")
             if k in _QUANT_KEYS and isinstance(v, dict) and "w" in v \
                     and not isinstance(v["w"], BipolarTensor):
-                out[k] = {"w": _quantize_leaf(v["w"], qcfg, stacked)}
+                out[k] = {"w": _quantize_leaf(v["w"], qcfg, stacked, donate)}
             elif k in ("w_up", "w_gate", "w_down") and isinstance(v, jax.Array) \
                     and v.ndim >= 3:
-                out[k] = _quantize_leaf(v, qcfg, stacked)  # stacked MoE experts
+                # stacked MoE experts
+                out[k] = _quantize_leaf(v, qcfg, stacked, donate)
             else:
-                out[k] = quantize_params(v, qcfg, sub_stacked, k)
+                out[k] = quantize_params(v, qcfg, sub_stacked, k,
+                                         donate=donate)
         return out
     if isinstance(params, (list, tuple)):
-        return type(params)(quantize_params(v, qcfg, stacked, _key)
+        return type(params)(quantize_params(v, qcfg, stacked, _key,
+                                            donate=donate)
                             for v in params)
     return params
 
 
-def _quantize_leaf(w: jax.Array, qcfg: QuantConfig,
-                   stacked: bool) -> BipolarTensor:
+def _quantize_leaf(w: jax.Array, qcfg: QuantConfig, stacked: bool,
+                   donate: bool = False) -> BipolarTensor:
     """Pack a weight leaf ``(*lead, N, K)`` along K.
 
     Unstacked: packed ``(n_bits, *lead, N, Kw)``, static shape = w.shape.
@@ -506,41 +520,44 @@ def _quantize_leaf(w: jax.Array, qcfg: QuantConfig,
     hands ops a plane-leading tensor that ``bipolar.nested_slice`` can
     serve at any width k <= w_bits.
 
-    A stacked leaf is quantized one scan unit at a time, so its f32 copy
-    and the clip-search temporaries exist for one layer only: at
-    published widths a whole stacked leaf in f32 (StableLM-3B's gate,
-    32 x 6912 x 2560 x 4 B = 2.3 GB) would not fit beside the bf16 tree
-    on a 16 GB chip.  Every step is per row, so this equals quantizing
-    the leaf whole.  The ops run eagerly, not under one jit: XLA would
-    rewrite the per-row division in ``quantize_values`` into a
-    reciprocal multiply and move a few rounded integers.
+    The leaf is quantized in row chunks of at most
+    ``QUANT_CHUNK_ELEMS`` elements, each scan unit on its own, so the
+    f32 copy and the clip-search temporaries stay small: at published
+    widths a whole stacked leaf in f32 (StableLM-3B's gate, 32 x 6912 x
+    2560 x 4 B = 2.3 GB) would not fit beside the bf16 tree on a 16 GB
+    chip.  Every step is per row, so this equals quantizing the leaf
+    whole.  The ops run eagerly, not under one jit: XLA would rewrite
+    the per-row division in ``quantize_values`` into a reciprocal
+    multiply and move a few rounded integers.  Each chunk is waited
+    for: the host dispatches eager ops faster than the device runs
+    large ones, and a queued op's output is allocated when it is
+    queued.  ``donate`` deletes ``w`` once it is packed.
     """
     shape = tuple(w.shape)
     n_bits = qcfg.w_bits
-    units = [w[u] for u in range(shape[0])] if stacked else [w]
-    parts = [ops.quantize_rows(u.reshape(-1, shape[-1]).astype(jnp.float32),
-                               n_bits, pad_bit=1, impl="reference",
-                               scale_search=True)
-             for u in units]
-    rest = shape[1:-1] if stacked else shape[:-1]
-    kw = parts[0].packed.shape[-1]
-    packed = [p.packed.reshape(n_bits, *rest, kw) for p in parts]
-    scale = [p.scale.reshape(*rest, 1) for p in parts]
-    width_scales = None
-    if parts[0].width_scales is not None:
-        width_scales = [p.width_scales.reshape(n_bits, *rest, 1)
-                        for p in parts]
-    if stacked:
-        packed, scale = jnp.stack(packed), jnp.stack(scale)
-        if width_scales is not None:
-            width_scales = jnp.stack(width_scales)
-        static_shape = shape[1:]
-    else:
-        packed, scale = packed[0], scale[0]
-        if width_scales is not None:
-            width_scales = width_scales[0]
-        static_shape = shape
-    return BipolarTensor(packed=packed, scale=scale, n_bits=n_bits,
-                         shape=static_shape,
-                         pack_axis=len(static_shape) - 1,
-                         width_scales=width_scales)
+    step = max(1, QUANT_CHUNK_ELEMS // shape[-1])
+    packed, scale, width_scales = [], [], []
+    for u in range(shape[0]) if stacked else [None]:
+        rows = (w if u is None else w[u]).reshape(-1, shape[-1])
+        ts = [jax.block_until_ready(ops.quantize_rows(
+                  rows[r:r + step].astype(jnp.float32), n_bits, pad_bit=1,
+                  impl="reference", scale_search=True))
+              for r in range(0, rows.shape[0], step)]
+        packed.append(jnp.concatenate([t.packed for t in ts], 1))
+        scale.append(jnp.concatenate([t.scale for t in ts], 0))
+        if ts[0].width_scales is not None:
+            width_scales.append(
+                jnp.concatenate([t.width_scales for t in ts], 1))
+    if donate:
+        w.delete()
+    static_shape = shape[1:] if stacked else shape
+    rest, kw = static_shape[:-1], packed[0].shape[-1]
+    join = jnp.stack if stacked else (lambda xs: xs[0])
+    return BipolarTensor(
+        packed=join([p.reshape(n_bits, *rest, kw) for p in packed]),
+        scale=join([s.reshape(*rest, 1) for s in scale]),
+        n_bits=n_bits, shape=static_shape,
+        pack_axis=len(static_shape) - 1,
+        width_scales=(join([s.reshape(n_bits, *rest, 1)
+                            for s in width_scales])
+                      if width_scales else None))

@@ -10,12 +10,14 @@ block tables.  Concurrent requests then scale with *tokens actually
 resident x bits/element*, not ``n_slots x max_len x 16``.
 
 Layout.  The pool reuses :func:`repro.models.model.init_caches` with
-``batch=n_blocks, max_len=block_size``: every attention cache leaf's
-leading (batch, length) dims become (physical block, in-block slot) --
-``k``/``v`` are ``(n_blocks, block_size, H, kv_bits, D/32)`` uint32 bit
-planes (stacked scan units carry a leading ``n_units`` dim), scales are
-``(n_blocks, block_size, H, 1)`` f32 and ``pos`` is ``(n_blocks,
-block_size)`` int32.  One *logical* block id addresses the same physical
+``batch=n_blocks, max_len=block_size, paged=True``: every attention
+cache leaf's leading (batch, length) dims become (physical block,
+in-block slot) -- ``k``/``v`` are ``(n_blocks, block_size, kv_bits * H
+* D/32)`` uint32 token rows of bit planes (``ops.kv_pool_rows``: a
+TPU stores the last two dims in (8, 128) tiles, so ``(kv_bits, D/32)``
+trailing dims would pad tens of times over), scales are ``(n_blocks,
+block_size, H)`` f32 and ``pos`` is ``(n_blocks, block_size)`` int32
+(stacked scan units carry a leading ``n_units`` dim).  One *logical* block id addresses the same physical
 index in every layer's pool, so a request owns a single block table.
 
 Block 0 is the reserved **null block**: never allocated, its positions
@@ -107,6 +109,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import ops
 from repro.models import model as M
 from repro.models.config import ModelConfig, QuantConfig, effective_kv_bits
 from repro.obs.metrics import MetricsRegistry
@@ -348,7 +351,8 @@ class PagedKVPool:
                       if self.needs_slots else None)
         self.caches = M.init_caches(
             cfg, n_blocks, block_size, enc_len=enc_len, quant=quant,
-            state_batch=(n_state_slots + 1) if self.needs_slots else None)
+            state_batch=(n_state_slots + 1) if self.needs_slots else None,
+            paged=True)
         # LIFO free list, block 0 reserved as the null block
         self._free = list(range(n_blocks - 1, 0, -1))
         self._ref: dict = {}            # block id -> refcount (>= 0)
@@ -951,7 +955,13 @@ class PagedKVPool:
         assert nb == self.blocks_for(max(n_tokens, 1)), (nb, n_tokens)
         idx = jnp.asarray(block_ids, jnp.int32)
 
-        def copy(pool_leaf, single_leaf, stacked):
+        def copy(key, pool_leaf, single_leaf, stacked):
+            # contiguous planes (.., H, n_bits, Dw) / scales (.., H, 1)
+            # -> the pool's token rows / (.., H) scales
+            if key in ("k", "v"):
+                single_leaf = ops.kv_pool_rows(single_leaf)
+            elif key in ("k_scale", "v_scale"):
+                single_leaf = single_leaf[..., 0]
             if stacked:
                 u = pool_leaf.shape[0]
                 assert single_leaf.shape[2] >= nb * bs, \
@@ -967,7 +977,7 @@ class PagedKVPool:
         for (pc, stacked), (sc, _) in zip(self._attn_caches(),
                                           self._attn_caches(single)):
             for key in _KV_KEYS:
-                pc[key] = copy(pc[key], sc[key], stacked)
+                pc[key] = copy(key, pc[key], sc[key], stacked)
 
     _STEP_KEYS = ("block_tables", "length", "block_offset", "slots")
 

@@ -77,9 +77,11 @@ def _paged_inputs(bits, *, B=2, H=3, G=2, sq=1, d=16, bs=8, n_blocks=12,
     # positions (the layers.attention_apply fold order)
     q_pos = jnp.asarray([[ln - sq + (r % sq) for r in range(G * sq)]
                          for ln in lens], jnp.int32)
-    paged = (q, jnp.asarray(k_pool), jnp.asarray(k_sc), jnp.asarray(v_pool),
-             jnp.asarray(v_sc), jnp.asarray(pool_pos), jnp.asarray(tables),
-             q_pos)
+    # the pool's own layout: lane-dense token rows, (.., H) scales
+    rows = lambda a: ops.kv_pool_rows(jnp.asarray(a))
+    paged = (q, rows(k_pool), jnp.asarray(k_sc[..., 0]), rows(v_pool),
+             jnp.asarray(v_sc[..., 0]), jnp.asarray(pool_pos),
+             jnp.asarray(tables), q_pos)
     contiguous = (k_cat, ksc_cat, v_cat, vsc_cat, pos_cat)
     return paged, contiguous
 

@@ -98,11 +98,60 @@ def test_paged_attention_compiles_for_v5e(one_chip, batch, q_rows):
         return ops.paged_kv_cache_attention(q, kp, ks, vp, vs, pos, bt, qpos,
                                             d=D_HEAD, impl="pallas")
 
-    planes = sds((n_blocks, BLOCK_SIZE, N_HEADS, KV_BITS, dw), jnp.uint32)
-    scales = sds((n_blocks, BLOCK_SIZE, N_HEADS, 1), jnp.float32)
+    planes = sds((n_blocks, BLOCK_SIZE, KV_BITS * N_HEADS * dw), jnp.uint32)
+    scales = sds((n_blocks, BLOCK_SIZE, N_HEADS), jnp.float32)
     text = _compile(
         fn, sds((batch, N_HEADS, q_rows, D_HEAD), jnp.bfloat16),
         planes, scales, planes, scales,
         sds((n_blocks, BLOCK_SIZE), jnp.int32),
         sds((batch, table), jnp.int32), sds((batch, q_rows), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("batch", [1, 8])
+def test_paged_decode_step_fits_beside_its_pool_on_v5e(one_chip, batch):
+    """A TPU stores an array's last two dims in (8, 128) tiles.  Pool
+    planes shaped ``(..., kv_bits, D/32)`` = (8, 3) took 43x their size
+    there, and a one-request decode step asked for 32 GiB of
+    temporaries.  With the pool's lane-dense token rows the whole decode
+    step (StableLM-3B widths, depth cut to 2 layers) needs less
+    temporary memory than the pool itself."""
+    import dataclasses
+    import numpy as np
+    from repro.configs import get_config
+    from repro.models import model as M
+    from repro.serving import engine as E
+
+    cfg = dataclasses.replace(get_config("stablelm-3b"), n_layers=2)
+    quant = dataclasses.replace(cfg.quant, kv_bits=KV_BITS)
+    n_blocks, table = 1025, 8
+
+    def sds(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = jax.eval_shape(lambda k: M.quantize_params(
+        M.init_params(cfg, k), quant), jax.random.PRNGKey(0))
+    pool = jax.eval_shape(lambda: M.init_caches(
+        cfg, n_blocks, BLOCK_SIZE, quant=quant, paged=True))
+    pool_bytes = sum(np.prod(x.shape) * x.dtype.itemsize
+                     for x in jax.tree.leaves(pool))
+
+    def step_cache(c):
+        u = c["k"].shape[0]
+        return dict(c, block_tables=jax.ShapeDtypeStruct(
+                        (u, batch, table), jnp.int32),
+                    length=jax.ShapeDtypeStruct((u, batch), jnp.int32),
+                    block_offset=jax.ShapeDtypeStruct((u, batch), jnp.int32))
+
+    caches = {"blocks": [step_cache(c) for c in pool["blocks"]]}
+    tok = jax.ShapeDtypeStruct((batch, 1), jnp.int32)
+    ops.set_impl("pallas")
+    try:
+        compiled = E.serve_step.lower(
+            sds(params), sds({"tokens": tok, "positions": tok}),
+            sds(caches), cfg, quant).compile()
+    finally:
+        ops.set_impl(None)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes

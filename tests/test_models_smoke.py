@@ -84,12 +84,18 @@ def test_paper_w2a8_forward_finite(arch_setup):
     assert np.all(np.isfinite(np.asarray(x, dtype=np.float32))), cfg.name
 
 
-def test_quantize_params_matches_whole_leaf_quantization(arch_setup):
-    """``quantize_params`` packs scan-stacked leaves one unit at a time
-    under jit; every packed word, scale and width scale must equal the
-    eager whole-leaf quantization of the same leaf."""
+@pytest.mark.parametrize("chunk_elems", [None, 40 * 64],
+                         ids=["default_chunks", "uneven_row_chunks"])
+def test_quantize_params_matches_whole_leaf_quantization(arch_setup,
+                                                         chunk_elems,
+                                                         monkeypatch):
+    """``quantize_params`` packs scan-stacked leaves one unit at a time,
+    in row chunks; every packed word, scale and width scale must equal
+    the whole-leaf quantization of the same leaf."""
     from repro.core.bipolar import BipolarTensor
     from repro.kernels import ops
+    if chunk_elems:
+        monkeypatch.setattr(M, "QUANT_CHUNK_ELEMS", chunk_elems)
     cfg, params = arch_setup
     qcfg = dataclasses.replace(cfg.quant, w_bits=cfg.quant.w_bits or 4)
     qparams = M.quantize_params(params, qcfg)
@@ -119,3 +125,27 @@ def test_quantize_params_matches_whole_leaf_quantization(arch_setup):
                                       np.asarray(ws))
         checked += 1
     assert checked >= 2, (cfg.name, checked)
+
+
+def test_quantize_params_donate_frees_packed_leaves_only(arch_setup):
+    """``donate=True`` gives the same packed tree and deletes exactly the
+    float weights it packed; the leaves it keeps stay usable."""
+    from repro.core.bipolar import BipolarTensor
+    cfg, params = arch_setup
+    qcfg = dataclasses.replace(cfg.quant, w_bits=cfg.quant.w_bits or 4)
+    want = M.quantize_params(params, qcfg)
+    fresh = jax.tree.map(jnp.copy, params)
+    got = M.quantize_params(fresh, qcfg, donate=True)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    is_q = lambda x: isinstance(x, BipolarTensor)
+    kept = {id(x) for x in jax.tree.leaves(got) if not x.is_deleted()}
+    n_deleted = 0
+    for leaf in jax.tree.leaves(fresh):
+        if leaf.is_deleted():
+            n_deleted += 1
+        else:
+            assert id(leaf) in kept, cfg.name
+    assert n_deleted == sum(
+        1 for q in jax.tree.leaves(want, is_leaf=is_q) if is_q(q)), cfg.name

@@ -120,9 +120,10 @@ def hooks_per_step() -> float:
     traces = eng.obs.tracer.traces.values()
     chunks = sum(tr.n_chunks for tr in traces)
     # per request: submit + admit + decode_begin + finish; per token:
-    # on_token; per chunk: on_chunk; per step: on_step + one dispatch
+    # on_token; per chunk: on_chunk; per step: on_step + one dispatch,
+    # and seven no-op spans of three calls each (span, enter, exit)
     calls = (4 * len(reqs) + reg.value("repro_engine_tokens")
-             + chunks + 2 * steps)
+             + chunks + (2 + 7 * 3) * steps)
     return calls / max(steps, 1)
 
 

@@ -378,6 +378,7 @@ def apmm_fused_linear(x: jax.Array, a_scale: jax.Array, bp: jax.Array,
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="apmm_fused_linear",
     )(*operands)
 
 

@@ -479,6 +479,7 @@ def flash_attention_paged_quantized(q: jax.Array,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=PAGED_VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="flash_attention_paged_quantized",
     )(block_tables, q_pos[:, :, None], pool_pos[:, None, :], k_scale,
       v_scale, q, k_pool, v_pool)
 

@@ -3,7 +3,7 @@
 The engine, scheduler, and pool do not talk to the registry or tracer
 directly on timed paths -- they call lifecycle hooks on a ``ServingObs``
 (``on_submit`` / ``on_admit`` / ``on_token`` / ``on_preempt`` /
-``on_finish`` / ``on_step`` / ``on_dispatch``), which owns:
+``on_finish`` / ``on_step`` / ``on_dispatch`` / ``on_tables``), which owns:
 
 * a :class:`~repro.obs.metrics.MetricsRegistry` (shared with the pool
   and scheduler, so every counter lives in ONE namespace),
@@ -11,11 +11,24 @@ directly on timed paths -- they call lifecycle hooks on a ``ServingObs``
   trees, and
 * the **engine's clock**: the engine binds its injectable ``clock`` to
   the facade at construction, so every timestamp -- TTFT, inter-token,
-  span edges, step durations -- is deterministic under an injected
+  request span edges -- is deterministic under an injected
   test clock (the same one deadline expiry already uses).
 
+Step phases are host spans on the *profiler's* clock instead
+(``span(name, **args)``, a ``jax.profiler.TraceAnnotation``): they
+share the device trace's timeline, so a profiler trace places each
+phase of a step (``engine.step`` > ``sched.plan``, ``engine.prepare``
+> ``pool.step_caches``, ``engine.dispatch``, ``engine.fetch``,
+``engine.advance``) beside the device work it waits for.  They record
+nothing unless a profiler trace is running.  While one runs, the facade
+also keeps the trace's summary in process (``profiled``): each span
+name's count and host seconds, and the block-table entries the traced
+steps dispatched, so the process that captured a trace reads those
+numbers without parsing the trace file.
+
 ``NULL_OBS`` is the disabled twin: a stateless singleton whose hooks
-are constant no-ops (``enabled = False``).  The engine's hot path calls
+are constant no-ops (``enabled = False``) and whose ``span`` returns one
+shared no-op context manager.  The engine's hot path calls
 the cheap per-event hooks unconditionally (one attribute access + one
 no-op call, no clock read, no allocation) and guards anything that
 would *compute* (per-step gauge math, forward-pass timing) behind
@@ -31,16 +44,27 @@ standalone, without an engine's ``on_submit``).
 
 from __future__ import annotations
 
+import dataclasses
 import time
 from typing import Any, Callable, Optional
 
 import numpy as np
 
-from repro.obs.metrics import (LATENCY_BUCKETS, TOKEN_BUCKETS,
-                               MetricsRegistry)
+from repro.obs.metrics import TOKEN_BUCKETS, MetricsRegistry
 from repro.obs.trace import Tracer
 
 __all__ = ["ServingObs", "NULL_OBS"]
+
+
+@dataclasses.dataclass
+class Profiled:
+    """What the step loop did while a profiler trace recorded: per span
+    name, ``[count, host seconds]``; and the block-table entries its
+    paged dispatches walked, and of those the entries that held a
+    sequence's blocks."""
+    spans: dict = dataclasses.field(default_factory=dict)
+    entries_live: int = 0
+    entries_walked: int = 0
 
 
 class ServingObs:
@@ -68,8 +92,6 @@ class ServingObs:
             "repro_request_queue_wait_seconds",
             "time spent waiting (initial queue + re-queues after "
             "preemption)")
-        self._h_step = r.histogram(
-            "repro_engine_step_seconds", "engine step wall time")
         # lifecycle counters
         self._c_submitted = r.counter(
             "repro_requests_submitted", "requests handed to submit()")
@@ -91,20 +113,19 @@ class ServingObs:
             "repro_engine_running", "requests currently running")
         self._g_waiting = r.gauge(
             "repro_engine_waiting", "requests queued for admission")
-        self._g_lanes = r.gauge(
-            "repro_engine_batch_lanes",
-            "dispatch lanes by kind (bucket padding waste = padded)",
-            labelnames=("kind",))
-        self._g_lanes_live = self._g_lanes.labels(kind="live")
-        self._g_lanes_pad = self._g_lanes.labels(kind="padded")
-        self._g_pad_waste = r.gauge(
-            "repro_engine_padding_waste",
-            "fraction of dispatched token slots that were padding")
-        self._g_chunk_util = r.gauge(
-            "repro_engine_chunk_budget_utilization",
-            "fraction of the chunk budget the step's plan used")
         self._g_occupancy = r.gauge(
             "repro_pool_occupancy", "used / usable pool blocks")
+        # block-table entries the paged attention grid walks, and how
+        # many of them hold a sequence's blocks (the rest pad the table
+        # to its bucketed width and point at the null block)
+        c_tables = r.counter(
+            "repro_engine_table_entries",
+            "block-table entries dispatched, by kind (walked = batch "
+            "lanes x table width; live = filled from a sequence)",
+            labelnames=("kind",))
+        self._c_entries_live = c_tables.labels(kind="live")
+        self._c_entries_walked = c_tables.labels(kind="walked")
+        self.profiled = Profiled()
         # MoE capacity pressure (per forward dispatch, fed by the
         # engine's moe_stats-specialized steps)
         self._h_moe_load = r.histogram(
@@ -217,23 +238,39 @@ class ServingObs:
                 chunk_budget: Optional[int] = None,
                 occupancy: Optional[float] = None) -> None:
         self._c_steps.inc()
-        self._h_step.observe(self.clock() - t0)
         self._g_running.set(running)
         self._g_waiting.set(waiting)
-        if chunk_budget:
-            self._g_chunk_util.set((chunk_used or 0) / chunk_budget)
         if occupancy is not None:
             self._g_occupancy.set(occupancy)
 
     def on_dispatch(self, *, live: int, lanes: int,
                     tok_live: int, tok_lanes: int) -> None:
-        """Record one forward dispatch's bucket-padding waste:
-        ``live`` real lanes padded to ``lanes`` bucket lanes, carrying
-        ``tok_live`` real tokens of ``tok_lanes`` dispatched slots."""
-        self._g_lanes_live.set(live)
-        self._g_lanes_pad.set(lanes - live)
-        if tok_lanes:
-            self._g_pad_waste.set(1.0 - tok_live / tok_lanes)
+        """One forward dispatch: ``live`` real lanes padded to ``lanes``
+        bucket lanes, carrying ``tok_live`` real tokens of ``tok_lanes``
+        dispatched slots.  Nothing here keeps it; a subclass that reads
+        bucket padding (the benchmark's) overrides it."""
+
+    def on_tables(self, *, live: int, walked: int) -> None:
+        """One paged dispatch's block tables: ``walked`` entries (batch
+        lanes x table width) of which ``live`` hold a sequence's
+        blocks."""
+        self._c_entries_live.inc(live)
+        self._c_entries_walked.inc(walked)
+        from jax.profiler import TraceAnnotation
+        if TraceAnnotation.is_enabled():
+            self.profiled.entries_live += live
+            self.profiled.entries_walked += walked
+
+    def span(self, name: str, **args):
+        """A host span on the profiler's clock: a
+        ``jax.profiler.TraceAnnotation`` (a ``StepTraceAnnotation``
+        when ``args`` holds ``step_num``) that also adds its host
+        seconds to ``profiled``.  Outside a profiler trace it is the
+        shared no-op span; ``set_metadata`` adds args known later."""
+        from jax.profiler import TraceAnnotation
+        if not TraceAnnotation.is_enabled():
+            return _NO_SPAN
+        return _ProfiledSpan(self.profiled.spans, name, args)
 
     def on_moe(self, stats: Any) -> None:
         """Record one forward pass's MoE capacity telemetry: ``stats``
@@ -255,6 +292,52 @@ class ServingObs:
             self._g_moe_util.set(float(load.sum()) / cap)
 
 
+class _NoSpan:
+    """The span of a disabled facade: enters, exits and takes metadata
+    without reading a clock."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **args):
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+class _ProfiledSpan:
+    """A profiler annotation that adds its host seconds to the
+    ``[count, seconds]`` of its name in ``totals``."""
+
+    __slots__ = ("_ann", "_rec", "_t0")
+
+    def __init__(self, totals: dict, name: str, args: dict):
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+        cls = StepTraceAnnotation if "step_num" in args else TraceAnnotation
+        self._ann = cls(name, **args)
+        self._rec = totals.setdefault(name, [0, 0.0])
+        self._t0 = 0.0
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self._rec[0] += 1
+        self._rec[1] += time.perf_counter() - self._t0
+        return self._ann.__exit__(*exc)
+
+    def set_metadata(self, **args):
+        self._ann.set_metadata(**args)
+
+
 class _NullObs:
     """Disabled twin of :class:`ServingObs`: every hook is a constant
     no-op -- no clock reads, no allocations, nothing retained.  One
@@ -264,6 +347,7 @@ class _NullObs:
     enabled = False
     registry = None
     tracer = None
+    profiled = None
 
     def t(self):
         return 0.0
@@ -294,6 +378,12 @@ class _NullObs:
 
     def on_dispatch(self, **kw):
         pass
+
+    def on_tables(self, **kw):
+        pass
+
+    def span(self, name, **args):
+        return _NO_SPAN
 
     def on_moe(self, stats):
         pass

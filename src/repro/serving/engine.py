@@ -56,11 +56,15 @@ refcount path mid-prefill or mid-decode.
 through a :class:`repro.obs.ServingObs` facade -- per-request lifecycle
 traces (queued/running/chunk_prefill/decode spans, token instants,
 TTFT/inter-token histograms, Perfetto export) plus step-loop gauges
-(batch lanes live vs padded, chunk-budget utilization, pool occupancy)
-in the SAME metrics registry the pool's and scheduler's counters live
-in, so ``report()``, ``registry.render()``, and the benchmarks can
-never disagree.  Every timestamp goes through the engine's injectable
-``clock`` (traces are deterministic under test), and the default is
+and counters (running and waiting requests, pool occupancy, block-table
+entries live vs walked) in the SAME metrics registry the pool's and
+scheduler's counters live in, so ``report()``, ``registry.render()``,
+and the benchmarks can never disagree.  Every timestamp goes through
+the engine's injectable ``clock`` (traces are deterministic under
+test); the paged step's phases are also marked as profiler spans
+(``engine.step``, ``sched.plan``, ``engine.prepare``,
+``pool.step_caches``, ``engine.dispatch``, ``engine.fetch``,
+``engine.advance``) on the device trace's clock.  The default is
 the no-op ``NULL_OBS`` sink: hooks cost one constant no-op call, no
 clock read, no allocation -- the hot path and token-identity are
 untouched when observability is off.
@@ -1125,54 +1129,70 @@ class Engine:
         return min(_next_pow2(n), self.max_batch)
 
     def _paged_step(self) -> bool:
+        """One step of the paged engine, its phases marked with profiler
+        spans (``obs.span``): ``engine.step`` holds ``sched.plan``, then
+        per dispatch ``engine.prepare`` (with ``pool.step_caches``),
+        ``engine.dispatch`` and ``engine.fetch``, then
+        ``engine.advance``."""
         sch = self.scheduler
         obs = self.obs
         t0 = obs.t() if obs.enabled else 0.0
-        self._expire()
-        if self.validate_every is not None and self.steps \
-                and self.steps % self.validate_every == 0:
-            self._watchdog()
-        try:
-            if self.chunk_tokens is None:
-                # whole-prompt mode: admission prefills, the step decodes
-                sch.admit(self._paged_prefill)
-                if not sch.running:
-                    # fault-free, an empty step means an empty engine;
-                    # with injection on, an admission race/rollback can
-                    # leave work waiting -- report it so run() retries
-                    return self.faults.enabled and sch.has_work
-                sch.ensure_append_capacity()  # reclaims out-of-window too
-                plan = [(s, 1) for s in sch.running]
-            else:
-                sch.admit_chunked()
-                plan = sch.ensure_step_capacity(sch.plan_step())
-                if not plan:
-                    return self.faults.enabled and sch.has_work
-        except PoolExhausted:
-            # a transient pool fault the scheduler could not absorb by
-            # preempting (e.g. injected exhaustion with one request
-            # left).  Alloc is atomic and the rollback paths ran, so
-            # state is intact: consume the step and retry on the next
-            # one.  Grown blocks stay owned by their seqs (reused next
-            # step, no leak)
-            self._c_fault_steps.inc()
-            self.steps += 1
-            return sch.has_work
-        chunk_used = 0
-        if obs.enabled and self.chunk_tokens is not None:
-            chunk_used = sum(n for s, n in plan if s.prefilling)
-        tf0 = obs.t() if obs.enabled else 0.0
-        rows = self._forward_plan(plan)
-        tf1 = obs.t() if obs.enabled else 0.0
-        self._advance(plan, rows, tf0, tf1)
-        if obs.enabled:
-            self.pool.sync_gauges()
-            obs.on_step(
-                t0, running=len(sch.running), waiting=len(sch.waiting),
-                chunk_used=chunk_used, chunk_budget=self.chunk_tokens,
-                occupancy=(self.pool.used_blocks
-                           / max(self.pool.n_usable, 1)
-                           if self.pool.needs_blocks else None))
+        with obs.span("engine.step", step_num=self.steps) as step_span:
+            with obs.span("sched.plan"):
+                self._expire()
+                if self.validate_every is not None and self.steps \
+                        and self.steps % self.validate_every == 0:
+                    self._watchdog()
+                try:
+                    if self.chunk_tokens is None:
+                        # whole-prompt mode: admission prefills, the step
+                        # decodes
+                        sch.admit(self._paged_prefill)
+                        if not sch.running:
+                            # fault-free, an empty step means an empty
+                            # engine; with injection on, an admission
+                            # race/rollback can leave work waiting --
+                            # report it so run() retries
+                            return self.faults.enabled and sch.has_work
+                        # also reclaims blocks out of the window
+                        sch.ensure_append_capacity()
+                        plan = [(s, 1) for s in sch.running]
+                    else:
+                        sch.admit_chunked()
+                        plan = sch.ensure_step_capacity(sch.plan_step())
+                        if not plan:
+                            return self.faults.enabled and sch.has_work
+                except PoolExhausted:
+                    # a transient pool fault the scheduler could not
+                    # absorb by preempting (e.g. injected exhaustion with
+                    # one request left).  Alloc is atomic and the
+                    # rollback paths ran, so state is intact: consume the
+                    # step and retry on the next one.  Grown blocks stay
+                    # owned by their seqs (reused next step, no leak)
+                    self._c_fault_steps.inc()
+                    self.steps += 1
+                    return sch.has_work
+            chunk_used = 0
+            if obs.enabled:
+                fused = self._bucketable and any(n > 1 for _, n in plan)
+                step_span.set_metadata(
+                    kind="fused" if fused else "decode", lanes=len(plan))
+                if self.chunk_tokens is not None:
+                    chunk_used = sum(n for s, n in plan if s.prefilling)
+            tf0 = obs.t() if obs.enabled else 0.0
+            rows = self._forward_plan(plan)
+            tf1 = obs.t() if obs.enabled else 0.0
+            with obs.span("engine.advance"):
+                self._advance(plan, rows, tf0, tf1)
+                if obs.enabled:
+                    self.pool.sync_gauges()
+                    obs.on_step(
+                        t0, running=len(sch.running),
+                        waiting=len(sch.waiting), chunk_used=chunk_used,
+                        chunk_budget=self.chunk_tokens,
+                        occupancy=(self.pool.used_blocks
+                                   / max(self.pool.n_usable, 1)
+                                   if self.pool.needs_blocks else None))
         return True
 
     def _forward_plan(self, plan) -> list:
@@ -1246,38 +1266,46 @@ class Engine:
         # O(window/block_size) however long the generation runs
         nb = min(_next_pow2(max(len(s.blocks) for s in running) or 1),
                  self.n_batch_blocks)
-        if self.obs.enabled:
-            self.obs.on_dispatch(live=len(running), lanes=bb,
-                                 tok_live=len(running), tok_lanes=bb)
-        toks = np.zeros(bb, np.int32)
-        pos = np.full(bb, -1, np.int32)       # pad lanes: masked everywhere
-        lens = np.zeros(bb, np.int32)
-        tables = np.zeros((bb, nb), np.int32)  # 0 = the null block
-        offsets = np.zeros(bb, np.int32)       # reclaimed logical blocks
-        slot_ids = np.full(bb, -1, np.int32)   # pad lanes: no slot
-        for i, seq in enumerate(running):
-            toks[i], pos[i], lens[i] = seq.last_tok, seq.length, seq.length
-            tables[i, :len(seq.blocks)] = seq.blocks
-            offsets[i] = seq.freed_prefix
-            slot_ids[i] = seq.slot
-        jpos = jnp.asarray(pos)[:, None]
-        if self.cfg.family == "vlm":
-            jpos = jnp.broadcast_to(jpos[None], (3, bb, 1))
-        batch = {"tokens": jnp.asarray(toks)[:, None], "positions": jpos}
-        caches = self.pool.step_caches(
-            tables, lens, block_offsets=offsets,
-            slots=slot_ids if self.pool.slots is not None else None)
-        quant = self._quant_for(running[0].precision)
-        if self._moe_telemetry:
-            logits, caches, mst = serve_step(self.params, batch, caches,
-                                             self.cfg, quant,
-                                             moe_stats=True)
-            self.obs.on_moe(mst)
-        else:
-            logits, caches = serve_step(self.params, batch, caches,
-                                        self.cfg, quant)
-        self.pool.absorb(caches)
-        return np.asarray(logits, np.float32)
+        obs = self.obs
+        with obs.span("engine.prepare"):
+            if obs.enabled:
+                obs.on_dispatch(live=len(running), lanes=bb,
+                                tok_live=len(running), tok_lanes=bb)
+                obs.on_tables(live=sum(len(s.blocks) for s in running),
+                              walked=bb * nb)
+            toks = np.zeros(bb, np.int32)
+            pos = np.full(bb, -1, np.int32)     # pad lanes: masked everywhere
+            lens = np.zeros(bb, np.int32)
+            tables = np.zeros((bb, nb), np.int32)  # 0 = the null block
+            offsets = np.zeros(bb, np.int32)     # reclaimed logical blocks
+            slot_ids = np.full(bb, -1, np.int32)  # pad lanes: no slot
+            for i, seq in enumerate(running):
+                toks[i], pos[i], lens[i] = \
+                    seq.last_tok, seq.length, seq.length
+                tables[i, :len(seq.blocks)] = seq.blocks
+                offsets[i] = seq.freed_prefix
+                slot_ids[i] = seq.slot
+            jpos = jnp.asarray(pos)[:, None]
+            if self.cfg.family == "vlm":
+                jpos = jnp.broadcast_to(jpos[None], (3, bb, 1))
+            batch = {"tokens": jnp.asarray(toks)[:, None], "positions": jpos}
+            with obs.span("pool.step_caches"):
+                caches = self.pool.step_caches(
+                    tables, lens, block_offsets=offsets,
+                    slots=slot_ids if self.pool.slots is not None else None)
+            quant = self._quant_for(running[0].precision)
+        with obs.span("engine.dispatch"):
+            if self._moe_telemetry:
+                logits, caches, mst = serve_step(self.params, batch, caches,
+                                                 self.cfg, quant,
+                                                 moe_stats=True)
+                obs.on_moe(mst)
+            else:
+                logits, caches = serve_step(self.params, batch, caches,
+                                            self.cfg, quant)
+            self.pool.absorb(caches)
+        with obs.span("engine.fetch", bytes=logits.nbytes):
+            return np.asarray(logits, np.float32)
 
     def _fused_forward(self, plan) -> list:
         """Fused decode + chunk-prefill forward, grouped per served
@@ -1314,41 +1342,49 @@ class Engine:
         sq = prefill_bucket(smax, self.max_len)
         nb = min(_next_pow2(max(len(s.blocks) for s, _ in plan) or 1),
                  self.n_batch_blocks)
-        if self.obs.enabled:
-            self.obs.on_dispatch(live=len(plan), lanes=bb,
-                                 tok_live=sum(n for _, n in plan),
-                                 tok_lanes=bb * sq)
-        toks = np.zeros((bb, sq), np.int32)
-        pos = np.full((bb, sq), -1, np.int32)  # pads: masked everywhere
-        last = np.zeros(bb, np.int32)
-        lens = np.zeros(bb, np.int32)
-        tables = np.zeros((bb, nb), np.int32)  # 0 = the null block
-        offsets = np.zeros(bb, np.int32)
-        for i, (seq, n) in enumerate(plan):
-            if seq.prefilling:
-                toks[i, :n] = np.asarray(
-                    seq.pending[seq.length:seq.length + n], np.int32)
+        obs = self.obs
+        with obs.span("engine.prepare"):
+            if obs.enabled:
+                obs.on_dispatch(live=len(plan), lanes=bb,
+                                tok_live=sum(n for _, n in plan),
+                                tok_lanes=bb * sq)
+                obs.on_tables(live=sum(len(s.blocks) for s, _ in plan),
+                              walked=bb * nb)
+            toks = np.zeros((bb, sq), np.int32)
+            pos = np.full((bb, sq), -1, np.int32)  # pads: masked everywhere
+            last = np.zeros(bb, np.int32)
+            lens = np.zeros(bb, np.int32)
+            tables = np.zeros((bb, nb), np.int32)  # 0 = the null block
+            offsets = np.zeros(bb, np.int32)
+            for i, (seq, n) in enumerate(plan):
+                if seq.prefilling:
+                    toks[i, :n] = np.asarray(
+                        seq.pending[seq.length:seq.length + n], np.int32)
+                else:
+                    toks[i, 0] = seq.last_tok
+                pos[i, :n] = np.arange(seq.length, seq.length + n)
+                last[i], lens[i] = n - 1, seq.length
+                tables[i, :len(seq.blocks)] = seq.blocks
+                offsets[i] = seq.freed_prefix
+            batch = {"tokens": jnp.asarray(toks),
+                     "positions": jnp.asarray(pos),
+                     "last_idx": jnp.asarray(last, jnp.int32)}
+            with obs.span("pool.step_caches"):
+                caches = self.pool.step_caches(tables, lens,
+                                               block_offsets=offsets)
+            quant = self._quant_for(plan[0][0].precision)
+        with obs.span("engine.dispatch"):
+            if self._moe_telemetry:
+                logits, caches, mst = prefill_step_bucketed(
+                    self.params, batch, caches, self.cfg, quant,
+                    moe_stats=True)
+                obs.on_moe(mst)
             else:
-                toks[i, 0] = seq.last_tok
-            pos[i, :n] = np.arange(seq.length, seq.length + n)
-            last[i], lens[i] = n - 1, seq.length
-            tables[i, :len(seq.blocks)] = seq.blocks
-            offsets[i] = seq.freed_prefix
-        batch = {"tokens": jnp.asarray(toks),
-                 "positions": jnp.asarray(pos),
-                 "last_idx": jnp.asarray(last, jnp.int32)}
-        caches = self.pool.step_caches(tables, lens, block_offsets=offsets)
-        quant = self._quant_for(plan[0][0].precision)
-        if self._moe_telemetry:
-            logits, caches, mst = prefill_step_bucketed(
-                self.params, batch, caches, self.cfg, quant,
-                moe_stats=True)
-            self.obs.on_moe(mst)
-        else:
-            logits, caches = prefill_step_bucketed(
-                self.params, batch, caches, self.cfg, quant)
-        self.pool.absorb(caches)
-        logits = np.asarray(logits, np.float32)
+                logits, caches = prefill_step_bucketed(
+                    self.params, batch, caches, self.cfg, quant)
+            self.pool.absorb(caches)
+        with obs.span("engine.fetch", bytes=logits.nbytes):
+            logits = np.asarray(logits, np.float32)
         return [logits[i] for i in range(len(plan))]
 
     def _advance(self, plan, rows, t_fwd0: float = 0.0,

@@ -570,6 +570,20 @@ def kv_pool_planes(rows: jax.Array, h: int, d: int) -> jax.Array:
         rows.reshape(*rows.shape[:-1], n_bits, h, dw), -3, -2)
 
 
+def paged_attention_body(n_heads: int, n_kv_heads: int, sq: int,
+                         impl: str | None = None) -> str:
+    """The body :func:`paged_kv_cache_attention` runs for ``sq`` query
+    tokens a lane of a model with ``n_heads`` query heads over
+    ``n_kv_heads``: the layer folds a GQA group's ``sq`` tokens into one
+    query tile of ``n_heads // n_kv_heads * sq`` rows, and
+    :func:`flash_attention.paged_decode_body` picks the kernel body
+    (``"decode"`` or ``"tile"``); ``"reference"`` runs no kernel."""
+    if (impl or default_impl()) == "reference":
+        return "reference"
+    rows = n_heads // n_kv_heads * sq
+    return "decode" if flash_kernel.paged_decode_body(rows) else "tile"
+
+
 def paged_kv_cache_attention(q: jax.Array,
                              k_pool: jax.Array, k_scale: jax.Array,
                              v_pool: jax.Array, v_scale: jax.Array,
@@ -594,7 +608,9 @@ def paged_kv_cache_attention(q: jax.Array,
 
     Dispatch: pallas | interpret run the block-table-gathering flash
     kernel (the table is a scalar-prefetch operand indexing the pool
-    block specs, the query axis tiled by ``q_block`` rows); reference
+    block specs; decode tiles of at most 8 rows run unpadded through its
+    head-batched decode body, wider query axes are tiled by ``q_block``
+    rows); reference
     gathers the request's blocks with :func:`repro.kernels.ref.gather_paged_kv`
     and reuses the contiguous :func:`kv_cache_attention` reference path
     on the exact same packed planes.
@@ -614,9 +630,12 @@ def paged_kv_cache_attention(q: jax.Array,
             d=d, causal=causal, window=window, impl=impl)
         return o.reshape(b, h, g, d)
     dp = bipolar.packed_words(d) * bipolar.PACK_WIDTH
-    gp = _round_up(g, 8)
-    bq = min(q_block or flash_kernel.DEFAULT_PAGED_BQ, gp)
-    gp = _round_up(gp, bq)
+    if flash_kernel.paged_decode_body(g):
+        gp = bq = g                               # decode body: real rows
+    else:
+        gp = _round_up(g, 8)
+        bq = min(q_block or flash_kernel.DEFAULT_PAGED_BQ, gp)
+        gp = _round_up(gp, bq)
     qp_arr = _pad_dim(_pad_dim(q, 3, dp), 2, gp)
     q_pos_p = _pad_dim(q_pos, 1, gp, -1)          # pad rows fully masked
     out = flash_kernel.flash_attention_paged_quantized(

@@ -60,11 +60,13 @@ __all__ = ["ServingObs", "NULL_OBS"]
 class Profiled:
     """What the step loop did while a profiler trace recorded: per span
     name, ``[count, host seconds]``; and the block-table entries its
-    paged dispatches walked, and of those the entries that held a
-    sequence's blocks."""
+    paged dispatches walked, of those the entries that held a
+    sequence's blocks, and the walked entries of dispatches whose
+    paged attention took the head-batched decode body."""
     spans: dict = dataclasses.field(default_factory=dict)
     entries_live: int = 0
     entries_walked: int = 0
+    entries_decode_body: int = 0
 
 
 class ServingObs:
@@ -125,6 +127,15 @@ class ServingObs:
             labelnames=("kind",))
         self._c_entries_live = c_tables.labels(kind="live")
         self._c_entries_walked = c_tables.labels(kind="walked")
+        # the same walked entries by the paged attention body that ran
+        # them (ops.paged_attention_body)
+        c_body = r.counter(
+            "repro_paged_attn_entries",
+            "block-table entries walked by paged attention, by body "
+            "(decode = head-batched decode tiles, tile = per-head query "
+            "tiles, reference = no kernel)", labelnames=("body",))
+        self._c_entries_body = {b: c_body.labels(body=b)
+                                for b in ("decode", "tile", "reference")}
         self.profiled = Profiled()
         # MoE capacity pressure (per forward dispatch, fed by the
         # engine's moe_stats-specialized steps)
@@ -250,16 +261,20 @@ class ServingObs:
         dispatched slots.  Nothing here keeps it; a subclass that reads
         bucket padding (the benchmark's) overrides it."""
 
-    def on_tables(self, *, live: int, walked: int) -> None:
+    def on_tables(self, *, live: int, walked: int, body: str) -> None:
         """One paged dispatch's block tables: ``walked`` entries (batch
         lanes x table width) of which ``live`` hold a sequence's
-        blocks."""
+        blocks, walked by the paged attention ``body`` (``"decode"``,
+        ``"tile"`` or ``"reference"``)."""
         self._c_entries_live.inc(live)
         self._c_entries_walked.inc(walked)
+        self._c_entries_body[body].inc(walked)
         from jax.profiler import TraceAnnotation
         if TraceAnnotation.is_enabled():
             self.profiled.entries_live += live
             self.profiled.entries_walked += walked
+            if body == "decode":
+                self.profiled.entries_decode_body += walked
 
     def span(self, name: str, **args):
         """A host span on the profiler's clock: a

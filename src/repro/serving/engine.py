@@ -120,6 +120,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import ops
 from repro.models import model as M
 from repro.models.config import ModelConfig, QuantConfig
 from repro.obs import NULL_OBS, MetricsRegistry, ServingObs
@@ -1125,6 +1126,12 @@ class Engine:
         self.pool.absorb(caches)
         return logits
 
+    def _attn_body(self, sq: int) -> str:
+        """The paged attention body a dispatch of ``sq`` query tokens a
+        lane runs (``ops.paged_attention_body``)."""
+        return ops.paged_attention_body(self.cfg.n_heads,
+                                        self.cfg.n_kv_heads, sq)
+
     def _decode_bucket(self, n: int) -> int:
         return min(_next_pow2(n), self.max_batch)
 
@@ -1272,7 +1279,7 @@ class Engine:
                 obs.on_dispatch(live=len(running), lanes=bb,
                                 tok_live=len(running), tok_lanes=bb)
                 obs.on_tables(live=sum(len(s.blocks) for s in running),
-                              walked=bb * nb)
+                              walked=bb * nb, body=self._attn_body(1))
             toks = np.zeros(bb, np.int32)
             pos = np.full(bb, -1, np.int32)     # pad lanes: masked everywhere
             lens = np.zeros(bb, np.int32)
@@ -1349,7 +1356,7 @@ class Engine:
                                 tok_live=sum(n for _, n in plan),
                                 tok_lanes=bb * sq)
                 obs.on_tables(live=sum(len(s.blocks) for s, _ in plan),
-                              walked=bb * nb)
+                              walked=bb * nb, body=self._attn_body(sq))
             toks = np.zeros((bb, sq), np.int32)
             pos = np.full((bb, sq), -1, np.int32)  # pads: masked everywhere
             last = np.zeros(bb, np.int32)

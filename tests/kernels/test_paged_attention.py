@@ -355,3 +355,116 @@ def test_paged_block_order_is_table_order():
         q, swap(kp), swap(ks), swap(vp), swap(vs), swap(pos),
         jnp.asarray(tbl), q_pos, d=d, impl="reference"))
     np.testing.assert_array_equal(y0, y1)
+
+
+# ---------------------------------------------------------------------------
+# The head-batched decode body: query tiles of at most
+# flash_attention.DECODE_MAX_ROWS real rows per kv head
+# ---------------------------------------------------------------------------
+
+# (H, d): StableLM-3B's 32 heads of 80 (Dp = 96), then Dp = 64 and 128
+DECODE_HEADS = [(32, 80), (4, 64), (2, 128)]
+
+
+def _decode_inputs(bits, H, d):
+    """One-row decode lanes over a 16-token-block pool: lane 0 holds 3
+    blocks and a null entry, lane 1 one block and three null entries,
+    lane 2 is inactive (null table, q_pos -1)."""
+    paged, _ = _paged_inputs(bits, B=3, H=H, G=1, d=d, bs=16, n_blocks=8,
+                             NB=4, lens=(37, 9, 5))
+    q, kp, ks, vp, vs, pos, tables, q_pos = paged
+    return (q, kp, ks, vp, vs, pos, tables.at[2].set(0),
+            q_pos.at[2].set(-1))
+
+
+@pytest.mark.parametrize("window", [None, 8])
+@pytest.mark.parametrize("H,d", DECODE_HEADS)
+@pytest.mark.parametrize("bits", [2, 3, 4, 8])
+def test_paged_decode_body_parity(bits, H, d, window):
+    paged = _decode_inputs(bits, H, d)
+    y_ref = np.asarray(ops.paged_kv_cache_attention(
+        *paged, d=d, window=window, impl="reference"))
+    y_int = np.asarray(ops.paged_kv_cache_attention(
+        *paged, d=d, window=window, impl="interpret"))
+    np.testing.assert_allclose(y_int, y_ref, rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(y_int[2], np.zeros_like(y_int[2]))
+    assert np.abs(y_int[:2]).max() > 0
+
+
+@pytest.mark.parametrize("bits", [3, 8])
+def test_paged_decode_body_rolling_table(bits):
+    """The decode body under a rolling table: dropping the blocks out of
+    every query's window (pad entries to the null block) changes
+    nothing, and matches the reference."""
+    w = 8
+    q, kp, ks, vp, vs, pos, tables, q_pos = _decode_inputs(bits, 32, 80)
+    y_full = np.asarray(ops.paged_kv_cache_attention(
+        q, kp, ks, vp, vs, pos, tables, q_pos, d=80, window=w,
+        impl="interpret"))
+    rolled = np.asarray(tables).copy()
+    rolled[0, :2], rolled[0, 2:] = rolled[0, 1:3], 0   # block 0 is dead
+    args = (q, kp, ks, vp, vs, pos, jnp.asarray(rolled), q_pos)
+    y_roll = np.asarray(ops.paged_kv_cache_attention(
+        *args, d=80, window=w, impl="interpret"))
+    y_ref = np.asarray(ops.paged_kv_cache_attention(
+        *args, d=80, window=w, impl="reference"))
+    np.testing.assert_allclose(y_roll, y_full, rtol=2e-6, atol=2e-6)
+    np.testing.assert_allclose(y_roll, y_ref, rtol=2e-5, atol=2e-5)
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for p in eqn.params.values():
+            inner = getattr(p, "jaxpr", p)
+            if hasattr(inner, "eqns"):
+                yield from _pallas_calls(inner)
+
+
+def _paged_grid(g):
+    """The grid and name of the paged kernel a call with ``g`` query
+    rows per kv head runs (traced only)."""
+    import jax
+    paged, _ = _paged_inputs(8, B=1, H=2, G=1, d=32, lens=(9,))
+    _, kp, ks, vp, vs, pos, tables, _ = paged
+    q = jnp.zeros((1, 2, g, 32), jnp.float32)
+    q_pos = jnp.zeros((1, g), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda *a: ops.paged_kv_cache_attention(
+        *a, d=32, impl="interpret"))(q, kp, ks, vp, vs, pos, tables, q_pos)
+    (call,) = _pallas_calls(jaxpr.jaxpr)
+    name = call.params["name"]
+    return call.params["grid_mapping"].grid, getattr(name, "name", name)
+
+
+def test_paged_decode_body_predicate():
+    """One shape predicate picks the body: decode tiles (one row per
+    lane, GQA groups up to 8) take the head-batched body, a 2-D grid of
+    (lanes, table entries); 256-row chunk tiles and GQA groups of 16
+    keep the per-head tile body's 3-D grid.  Both run under the kernel
+    name the benchmark matches."""
+    from repro.kernels import flash_attention as fa
+    assert fa.paged_decode_body(1) and fa.paged_decode_body(8)
+    assert not fa.paged_decode_body(9)
+    assert not fa.paged_decode_body(16) and not fa.paged_decode_body(256)
+    for g, body in ((1, "decode"), (8, "decode"), (16, "tile"),
+                    (256, "tile")):
+        grid, name = _paged_grid(g)
+        assert name == "flash_attention_paged_quantized"
+        assert len(grid) == (2 if body == "decode" else 3), (g, grid)
+
+
+@pytest.mark.parametrize("n_heads,n_kv_heads,sq,impl,body", [
+    (32, 32, 1, "pallas", "decode"),       # StableLM-3B decode
+    (32, 32, 256, "pallas", "tile"),       # its fused chunk step
+    (32, 2, 1, "interpret", "tile"),       # GLM-4-9B decode: G = 16
+    (4, 1, 2, "interpret", "decode"),      # G x sq = 8 rows
+    (4, 1, 3, "pallas", "tile"),           # 12 rows
+    (32, 32, 1, "reference", "reference"),
+])
+def test_paged_attention_body_label(n_heads, n_kv_heads, sq, impl, body):
+    """The label a dispatch's counters take is the body its folded query
+    tile (``n_heads // n_kv_heads * sq`` rows) runs, and no kernel body
+    under the reference path."""
+    assert ops.paged_attention_body(n_heads, n_kv_heads, sq,
+                                    impl=impl) == body

@@ -85,8 +85,8 @@ def test_fused_linear_compiles_for_v5e(one_chip, linear, m):
         assert "tpu_custom_call" in text, (linear, m, w_bits)
 
 
-@pytest.mark.parametrize("batch,q_rows", [(8, 1), (1, 256)],
-                         ids=["decode", "chunk256"])
+@pytest.mark.parametrize("batch,q_rows", [(8, 1), (1, 256), (16, 1)],
+                         ids=["decode", "chunk256", "decode16"])
 def test_paged_attention_compiles_for_v5e(one_chip, batch, q_rows):
     n_blocks, table = 513, 64
     dw = bipolar.packed_words(D_HEAD)

@@ -5,10 +5,11 @@ profiler's clock (``ServingObs.span``): ``engine.step`` holds one
 ``sched.plan``, then per dispatch ``engine.prepare`` (holding
 ``pool.step_caches``), ``engine.dispatch`` and ``engine.fetch``, then
 ``engine.advance``.  ``on_tables`` counts the block-table entries each
-dispatch walks and those that hold a sequence's blocks.  While the
-profiler records, the facade keeps both, and each span's count and host
-seconds, in ``profiled``.  With ``NULL_OBS`` the same requests give the
-same tokens and no span.
+dispatch walks, those that hold a sequence's blocks, and the walked
+entries by the paged attention body that runs them.  While the
+profiler records, the facade keeps these totals, and each span's count
+and host seconds, in ``profiled``.  With ``NULL_OBS`` the same requests
+give the same tokens and no span.
 """
 
 from pathlib import Path
@@ -39,11 +40,22 @@ def _requests(cfg):
                       max_new_tokens=5) for n in (5, 11, 19)]
 
 
+@pytest.fixture(scope="module")
+def gqa_model():
+    """The same model with one kv head for its four query heads: a
+    decode tile holds 4 query rows a lane, a fused chunk tile 4 x 8."""
+    import dataclasses
+    from repro.models import model as M
+    cfg = dataclasses.replace(get_config("stablelm-3b").reduced(
+        n_layers=2, vocab=256), n_kv_heads=1)
+    return cfg, M.init_params(cfg, jax.random.PRNGKey(3))
+
+
 def _traced_run(model, metrics, log_dir, tables=None):
     """Serve the requests under a profiler trace (none when ``log_dir``
     is None); returns their tokens, the engine, and the program's spans
     as ``(name, start, end, stats)``.  ``tables`` collects the block
-    tables handed to the pool."""
+    tables handed to the pool, each as ``(dispatch kind, table)``."""
     from jax.profiler import ProfileData
     from repro.models.config import QuantConfig
     from repro.serving import engine as E
@@ -53,11 +65,19 @@ def _traced_run(model, metrics, log_dir, tables=None):
                    quant=QuantConfig(kv_bits=8))
     if tables is not None:
         step_caches = eng.pool.step_caches
+        kind = [None]
 
         def recording(tbl, lens, **kw):
-            tables.append(np.array(tbl))
+            tables.append((kind[0], np.array(tbl)))
             return step_caches(tbl, lens, **kw)
         eng.pool.step_caches = recording
+        for k in ("decode", "fused"):
+            dispatch = getattr(eng, f"_{k}_dispatch")
+
+            def marked(*a, _k=k, _dispatch=dispatch):
+                kind[0] = _k
+                return _dispatch(*a)
+            setattr(eng, f"_{k}_dispatch", marked)
     reqs = _requests(cfg)
     for r in reqs:
         eng.submit(r)
@@ -130,8 +150,8 @@ def test_table_counter_matches_the_dispatched_tables(model, tmp_path):
     assert tables
     reg = eng.obs.registry
     # block 0 is the null block: every other entry came from a sequence
-    live = sum(int(np.count_nonzero(t)) for t in tables)
-    walked = sum(t.size for t in tables)
+    live = sum(int(np.count_nonzero(t)) for _, t in tables)
+    walked = sum(t.size for _, t in tables)
     assert reg.value("repro_engine_table_entries", kind="live") == live
     assert reg.value("repro_engine_table_entries", kind="walked") == walked
     assert 0 < live < walked
@@ -141,14 +161,46 @@ def test_table_counter_matches_the_dispatched_tables(model, tmp_path):
     assert (prof.entries_live, prof.entries_walked) == (live, walked)
 
 
+def test_body_counter_splits_decode_from_fused_dispatches(gqa_model,
+                                                          tmp_path):
+    """Run through the Pallas kernels (interpret mode), decode dispatches
+    (4 query rows a lane) walk their entries in the head-batched decode
+    body, fused chunk dispatches (4 x 8 rows) in the tile body; the
+    profiled total keeps the decode body's share."""
+    from repro.kernels import ops
+    tables: list = []
+    ops.set_impl("interpret")
+    try:
+        _, eng, _ = _traced_run(gqa_model, True, tmp_path, tables=tables)
+    finally:
+        ops.set_impl(None)
+    walked = {k: sum(t.size for kind, t in tables if kind == k)
+              for k in ("decode", "fused")}
+    assert walked["decode"] > 0 and walked["fused"] > 0
+    reg = eng.obs.registry
+    assert reg.value("repro_paged_attn_entries",
+                     body="decode") == walked["decode"]
+    assert reg.value("repro_paged_attn_entries",
+                     body="tile") == walked["fused"]
+    assert reg.value("repro_paged_attn_entries", body="reference") == 0
+    assert "repro_paged_attn_entries_total{body=\"decode\"}" \
+        in reg.render()
+    assert eng.obs.profiled.entries_decode_body == walked["decode"]
+
+
 def test_profiled_totals_count_only_while_a_trace_records(model):
     tables: list = []
     _, eng, _ = _traced_run(model, True, None, tables=tables)
-    walked = sum(t.size for t in tables)
-    assert eng.obs.registry.value("repro_engine_table_entries",
-                                  kind="walked") == walked > 0
+    walked = sum(t.size for _, t in tables)
+    reg = eng.obs.registry
+    assert reg.value("repro_engine_table_entries",
+                     kind="walked") == walked > 0
+    # the CPU's default reference path runs no kernel body
+    assert reg.value("repro_paged_attn_entries", body="reference") == walked
+    assert reg.value("repro_paged_attn_entries", body="decode") == 0
     assert eng.obs.profiled.spans == {}
     assert eng.obs.profiled.entries_walked == 0
+    assert eng.obs.profiled.entries_decode_body == 0
     assert eng.obs.span("engine.step", step_num=0) is NULL_OBS.span("x")
 
 
@@ -165,4 +217,4 @@ def test_null_span_is_one_shared_no_op():
     assert span is NULL_OBS.span("engine.fetch", bytes=4)
     with span as s:
         s.set_metadata(kind="decode", lanes=1)
-    NULL_OBS.on_tables(live=1, walked=2)
+    NULL_OBS.on_tables(live=1, walked=2, body="decode")

@@ -1,8 +1,10 @@
 """The comparison that decides ``correct``.
 
 Once the window has closed, a sample of the requests the timed path
-served in the window (``sample``) is run through the plain reference
-(``reference.py``) on weights regenerated from the seed.  For every served token the gap by
+served in the window (``sample``) is run through the plain reference of
+the configuration's architecture module (``arch/``, built from
+``reference.py``) on weights regenerated from the seed and mapped by
+that module's ``reference_weights``.  For every served token the gap by
 which the reference's logit of that token lies below the reference's
 best logit is read; the widest gap is compared with the configuration's
 limit.  Served tokens are greedy, so a sound run serves tokens the
@@ -16,33 +18,6 @@ import numpy as np
 from bench import reference
 
 SAMPLE_REQUESTS = 4
-
-_LINEARS = {"wq": "mixer", "wk": "mixer", "wv": "mixer", "wo": "mixer",
-            "w_gate": "ffn", "w_up": "ffn", "w_down": "ffn"}
-
-
-def reference_weights(flat: dict) -> dict:
-    """Reference weights from the seeded tree's ``{path: array}`` view
-    (``weights.plain``): the program's leaf names mapped to the
-    reference's, nothing computed."""
-    def norm(prefix):
-        out = {"scale": flat[f"{prefix}['scale']"]}
-        if f"{prefix}['bias']" in flat:
-            out["bias"] = flat[f"{prefix}['bias']"]
-        return out
-
-    def packed(prefix):
-        return (flat[f"{prefix}['w'].packed"], flat[f"{prefix}['w'].scale"])
-
-    blk = "['blocks'][0]"
-    layers = {name: packed(f"{blk}['{part}']['{name}']")
-              for name, part in _LINEARS.items()}
-    layers["norm1"] = norm(f"{blk}['norm1']")
-    layers["norm2"] = norm(f"{blk}['norm2']")
-    return {"embed": flat["['embed']['w']"],
-            "final_norm": norm("['final_norm']"),
-            "lm_head": packed("['lm_head']"),
-            "layers": layers}
 
 
 def sample(finished: list, in_flight: list, seed: int,
@@ -63,11 +38,11 @@ def sample(finished: list, in_flight: list, seed: int,
     return [longest] + order[:n - 1]
 
 
-def widest_gap(weights: dict, cfg: dict, requests: list, t_max: int,
+def widest_gap(arch, weights: dict, cfg: dict, requests: list, t_max: int,
                m_max: int) -> tuple:
     """(widest gap over every served token of ``requests``, tokens
-    compared)."""
-    gaps = [reference.served_gaps(weights, cfg, np.asarray(r.prompt),
+    compared) under the reference of ``arch``."""
+    gaps = [reference.served_gaps(arch, weights, cfg, np.asarray(r.prompt),
                                   np.asarray(r.out, np.int32), t_max, m_max)
             for r in requests]
     if not gaps:

@@ -27,13 +27,6 @@ COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # percentiles of the gap between tokens and of time to first token that a
 # cell may report as itl_p<q>_ms / ttft_p<q>_ms
 PERCENTILES = (50, 90, 95, 99)
-_FIELDS = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
-           "num_attention_heads": "n_heads",
-           "num_key_value_heads": "n_kv_heads", "head_dim": "d_head",
-           "intermediate_size": "d_ff", "vocab_size": "vocab",
-           "partial_rotary_factor": "rope_pct", "rope_theta": "rope_theta",
-           "norm": "norm_type", "norm_eps": "norm_eps"}
-
 
 class NoDevice(RuntimeError):
     """JAX found no accelerator, or fewer chips than the cell needs."""
@@ -52,11 +45,12 @@ def process_start() -> float:
 
 
 def model_config(config: dict):
-    """The program's ``ModelConfig`` with the sizes of ``config``."""
+    """The program's ``ModelConfig`` with the sizes of ``config``, as its
+    architecture module maps them."""
     from repro.configs import get_config
     base = get_config(config["repo_config"])
     return dataclasses.replace(
-        base, **{f: config[k] for k, f in _FIELDS.items()})
+        base, **spec.architecture(config).model_fields(config))
 
 
 def quant_config(config: dict):
@@ -74,6 +68,7 @@ class Context:
     steps: list
     trace: object
     compiles: int
+    obs: object = None        # the engine's ServingObs in a traced run
 
     @staticmethod
     def work(name: str):
@@ -213,7 +208,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
             shutil.rmtree(trace_dir, ignore_errors=True)
         log("trace reduced", file=files[-1].name)
         ctx = Context(cfg=config, peaks=peaks, steps=traced_steps,
-                      trace=red, compiles=compiles["n"])
+                      trace=red, compiles=compiles["n"], obs=obs)
         metrics = {}
         for m in cell.per_layer:
             v = spec.metric_reader(m["name"])(ctx)
@@ -236,9 +231,10 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     _free(eng.pool.caches, params)
     del eng, loop, params, obs
     gc.collect()
+    arch = spec.architecture(config)
     ref_params = weights.make_params(struct, seed)
-    ref_w = correct.reference_weights(weights.plain(ref_params))
-    gap, n_tok = correct.widest_gap(ref_w, config, picked,
+    ref_w = arch.reference_weights(weights.plain(ref_params))
+    gap, n_tok = correct.widest_gap(arch, ref_w, config, picked,
                                     t_max=traffic.max_tokens,
                                     m_max=traffic.output.max)
     log("reference", requests=len(picked), tokens=n_tok, gap=gap)

@@ -1,27 +1,19 @@
 """The program's own totals of a traced window, for per-layer readers.
 
-A reader gets only its ``Context``.  The engine's ``ServingObs`` keeps
-what it saw while the profiler recorded in ``profiled`` (span host
-seconds and block-table entries, ``repro.obs.hooks.Profiled``), and the
-run still holds that object while its readers run: it is the hook
-object whose ``steps`` hold the context's step records.  A program
-without ``profiled`` gives ``None``, and so does a window with no step.
+The engine's ``ServingObs`` keeps what it saw while the profiler
+recorded in ``profiled`` (span host seconds and block-table entries,
+``repro.obs.hooks.Profiled``); a traced run hands that hook object to
+its readers as ``Context.obs``.  A program without ``profiled`` gives
+``None``, and so do a window with no step and a context with no hook
+object.
 """
 
 from __future__ import annotations
-
-import gc
 
 
 def profiled(ctx):
     """``ServingObs.profiled`` of the engine that ran ``ctx.steps``, or
     None."""
-    if not ctx.steps:
+    if not ctx.steps or ctx.obs is None:
         return None
-    from repro.obs import ServingObs
-    first = ctx.steps[0]
-    for obj in gc.get_objects():
-        if isinstance(obj, ServingObs) and any(
-                s is first for s in getattr(obj, "steps", ())):
-            return getattr(obj, "profiled", None)
-    return None
+    return getattr(ctx.obs, "profiled", None)

@@ -1,17 +1,15 @@
-"""Plain float32 reference forward of the served configurations.
+"""Plain float32 building blocks of the reference forwards, and the gap of
+served tokens below the reference's best logit.
 
-Straight ``jax.numpy`` in float32 at the highest matmul precision, from
-the published configuration (``configs/<name>.json``): no kernel, no
-cache, no batching, no quantized activations or KV.  It imports nothing
-of the program.  Weights arrive as packed bipolar-INT bit planes with
-per-row scales, and are decoded here: element ``k`` of a row is bit
-``k % 32`` of word ``k // 32`` of every plane, plane ``i`` weighs
-``2**i``, and a bit reads -1 (0) or +1 (1) (the paper's Eq. 1).
+Straight ``jax.numpy`` in float32 at the highest matmul precision: no
+kernel, no cache, no batching, no quantized activations or KV.  It
+imports nothing of the program.  Weights arrive as packed bipolar-INT
+bit planes with per-row scales, and are decoded here: element ``k`` of a
+row is bit ``k % 32`` of word ``k // 32`` of every plane, plane ``i``
+weighs ``2**i``, and a bit reads -1 (0) or +1 (1) (the paper's Eq. 1).
 
-Covers a decoder of pre-norm blocks: LayerNorm (with bias) or RMSNorm,
-partial rotary embedding on the leading ``partial_rotary_factor`` of
-each head (the two halves of the rotated part form the pairs), causal
-grouped-query attention, and a SwiGLU MLP; untied LM head.
+Each architecture module (``arch/``) builds its forward, ``logits_at``,
+from these blocks; ``served_gaps`` runs any of them.
 """
 
 from __future__ import annotations
@@ -38,26 +36,27 @@ def decode_planes(packed, scale, k_dim: int):
     return jnp.sum(signed * weight, axis=0) * scale.astype(jnp.float32)
 
 
-def _linear(x, w):
+def linear(x, w):
     """``x (T, K) @ W (N, K)^T`` for a packed ``w = (packed, scale)``."""
     packed, scale = w
     return jnp.dot(x, decode_planes(packed, scale, x.shape[-1]).T,
                    precision=HIGHEST)
 
 
-def _lm_head(x, w):
-    """The LM head over row blocks of the vocabulary: decoded whole, a
-    151,552-row head would take gigabytes of temporaries."""
+def lm_head(x, w):
+    """A packed LM head over row blocks of the vocabulary: decoded whole,
+    a 151,552-row head would take gigabytes of temporaries."""
     packed, scale = w
     n_bits, vp, kw = packed.shape
     c = math.gcd(vp, 4096)
     blocks = (packed.reshape(n_bits, vp // c, c, kw).transpose(1, 0, 2, 3),
               scale.reshape(vp // c, c, 1))
-    out = jax.lax.map(lambda b: _linear(x, b), blocks)     # (vp/c, M, c)
+    out = jax.lax.map(lambda b: linear(x, b), blocks)      # (vp/c, M, c)
     return out.transpose(1, 0, 2).reshape(x.shape[0], vp)
 
 
-def _norm(x, p, cfg):
+def norm(x, p, cfg):
+    """LayerNorm with bias (``cfg["norm"] == "layernorm"``) or RMSNorm."""
     eps = cfg["norm_eps"]
     if cfg["norm"] == "layernorm":
         mu = x.mean(-1, keepdims=True)
@@ -66,8 +65,10 @@ def _norm(x, p, cfg):
     return x / jnp.sqrt((x * x).mean(-1, keepdims=True) + eps) * p["scale"]
 
 
-def _rope(x, pos, cfg):
-    """Rotary embedding on the leading part of each head; ``x (T, H, D)``."""
+def rope(x, pos, cfg):
+    """Rotary embedding on the leading ``partial_rotary_factor`` of each
+    head, the two halves of the rotated part forming the pairs;
+    ``x (T, H, D)``."""
     d = x.shape[-1]
     rot = int(d * cfg["partial_rotary_factor"])
     rot -= rot % 2
@@ -80,42 +81,8 @@ def _rope(x, pos, cfg):
                             x[..., rot:]], axis=-1)
 
 
-def _block(x, lp, pos, cfg):
-    t = x.shape[0]
-    h, hk = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    dh = cfg["head_dim"]
-    a = _norm(x, lp["norm1"], cfg)
-    q = _rope(_linear(a, lp["wq"]).reshape(t, h, dh), pos, cfg)
-    k = _rope(_linear(a, lp["wk"]).reshape(t, hk, dh), pos, cfg)
-    v = _linear(a, lp["wv"]).reshape(t, hk, dh)
-    k = jnp.repeat(k, h // hk, axis=1)          # query head j: kv head j // g
-    v = jnp.repeat(v, h // hk, axis=1)
-    s = jnp.einsum("qhd,khd->hqk", q, k, precision=HIGHEST) / np.sqrt(dh)
-    causal = jnp.arange(t)[None, :] <= jnp.arange(t)[:, None]
-    s = jnp.where(causal[None], s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("hqk,khd->qhd", p, v, precision=HIGHEST).reshape(t, h * dh)
-    x = x + _linear(o, lp["wo"])
-    m = _norm(x, lp["norm2"], cfg)
-    f = jax.nn.silu(_linear(m, lp["w_gate"])) * _linear(m, lp["w_up"])
-    return x + _linear(f, lp["w_down"])
-
-
-def logits_at(weights, tokens, idx, cfg: dict):
-    """Logits ``(M, vocab)`` at positions ``idx (M,)`` of ``tokens (T,)``."""
-    pos = jnp.arange(tokens.shape[0])
-    x = weights["embed"][tokens].astype(jnp.float32)
-
-    def body(x, lp):
-        return _block(x, lp, pos, cfg), None
-
-    x, _ = jax.lax.scan(body, x, weights["layers"])
-    x = _norm(x[idx], weights["final_norm"], cfg)
-    return _lm_head(x, weights["lm_head"])[:, :cfg["vocab_size"]]
-
-
-@partial(jax.jit, static_argnames=("cfg",))
-def _served_gaps(weights, tokens, idx, served, cfg):
+@partial(jax.jit, static_argnames=("cfg", "logits_at"))
+def _served_gaps(weights, tokens, idx, served, cfg, logits_at):
     """Logit gaps of served tokens.  ``tokens (T,)`` is a prompt with its
     served tokens (padded at the end); ``idx (M,)`` the positions whose
     logits chose ``served (M,)``.  Returns ``(M,)`` gaps: the reference's
@@ -126,22 +93,11 @@ def _served_gaps(weights, tokens, idx, served, cfg):
     return best - got
 
 
-KEYS = ("num_attention_heads", "num_key_value_heads", "head_dim", "norm",
-        "norm_eps", "partial_rotary_factor", "rope_theta", "vocab_size")
-
-
-def static(cfg: dict) -> tuple:
-    """The sizes the forward reads, hashable for ``jax.jit``."""
-    return tuple(sorted((k, cfg[k]) for k in KEYS))
-
-
-def served_gaps(weights: dict, cfg: dict, prompt: np.ndarray,
+def served_gaps(arch, weights: dict, cfg: dict, prompt: np.ndarray,
                 served: np.ndarray, t_max: int, m_max: int) -> np.ndarray:
-    """Gap of each served token below the reference's best logit.
-
-    ``weights``: ``embed (V, d)``, ``final_norm``, ``lm_head`` and
-    ``layers`` (each leaf stacked over layers), packed linears as
-    ``(packed, scale)``.  Sequences are padded to ``t_max`` tokens and
+    """Gap of each served token below the best logit of the reference of
+    ``arch`` (an architecture module) on ``weights`` (its
+    ``reference_weights``).  Sequences are padded to ``t_max`` tokens and
     ``m_max`` served tokens, so one program serves every request."""
     p, m = len(prompt), len(served)
     tokens = np.zeros(t_max, np.int32)
@@ -152,5 +108,5 @@ def served_gaps(weights: dict, cfg: dict, prompt: np.ndarray,
     srv = np.zeros(m_max, np.int32)
     srv[:m] = served
     gaps = _served_gaps(weights, jnp.asarray(tokens), jnp.asarray(idx),
-                        jnp.asarray(srv), static(cfg))
+                        jnp.asarray(srv), arch.static(cfg), arch.logits_at)
     return np.asarray(gaps)[:m]

@@ -151,8 +151,8 @@ def _recording_steps(shapes: set):
     saved = E.serve_step, E.prefill_step_bucketed
 
     def key(kind, batch, caches):
-        bt = caches["blocks"][0]["block_tables"]
-        return (kind,) + tuple(batch["tokens"].shape) + (bt.shape[-1],)
+        return ((kind,) + tuple(batch["tokens"].shape)
+                + (caches["block_tables"].shape[-1],))
 
     def stub(kind):
         def run(params, batch, caches, cfg, quant=None, moe_stats=False):
@@ -184,7 +184,7 @@ def plan_shapes(cfg, quant, traffic: T.Traffic, eng_args: dict, seed: int,
         # the stand-ins read only the block table's width: skip the
         # pool's device work
         eng.pool.step_caches = lambda tables, lengths, **_: {
-            "blocks": [{"block_tables": tables}]}
+            "block_tables": tables}
         eng.pool.absorb = lambda caches: None
         eng.pool._reset_pos = lambda ids: None
         alloc = eng.pool.alloc

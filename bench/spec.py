@@ -1,17 +1,24 @@
 """Find a cell's pieces by name: ``BENCHMARK.json`` names a workload,
-its configuration file and its traffic mix; each per-layer metric is a
+its configuration file and its traffic mix; a configuration names its
+architecture module in ``arch/<name>.py``; each per-layer metric is a
 reader in ``metrics/<name>.py``; ``peaks.json`` holds the chip's peaks.
-Adding a cell or a metric adds files and entries and edits none."""
+Adding a cell, a metric or an architecture adds files and entries and
+edits none."""
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
+# packages that hold architecture modules: the benchmark's, and the
+# architectures that only the tests serve
+ARCH_PACKAGES = ("arch", "tests.arch")
+DEFAULT_ARCH = "dense_decoder"
 
 
 def load_json(path: Path) -> dict:
@@ -51,6 +58,25 @@ def load_cell(name: str, bench_file: Path = ROOT / "BENCHMARK.json") -> Cell:
                             if _reports(m, name)],
                 per_layer=[m for m in spec["per_layer"]
                            if _reports(m, name)])
+
+
+def architecture(config: dict):
+    """The architecture module a configuration names (``"arch"``; a file
+    without the key means ``dense_decoder``).  An unknown name is an
+    error that lists the known ones, never a default."""
+    return _arch_module(config.get("arch", DEFAULT_ARCH))
+
+
+@functools.lru_cache(maxsize=None)
+def _arch_module(name: str):
+    where = [(p.stem, pkg) for pkg in ARCH_PACKAGES
+             for p in BENCH_DIR.joinpath(*pkg.split(".")).glob("[!_]*.py")]
+    found = [pkg for stem, pkg in where if stem == name]
+    if len(found) != 1:
+        raise KeyError(f"architecture {name!r} is "
+                       f"{'named twice' if found else 'unknown'}; known: "
+                       f"{sorted(stem for stem, _ in where)}")
+    return importlib.import_module(f"bench.{found[0]}.{name}")
 
 
 def metric_reader(name: str):

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import correct, harness, reference, weights
+from bench import harness, spec, weights
 from bench.tests import tiny
 
 CONFIGS = ["stablelm-3b.w3a8kv8", "glm4-9b.w2a8kv8"]
@@ -26,26 +26,27 @@ def test_reference_agrees_with_the_program_on_the_same_planes(name):
     cfg_file = tiny.config(name)
     cfg = harness.model_config(cfg_file)
     quant = harness.quant_config(cfg_file)
+    arch = spec.architecture(cfg_file)
     struct = weights.structure(cfg, quant)
     params = weights.make_params(struct, 5)
-    ref_w = correct.reference_weights(weights.plain(params))
+    ref_w = arch.reference_weights(weights.plain(params))
     rng = np.random.default_rng(0)
     tokens = rng.integers(0, cfg.vocab, 40).astype(np.int32)
     got = np.asarray(M.forward(params, jnp.asarray(tokens)[None], cfg,
                                quant=quant, remat=False,
                                logits_mode="last")[0], np.float32)[0]
-    want = np.asarray(reference.logits_at(
+    want = np.asarray(arch.logits_at(
         ref_w, jnp.asarray(tokens), jnp.asarray([39]),
-        dict(reference.static(cfg_file))))[0]
+        dict(arch.static(cfg_file))))[0]
     scale = np.abs(want).max()
     # the program quantizes activations to 8 bits and runs in bf16
     assert np.abs(got[:cfg.vocab] - want).max() < 0.05 * scale
     # a wrong plane order or row scale is of the logits' own size
     bad = dict(ref_w, lm_head=(ref_w["lm_head"][0][::-1],
                                ref_w["lm_head"][1]))
-    wrong = np.asarray(reference.logits_at(
+    wrong = np.asarray(arch.logits_at(
         bad, jnp.asarray(tokens), jnp.asarray([39]),
-        dict(reference.static(cfg_file))))[0]
+        dict(arch.static(cfg_file))))[0]
     assert np.abs(got[:cfg.vocab] - wrong).max() > 0.3 * scale
 
 

@@ -16,7 +16,7 @@ def _window(obs, n=3):
     obs.steps.extend(StepRecord([(100 + i, 1)], 1, 1, 0, 0.5)
                      for i in range(n))
     return harness.Context(cfg=None, peaks={}, steps=obs.steps[1:],
-                           trace=None, compiles=0)
+                           trace=None, compiles=0, obs=obs)
 
 
 @pytest.mark.parametrize("decode,walked", [(128, 128), (96, 128), (0, 64)])
@@ -39,7 +39,7 @@ def test_left_out_where_the_program_counts_no_body():
     obs.profiled = SimpleNamespace(spans={}, entries_live=32,
                                    entries_walked=64)
     assert spec.metric_reader(NAME)(ctx) is None
-    # steps no engine ran
+    # steps with no hook object
     assert spec.metric_reader(NAME)(harness.Context(
         cfg=None, peaks={}, steps=[StepRecord([(1, 1)], 1, 1, 0, 0.5)],
         trace=None, compiles=0)) is None

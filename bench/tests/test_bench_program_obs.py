@@ -17,9 +17,9 @@ from bench.tests import tiny
 READERS = ("engine.host_ms_per_step", "paged_attn.live_entry_share")
 
 
-def _ctx(steps):
+def _ctx(steps, obs=None):
     return harness.Context(cfg=None, peaks={}, steps=steps, trace=None,
-                           compiles=0)
+                           compiles=0, obs=obs)
 
 
 def _records(n):
@@ -33,7 +33,7 @@ def test_readers_read_the_engine_that_ran_the_window():
                                "engine.fetch": [3, 0.046],
                                "sched.plan": [2, 0.004]})
     obs.profiled.entries_live, obs.profiled.entries_walked = 62, 128
-    ctx = _ctx(obs.steps[1:3])
+    ctx = _ctx(obs.steps[1:3], obs)
     assert program_obs.profiled(ctx) is obs.profiled
     # (72 - 46) ms of host time over two steps
     assert spec.metric_reader(READERS[0])(ctx) == pytest.approx(13.0)
@@ -46,17 +46,17 @@ def test_readers_leave_out_what_the_program_does_not_give():
     obs.steps.extend(_records(2))
     # a window the profiler saw no step or table of
     for name in READERS:
-        assert spec.metric_reader(name)(_ctx(obs.steps)) is None
-    # steps no engine ran, and no steps at all
-    for steps in (_records(2), []):
+        assert spec.metric_reader(name)(_ctx(obs.steps, obs)) is None
+    # steps with no hook object, and no steps at all
+    for steps, hooks in ((_records(2), None), ([], obs)):
         for name in READERS:
-            assert spec.metric_reader(name)(_ctx(steps)) is None
+            assert spec.metric_reader(name)(_ctx(steps, hooks)) is None
     # a program whose hooks keep no totals
     obs.profiled.spans["engine.step"] = [1, 0.01]
     obs.profiled.entries_walked = 8
     del obs.profiled
     for name in READERS:
-        assert spec.metric_reader(name)(_ctx(obs.steps)) is None
+        assert spec.metric_reader(name)(_ctx(obs.steps, obs)) is None
 
 
 def _spans(log_dir):
@@ -99,7 +99,7 @@ def test_readers_on_a_tiny_engine_under_a_profiler_trace(tmp_path):
             loop.step()
     finally:
         jax.profiler.stop_trace()
-    ctx = _ctx(obs.steps[n0:])
+    ctx = _ctx(obs.steps[n0:], obs)
     assert len(ctx.steps) == 6
     assert obs.profiled.spans["engine.step"][0] == 6
     # block 0 is the null block: every other entry came from a sequence

@@ -1,18 +1,19 @@
 import pytest
 
-from bench import spec
+from bench.arch import dense_decoder
+from bench.tests import tiny
 from bench.work import apmm, model_step, paged_attn
 
 
 def _cfg(name, **quant):
-    cfg = spec.load_json(spec.BENCH_DIR / "configs" / f"{name}.json")
+    cfg = tiny.load_config(name)
     cfg["quant"] = dict(cfg["quant"], **quant)
     return cfg
 
 
 def _weight_bytes(cfg, lanes):
     """Bytes of one decode step's APMM calls minus the activations."""
-    per_tok = apmm.act_bytes_per_token(cfg)
+    per_tok = dense_decoder.act_bytes_per_token(cfg)
     d, v = cfg["hidden_size"], cfg["vocab_size"]
     calls = apmm.calls(cfg, lanes)
     body = calls[0][1] - len(lanes) * per_tok
@@ -66,3 +67,16 @@ def test_linear_ops_and_head_rows():
     assert model_step.calls(cfg, lanes) == calls + paged_attn.calls(cfg,
                                                                     lanes)
     assert apmm.calls(cfg, []) == [] and paged_attn.calls(cfg, []) == []
+
+
+def test_a_tied_head_is_no_fused_linear_call():
+    cfg = _cfg("minicpm-2b.w2a8kv8")
+    lanes = [(0, 32), (300, 1)]
+    calls = apmm.calls(cfg, lanes)
+    # the layers' linears only: the tied head is an XLA matmul
+    assert calls == dense_decoder.layer_linear_calls(cfg, lanes)
+    assert len(calls) == 40
+    d, v = 2304, 122753
+    head = (2 * 2 * v * d, 2 * v * d + 2 * 2 * (d + v))
+    assert model_step.calls(cfg, lanes) == calls + [head] + \
+        paged_attn.calls(cfg, lanes)

@@ -1,7 +1,9 @@
-"""The whole step: the linears and the attention the tokens need."""
+"""The whole step: every kernel call the architecture module counts
+(its linears, LM head and attention), in the order the step runs them."""
 
-from bench.work import apmm, paged_attn
+from bench import spec
 
 
 def calls(cfg: dict, lanes) -> list:
-    return apmm.calls(cfg, lanes) + paged_attn.calls(cfg, lanes)
+    return [c for fn in spec.architecture(cfg).WORK.values()
+            for c in fn(cfg, lanes)]

@@ -125,7 +125,7 @@ def compile_decode(eng, cfg, quant, reqs):
                                   block_offsets=np.zeros(batch, np.int32))
     toks = jnp.zeros((batch, 1), jnp.int32)
     t0 = time.perf_counter()
-    compiled = E.serve_step.lower(
+    compiled = E.serve_program.lower(
         eng.params, {"tokens": toks, "positions": toks}, caches, cfg,
         quant).compile()
     dt = time.perf_counter() - t0

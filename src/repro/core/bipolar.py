@@ -331,6 +331,17 @@ def quantize_pack(x: jax.Array, n_bits: int, pack_axis: int,
                          pack_axis=pack_axis if pack_axis >= 0 else x.ndim + pack_axis)
 
 
+def take_experts(t: BipolarTensor, idx: jax.Array) -> BipolarTensor:
+    """Experts ``idx`` of a packed expert stack ``(E, N, K)`` (packed
+    ``(n_bits, E, N, Kw)``), in that order, as a ``(len(idx), N, K)``
+    stack: each dispatch tile's own copy of its expert's weights."""
+    ws = t.width_scales
+    return dataclasses.replace(
+        t, packed=t.packed[:, idx], scale=t.scale[idx],
+        width_scales=None if ws is None else ws[:, idx],
+        shape=(idx.shape[0],) + tuple(t.shape[1:]))
+
+
 def nested_slice(t: BipolarTensor, k: int) -> BipolarTensor:
     """Top-``k`` plane prefix of a packed tensor as a k-bit tensor.
 

@@ -30,17 +30,17 @@ tests/kernels/test_parity.py.  Ops covered:
   a dual-GEMM gate/up mode (``w2``: SwiGLU's two projections share one
   A-tile stream, ``act(x@w1^T) * (x@w2^T)``).  Bit-identical to the
   composed unfused pipeline (tests/kernels/test_fused_linear.py);
-* ``ap_moe_expert_linear`` -- grouped MoE expert linear ``(E, C, K) x
-  (E, N, K) -> (E, C, N)``: ONE launch for all experts over an
-  ``(expert*group, row-tile, col-tile, k-tile)`` grid, per-(expert,
-  group) live-row counts riding scalar prefetch, ``pl.when`` skipping
-  row tiles whose capacity slots hold no routed token, and the fused
-  quantize prologue / dequant epilogue of ``ap_linear_fused`` batched
-  per expert (dual gate/up mode included).  Live rows are bit-identical
-  to ``layers._expert_matmul``; dead capacity rows are exact zeros.
-  ``with_stats=True`` additionally returns the kernel's live-tile map
-  (the interpret-mode skip proof and the BENCH_moe skipped-tile
-  fraction);
+* ``ap_moe_tiles_linear`` -- grouped MoE expert linear over expert
+  tiles: ONE launch for all experts over a grid of ``bc``-row tiles,
+  each tile's live-row count and expert riding scalar prefetch,
+  ``pl.when`` skipping tiles that hold no routed token (and fetching
+  nothing for them), and the fused quantize prologue / dequant epilogue
+  of ``ap_linear_fused`` batched per expert (dual gate/up mode
+  included).  ``ap_moe_expert_linear`` runs the capacity dispatch
+  ``(E, C, K) x (E, N, K) -> (E, C, N)`` through it.  Live rows are
+  bit-identical to ``layers._expert_matmul``; dead rows are exact
+  zeros.  ``with_stats=True`` additionally returns the kernel's
+  live-tile map (the interpret-mode skip proof);
 * the bipolar KV-cache path ``quantize_kv`` / ``dequantize_kv`` /
   ``kv_cache_attention`` (dequant-on-read flash attention) /
   ``paged_kv_cache_attention`` (same, reading K/V through a serving
@@ -352,7 +352,7 @@ def ap_moe_expert_linear(x: jax.Array, w: BipolarTensor, *,
                          impl: str | None = None, out_dtype=None,
                          with_stats: bool = False,
                          w_bits: int | None = None):
-    """Grouped quantized MoE expert linear (one launch for all experts).
+    """Grouped quantized MoE expert linear over a capacity dispatch.
 
     ``y (E, C, N) = epi(Q(x) (E, C, K) @ W (E, N, K)^T)`` where ``C =
     G * seg`` capacity rows per expert hold ``G`` dispatch-group
@@ -376,14 +376,12 @@ def ap_moe_expert_linear(x: jax.Array, w: BipolarTensor, *,
     ``w2`` enables the dual gate/up mode: one quantized A-tile stream
     against both expert weights, ``act(Y1) * Y2`` fused before the
     output write (SwiGLU: w = gate, w2 = up -- the convention of
-    ``mlp_apply``).  The pallas/interpret impls run
-    :func:`repro.kernels.moe.moe_expert_linear`: counts ride scalar
-    prefetch and ``pl.when`` skips the quantize prologue and every MXU
-    pass of row tiles holding no live token.  ``with_stats=True``
-    additionally returns the ``(E*G, n_row_tiles)`` int32 live-tile map
-    (kernel-reported for pallas/interpret, analytic for reference --
-    the interpret parity test asserting they agree is the skip-path
-    proof).
+    ``mlp_apply``).  The pallas/interpret impls cut each segment into
+    row tiles and run :func:`ap_moe_tiles_linear`: tiles holding no live
+    token are skipped.  ``with_stats=True`` additionally returns the
+    ``(E*G, n_row_tiles)`` int32 live-tile map (kernel-reported for
+    pallas/interpret, analytic for reference -- the interpret parity
+    test asserting they agree is the skip-path proof).
 
     ``w_bits`` serves nested expert weights at a lower width (leading
     plane-prefix slice of the ``(n_bits, E, N, Kw)`` packed buffers,
@@ -391,32 +389,21 @@ def ap_moe_expert_linear(x: jax.Array, w: BipolarTensor, *,
     """
     impl = impl or default_impl()
     out_dtype = out_dtype or x.dtype
-    if w_bits is not None:
-        w = bipolar.nested_slice(w, w_bits)
-        if w2 is not None:
-            w2 = bipolar.nested_slice(w2, w_bits)
     e, c, k = x.shape
     g = counts.shape[1]
     assert c % g == 0, (c, g)
     seg = c // g
-    n = w.shape[1]
-    assert w.shape == (e, n, k), (x.shape, w.shape)
-    if w2 is not None:
-        assert w2.shape == w.shape and w2.n_bits == w.n_bits, \
-            (w.shape, w2.shape)
     counts = counts.astype(jnp.int32)
-    # pin the operand materialization: the kernel reads x from HBM in
-    # its storage dtype, so the reference dataflow must quantize the
-    # SAME rounded values -- the barrier stops XLA from feeding it the
-    # pre-cast excess-precision f32 upstream value instead
-    x = jax.lax.optimization_barrier(x)
-    # per-row absmax scale in f32 -- exactly _expert_quantize's chain
-    a_scale = bipolar.absmax_scale(x.astype(jnp.float32), a_bits,
-                                   axis=-1, keepdims=True)
     # tile geometry shared by all impls so the live map is comparable
-    bc = min(apmm_kernel.DEFAULT_BM, _round_up(seg, 8))
+    bc = moe_segment_tile_rows(seg)
     n_ci = _round_up(seg, bc) // bc
     if impl == "reference":
+        if w_bits is not None:
+            w = bipolar.nested_slice(w, w_bits)
+            if w2 is not None:
+                w2 = bipolar.nested_slice(w2, w_bits)
+        assert w.shape == (e, w.shape[1], k), (x.shape, w.shape)
+        x, a_scale = _moe_operand(x, a_bits)
         # result barrier = the kernel's HBM write: downstream consumers
         # (the next GEMM's quantizer, the combine) see materialized
         # out_dtype bits, never the fused f32 intermediate
@@ -428,17 +415,94 @@ def ap_moe_expert_linear(x: jax.Array, w: BipolarTensor, *,
                     > jnp.arange(n_ci, dtype=jnp.int32)[None, :] * bc)
             return y, live.astype(jnp.int32)
         return y
+    # each (expert, group) segment padded to whole tiles of bc rows
+    cp = n_ci * bc
+    xt = _pad_dim(x.reshape(e * g, seg, k), 1, cp).reshape(e * g * cp, k)
+    rows = jnp.clip(counts.reshape(e * g, 1)
+                    - jnp.arange(n_ci, dtype=jnp.int32)[None, :] * bc,
+                    0, bc).reshape(-1)
+    experts = jnp.repeat(jnp.arange(e, dtype=jnp.int32), g * n_ci)
+    out = ap_moe_tiles_linear(
+        xt, w, tile_rows=rows, tile_expert=experts, a_bits=a_bits, w2=w2,
+        act=act, variant=variant, impl=impl, out_dtype=out_dtype,
+        with_stats=with_stats, w_bits=w_bits)
+    y, live = out if with_stats else (out, None)
+    y = y.reshape(e * g, cp, -1)[:, :seg].reshape(e, c, -1)
+    return (y, live.reshape(e * g, n_ci)) if with_stats else y
+
+
+def moe_segment_tile_rows(seg: int) -> int:
+    """Rows of one kernel tile of a capacity segment of ``seg`` rows."""
+    return min(apmm_kernel.DEFAULT_BM, _round_up(seg, 8))
+
+
+def _moe_operand(x, a_bits: int):
+    """Pin the dispatched rows' materialization and take their per-row
+    absmax scales in f32 -- exactly ``_expert_quantize``'s chain.  The
+    kernel reads ``x`` from HBM in its storage dtype, so the reference
+    dataflow must quantize the SAME rounded values: the barrier stops
+    XLA from feeding it the pre-cast excess-precision f32 upstream
+    value instead."""
+    x = jax.lax.optimization_barrier(x)
+    return x, bipolar.absmax_scale(x.astype(jnp.float32), a_bits, axis=-1,
+                                   keepdims=True)
+
+
+def ap_moe_tiles_linear(x: jax.Array, w: BipolarTensor, *,
+                        tile_rows: jax.Array, tile_expert: jax.Array,
+                        a_bits: int, w2: BipolarTensor | None = None,
+                        act: str = "none", variant: str = "fused",
+                        impl: str | None = None, out_dtype=None,
+                        with_stats: bool = False,
+                        w_bits: int | None = None):
+    """Grouped quantized MoE expert linear over expert tiles.
+
+    ``x (R, K)`` holds ``T = len(tile_rows)`` tiles of ``R / T`` rows;
+    tile ``i`` belongs to expert ``tile_expert[i]`` and its live rows
+    are its first ``tile_rows[i]``.  Returns ``y (R, N)``: live rows
+    ``epi(Q(x_row) @ W_e^T)`` with the numerics of
+    :func:`ap_moe_expert_linear`, every other row exactly zero.  The
+    pallas/interpret impls run :func:`repro.kernels.moe.moe_expert_linear`,
+    which skips dead tiles and fetches no block for them;
+    ``with_stats=True`` also returns the ``(T,)`` int32 map of tiles
+    that did work (analytic for reference).
+    """
+    impl = impl or default_impl()
+    out_dtype = out_dtype or x.dtype
+    if w_bits is not None:
+        w = bipolar.nested_slice(w, w_bits)
+        if w2 is not None:
+            w2 = bipolar.nested_slice(w2, w_bits)
+    r, k = x.shape
+    n_t = tile_rows.shape[0]
+    assert r % n_t == 0, (r, n_t)
+    bc = r // n_t
+    e, n = w.shape[0], w.shape[1]
+    assert w.shape == (e, n, k), (x.shape, w.shape)
+    if w2 is not None:
+        assert w2.shape == w.shape and w2.n_bits == w.n_bits, \
+            (w.shape, w2.shape)
+    tile_rows = tile_rows.astype(jnp.int32)
+    tile_expert = tile_expert.astype(jnp.int32)
+    x, a_scale = _moe_operand(x, a_bits)
+    if impl == "reference":
+        # each tile as a one-segment "expert" holding its own weights
+        y = jax.lax.optimization_barrier(ref.ap_moe_expert_linear_ref(
+            x.reshape(n_t, bc, k), a_scale.reshape(n_t, bc, 1),
+            tile_rows[:, None], bipolar.take_experts(w, tile_expert),
+            w2=None if w2 is None else bipolar.take_experts(w2,
+                                                            tile_expert),
+            a_bits=a_bits, variant=variant, act=act, out_dtype=out_dtype))
+        y = y.reshape(r, n)
+        return (y, (tile_rows > 0).astype(jnp.int32)) if with_stats else y
     # --- pad to tile multiples (kernel masks the K pad in-prologue) -----
     wp = w.packed
     w2p = w2.packed if w2 is not None else None
     kw = max(bipolar.packed_words(k), wp.shape[-1],
              w2p.shape[-1] if w2p is not None else 0)
-    bn = min(apmm_kernel.DEFAULT_BN, _round_up(n, 128))
-    np_ = _round_up(n, bn)
-    cp = n_ci * bc
-    xg = _pad_dim(_pad_dim(x.reshape(e * g, seg, k), 2,
-                           kw * bipolar.PACK_WIDTH), 1, cp)
-    sg = _pad_dim(a_scale.reshape(e * g, seg, 1), 1, cp, 1.0)
+    np_ = _round_up(n, 128)
+    bn = moe_kernel.col_block(np_, kw, w.n_bits, w2 is not None)
+    xp = _pad_dim(x, 1, kw * bipolar.PACK_WIDTH)
     wp = _pad_dim(_pad_dim(wp, 2, np_), 3, kw, 0xFFFFFFFF)
     ws = _pad_dim(w.scale.reshape(e, 1, n).astype(jnp.float32), 2, np_, 1.0)
     kw_args: dict = {}
@@ -446,13 +510,14 @@ def ap_moe_expert_linear(x: jax.Array, w: BipolarTensor, *,
         kw_args["wp2"] = _pad_dim(_pad_dim(w2p, 2, np_), 3, kw, 0xFFFFFFFF)
         kw_args["w2_scale"] = _pad_dim(
             w2.scale.reshape(e, 1, n).astype(jnp.float32), 2, np_, 1.0)
-    y, live = moe_kernel.moe_expert_linear(
-        xg, sg, counts.reshape(e * g), wp, ws,
-        n_a=a_bits, n_b=w.n_bits, k_orig=k, n_groups=g, variant=variant,
-        act=act, block=(bc, bn), out_dtype=out_dtype,
+    out = moe_kernel.moe_expert_linear(
+        xp, a_scale.astype(jnp.float32), tile_rows, tile_expert, wp, ws,
+        n_a=a_bits, n_b=w.n_bits, k_orig=k, variant=variant, act=act,
+        block=(bc, bn), out_dtype=out_dtype, stats=with_stats,
         interpret=(impl == "interpret"), **kw_args)
-    y = y[:, :seg, :n].reshape(e, c, n)
-    return (y, live) if with_stats else y
+    if with_stats:
+        return out[0][:, :n], out[1]
+    return out[:, :n]
 
 
 def pack_weight(w: jax.Array, n_bits: int, *,

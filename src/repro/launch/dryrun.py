@@ -86,7 +86,7 @@ def _serve_cell(cfg, mesh, specs, shape, mode):
         if mode == "prefill":
             return E.prefill_step.__wrapped__(params, batch_in, caches, cfg,
                                               quant)
-        return E.serve_step.__wrapped__(params, batch_in, caches, cfg, quant)
+        return E.serve_step(params, batch_in, caches, cfg, quant)
 
     psh = S.shardings_for_params(mesh, qparams)
     bsh = S.shardings_for_batch(mesh, specs)

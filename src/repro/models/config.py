@@ -151,6 +151,9 @@ class ModelConfig:
     n_experts: int = 0
     n_shared_experts: int = 0
     top_k: int = 0
+    # top-k gate weights renormalized to sum to 1 (mixtral); false keeps
+    # the softmax probabilities as they are (deepseek-moe)
+    norm_topk_prob: bool = True
     expert_d_ff: int = 0
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01

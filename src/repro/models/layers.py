@@ -747,10 +747,141 @@ MOE_DISPATCH_GROUPS = 32   # static token-group count (per-group capacity)
 GROUPED_MOE = True
 
 
-def moe_apply(params: dict, x: jax.Array, cfg: ModelConfig, quant=None):
-    """Top-k capacity-bounded MoE over ``x (B, S, d)``.
+def _route(xf, router_w, cfg: ModelConfig):
+    """Softmax router in f32: ``(probs (..., E), top_p (..., k), top_e
+    (..., k))``; the top-k weights are renormalized to sum to 1 only
+    where ``cfg.norm_topk_prob``."""
+    logits = jnp.einsum("...d,ed->...e", xf, router_w)
+    probs = jax.nn.softmax(logits, -1)
+    top_p, top_e = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.norm_topk_prob:
+        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    return probs, top_p, top_e
 
-    *Grouped* dispatch: tokens are split into G static groups with
+
+def moe_tile_rows(t: int, k: int, e: int) -> int:
+    """Rows of one expert tile of the dropless dispatch of ``t`` rows:
+    8 while the step averages at most 8 assignments an expert (decode),
+    else 32, so padded steps walk fewer dead tiles."""
+    return 8 if t * k <= 8 * e else 32
+
+
+def _dropless_experts(params: dict, xt, live, top_p, top_e,
+                      cfg: ModelConfig, quant):
+    """Routed experts of the live rows of ``xt (t, d)``, no assignment
+    dropped: ``(y (t, d), stats)``.
+
+    The live assignments are sorted by expert and each expert's run is
+    padded to whole tiles of ``bc`` rows (``moe_tile_rows``), so every
+    tile belongs to one expert and the grouped kernel computes only
+    tiles that hold a live row.  An expert takes at most one assignment
+    per token (top-k experts are distinct), so no bound on an expert's
+    rows is needed; the static layout holds ``t * k`` assignments in
+    ``T`` tiles.  Rows with ``live`` false (pad tokens) are neither
+    routed nor counted; their outputs are zero."""
+    t, d = xt.shape
+    e, k = cfg.n_experts, cfg.top_k
+    bc = moe_tile_rows(t, k, e)
+    n_t = min(t * k, -(-(t * k + e * (bc - 1)) // bc))
+    flat_e = top_e.reshape(t * k)
+    ok = jnp.repeat(live, k)                                    # (t*k,)
+    oh = jax.nn.one_hot(flat_e, e, dtype=jnp.int32) * ok[:, None]
+    counts = oh.sum(0)                                          # (E,)
+    rank = jnp.take_along_axis(jnp.cumsum(oh, 0) - oh, flat_e[:, None],
+                               1)[:, 0]
+    tiles = -(-counts // bc)
+    tile_end = jnp.cumsum(tiles)                                # (E,)
+    tile_start = tile_end - tiles
+    # each live assignment's row; pad assignments go to the sink row
+    row = jnp.where(ok, tile_start[flat_e] * bc + rank, n_t * bc)
+    src = jnp.full(n_t * bc + 1, t, jnp.int32).at[row].set(
+        jnp.arange(t * k, dtype=jnp.int32) // k)[:n_t * bc]
+    disp = jnp.concatenate([xt, jnp.zeros((1, d), xt.dtype)], 0)[src]
+    ids = jnp.arange(n_t, dtype=jnp.int32)
+    tile_e = jnp.minimum(jnp.searchsorted(tile_end, ids, side="right"),
+                         e - 1).astype(jnp.int32)
+    tile_rows = jnp.where(ids < tile_end[-1],
+                          jnp.clip(counts[tile_e]
+                                   - (ids - tile_start[tile_e]) * bc, 0, bc),
+                          0).astype(jnp.int32)
+
+    if isinstance(params["w_up"], BipolarTensor) and GROUPED_MOE:
+        # grouped kernel: one launch for gate+up (dual-GEMM, shared
+        # quantized A-stream), one for down
+        h = ops.ap_moe_tiles_linear(
+            disp, params["w_gate"], w2=params["w_up"], tile_rows=tile_rows,
+            tile_expert=tile_e, a_bits=quant.a_bits, act="silu",
+            variant=quant.variant, out_dtype=xt.dtype,
+            w_bits=quant.nested_bits)
+        out = ops.ap_moe_tiles_linear(
+            h, params["w_down"], tile_rows=tile_rows, tile_expert=tile_e,
+            a_bits=quant.a_bits, variant=quant.variant, out_dtype=xt.dtype,
+            w_bits=quant.nested_bits)                           # (R, d)
+    else:
+        # the batched expert path on each tile's own copy of its
+        # expert's weights (the grouped kernel's oracle, and float
+        # experts)
+        out = _batched_experts(
+            {n: (bipolar.take_experts(params[n], tile_e)
+                 if isinstance(params[n], BipolarTensor)
+                 else params[n][tile_e])
+             for n in ("w_gate", "w_up", "w_down")},
+            disp.reshape(n_t, bc, d), quant).reshape(n_t * bc, d)
+
+    out = jnp.concatenate([out, jnp.zeros((1, d), out.dtype)], 0)
+    y = out[row] * (top_p.reshape(t * k) * ok)[:, None].astype(out.dtype)
+    y = y.reshape(t, k, d).sum(1)
+    stats = {"load": counts,
+             "dropped": ok.sum(dtype=jnp.int32) - counts.sum(),
+             "capacity": jnp.int32(n_t * bc),
+             "computed": tiles.sum() * bc,
+             "touched": (counts > 0).sum(dtype=jnp.int32)}
+    return y, stats
+
+
+def _batched_experts(w: dict, disp_e, quant):
+    """Expert SwiGLU over ``disp_e (E', C, d)`` with batched per-expert
+    GEMMs on ``w`` (each leaf ``(E', ...)``) -- no grouped kernel."""
+    if isinstance(w["w_up"], BipolarTensor):
+        # legacy batched-over-E oracle for the grouped kernel: gate and
+        # up share one quantized-activation stream, the dual epilogue
+        # composes in f32.  optimization_barrier pins the bf16
+        # materialization points the kernel pins physically (its HBM
+        # operand/result round-trips) -- without them XLA's excess-
+        # precision pass elides the f32->bf16->f32 converts between
+        # stages in a fused graph and the two paths bit-diverge
+        disp_e = jax.lax.optimization_barrier(disp_e)
+        pre = _expert_quantize(disp_e, quant.a_bits)
+        gate = _expert_matmul(w["w_gate"], disp_e, quant, pre,
+                              out_dtype=jnp.float32)
+        up = _expert_matmul(w["w_up"], disp_e, quant, pre,
+                            out_dtype=jnp.float32)
+        h = jax.lax.optimization_barrier(
+            (jax.nn.silu(gate) * up).astype(disp_e.dtype))
+        return jax.lax.optimization_barrier(
+            _expert_matmul(w["w_down"], h, quant))
+    # dense (unquantized) fallback -- kept barrier-free: the float path
+    # trains, and optimization_barrier has no grad rule
+    up = _expert_matmul(w["w_up"], disp_e, quant)
+    gate = _expert_matmul(w["w_gate"], disp_e, quant)
+    h = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+         ).astype(disp_e.dtype)
+    return _expert_matmul(w["w_down"], h, quant)
+
+
+def moe_apply(params: dict, x: jax.Array, cfg: ModelConfig, quant=None,
+              live: Optional[jax.Array] = None):
+    """Top-k MoE over ``x (B, S, d)``.
+
+    Serving (``live (B, S)`` bool given, false on pad tokens) is
+    *dropless*: ``_dropless_experts`` routes every live token to all its
+    top-k experts and no pad token to any.  Without ``live`` (training
+    and cacheless forwards) the dispatch is capacity-bounded: per-group
+    capacity ``ceil(k * T_g * capacity_factor / E)`` assignments an
+    expert, the rest dropped, which keeps the dispatch's shape
+    independent of the routing for SPMD partitioning.
+
+    *Grouped* capacity dispatch: tokens are split into G static groups with
     per-group capacity (= per-device capacity at scale).  The dispatch
     scatter and the position cumsum are then *batched over G*, which SPMD
     partitions along the group axis -- the flat global scatter was
@@ -758,19 +889,31 @@ def moe_apply(params: dict, x: jax.Array, cfg: ModelConfig, quant=None):
     MoE layer on the jamba-398B train cell (EXPERIMENTS.md §Perf iter 3).
     Memory is O(G * E * C_g * d) = O(k*T*cf*d).
 
-    Quantized experts run through ``ops.ap_moe_expert_linear`` (one
-    grouped launch per projection stage, gate+up fused dual-GEMM,
-    scalar-prefetched live-row counts skipping empty capacity tiles) --
-    token-identical to the legacy batched ``_expert_matmul`` path
-    (``GROUPED_MOE = False``), which remains the dense fallback.
+    Quantized experts run through the grouped kernel
+    (``ops.ap_moe_tiles_linear`` / ``ops.ap_moe_expert_linear``: one
+    launch per projection stage, gate+up fused dual-GEMM, scalar-
+    prefetched live-row counts skipping empty tiles) -- token-identical
+    to the legacy batched ``_expert_matmul`` path (``GROUPED_MOE =
+    False``), which remains the dense fallback.
 
-    Returns ``(y, aux, stats)``; ``stats`` carries per-layer capacity
-    telemetry -- ``load (E,)`` tokens kept per expert, ``dropped ()``
-    assignments lost to the capacity bound, ``capacity ()`` total
-    dispatch slots -- all int32, computed from the routing one-hots
-    (XLA dead-code-eliminates them when the caller drops ``stats``, so
-    collection is free unless observability asks for it).
+    Returns ``(y, aux, stats)``; ``stats`` carries per-layer telemetry
+    -- ``load (E,)`` assignments kept per expert, ``dropped ()``
+    assignments lost to the capacity bound, ``capacity ()`` dispatch
+    slots, ``computed ()`` rows the grouped kernel computes (live row
+    tiles, tile padding included), ``touched ()`` experts holding a
+    kept assignment -- all int32, computed from the routing (XLA
+    dead-code-eliminates them when the caller drops ``stats``).
     """
+    if live is not None:
+        b, s, d = x.shape
+        xt = x.reshape(b * s, d)
+        probs, top_p, top_e = _route(xt.astype(jnp.float32),
+                                     params["router"]["w"], cfg)
+        y, stats = _dropless_experts(params, xt, live.reshape(b * s),
+                                     top_p, top_e, cfg, quant)
+        if "shared" in params:
+            y = y + mlp_apply(params["shared"], xt, cfg, quant=quant)
+        return y.reshape(b, s, d), jnp.zeros((), jnp.float32), stats
     b, s, d = x.shape
     e, k = cfg.n_experts, cfg.top_k
     t = b * s
@@ -792,11 +935,8 @@ def moe_apply(params: dict, x: jax.Array, cfg: ModelConfig, quant=None):
     xt = x.reshape(t, d)
     xg = x.reshape(g, tg, d)
 
-    logits = jnp.einsum("gtd,ed->gte", xg.astype(jnp.float32),
-                        params["router"]["w"])
-    probs = jax.nn.softmax(logits, -1)
-    top_p, top_e = jax.lax.top_k(probs, k)                     # (G, Tg, k)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    probs, top_p, top_e = _route(xg.astype(jnp.float32),
+                                 params["router"]["w"], cfg)    # (G, Tg, k)
 
     flat_e = top_e.reshape(g, tg * k)                           # (G, Tg*k)
     oh = jax.nn.one_hot(flat_e, e, dtype=jnp.int32)             # (G, Tg*k, E)
@@ -832,32 +972,8 @@ def moe_apply(params: dict, x: jax.Array, cfg: ModelConfig, quant=None):
             h, params["w_down"], counts=counts_e, a_bits=quant.a_bits,
             variant=quant.variant, out_dtype=x.dtype,
             w_bits=quant.nested_bits)                           # (E, G*C, d)
-    elif quantized:
-        # legacy batched-over-E oracle for the grouped kernel: gate and
-        # up share one quantized-activation stream, the dual epilogue
-        # composes in f32.  optimization_barrier pins the bf16
-        # materialization points the kernel pins physically (its HBM
-        # operand/result round-trips) -- without them XLA's excess-
-        # precision pass elides the f32->bf16->f32 converts between
-        # stages in a fused graph and the two paths bit-diverge
-        disp_e = jax.lax.optimization_barrier(disp_e)
-        pre = _expert_quantize(disp_e, quant.a_bits)
-        gate = _expert_matmul(params["w_gate"], disp_e, quant, pre,
-                              out_dtype=jnp.float32)
-        up = _expert_matmul(params["w_up"], disp_e, quant, pre,
-                            out_dtype=jnp.float32)
-        h = jax.lax.optimization_barrier(
-            (jax.nn.silu(gate) * up).astype(x.dtype))
-        out = jax.lax.optimization_barrier(
-            _expert_matmul(params["w_down"], h, quant))         # (E, G*C, d)
     else:
-        # dense (unquantized) fallback -- kept barrier-free: the float
-        # path trains, and optimization_barrier has no grad rule
-        up = _expert_matmul(params["w_up"], disp_e, quant)
-        gate = _expert_matmul(params["w_gate"], disp_e, quant)
-        h = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-             ).astype(x.dtype)
-        out = _expert_matmul(params["w_down"], h, quant)        # (E, G*C, d)
+        out = _batched_experts(params, disp_e, quant)           # (E, G*C, d)
 
     out_g = out.reshape(e, g, cap, d).transpose(1, 0, 2, 3)     # (G, E, C, d)
     if g > 1:
@@ -883,7 +999,10 @@ def moe_apply(params: dict, x: jax.Array, cfg: ModelConfig, quant=None):
 
     routed = oh.sum(axis=(0, 1))                                # (E,) int32
     load = counts.sum(axis=0)                                   # (E,) int32
+    bc = ops.moe_segment_tile_rows(cap)
     stats = {"load": load,
              "dropped": (routed - load).sum(),
-             "capacity": jnp.int32(e * cap * g)}
+             "capacity": jnp.int32(e * cap * g),
+             "computed": (-(-counts // bc)).sum() * bc,
+             "touched": (load > 0).sum(dtype=jnp.int32)}
     return y.reshape(b, s, d), aux, stats

@@ -30,6 +30,8 @@ from repro.models.config import ModelConfig, QuantConfig
 from repro.distributed.sharding import constrain
 
 LOSS_CHUNK = 512  # sequence chunk for the CE loss (bounds logits memory)
+# per-layer MoE telemetry keys (``layers.moe_apply``'s stats)
+MOE_STATS = ("load", "dropped", "capacity", "computed", "touched")
 
 
 # ---------------------------------------------------------------------------
@@ -173,7 +175,14 @@ def _apply_block(p, x, cfg, mixer_kind, ffn_kind, *, positions, cache,
     if ffn_kind != "none":
         h = L.norm_apply(p["norm2"], x, cfg)
         if ffn_kind == "moe":
-            h, aux, moe_stats = L.moe_apply(p["ffn"], h, cfg, quant=quant)
+            # with a cache this is a serving step: route dropless, and
+            # only real tokens (pad positions are -1)
+            live = None
+            if cache is not None:
+                live = (positions if positions.ndim == 2
+                        else positions[0]) >= 0
+            h, aux, moe_stats = L.moe_apply(p["ffn"], h, cfg, quant=quant,
+                                            live=live)
             x = x + h.astype(x.dtype) * rs
         else:
             h = L.mlp_apply(p["ffn"], h, cfg, quant=quant,
@@ -256,10 +265,11 @@ def forward(params: dict, tokens: jax.Array, cfg: ModelConfig, *,
     :func:`loss_and_logits` in chunks.
 
     ``collect_moe_stats=True`` appends a 4th element: the per-MoE-layer
-    capacity telemetry ``{"load": (L_moe, E), "dropped": (L_moe,),
-    "capacity": (L_moe,)}`` (int32; rows ordered prelude layers first,
-    then scanned unit positions with their ``n_units`` stacked per row
-    group), or ``None`` if the stack has no MoE layers.
+    telemetry of :func:`repro.models.layers.moe_apply` -- ``{"load":
+    (L_moe, E)}`` and ``(L_moe,)`` rows of ``dropped``, ``capacity``,
+    ``computed`` and ``touched`` (int32; rows ordered prelude layers
+    first, then scanned unit positions with their ``n_units`` stacked
+    per row group), or ``None`` if the stack has no MoE layers.
     """
     b, s = tokens.shape
     # a QuantConfig that only sets kv_bits still matters (cache reads);
@@ -355,7 +365,7 @@ def forward(params: dict, tokens: jax.Array, cfg: ModelConfig, *,
             kk: jnp.concatenate(
                 [p[kk][None] if p[kk].ndim == (1 if kk == "load" else 0)
                  else p[kk] for p in moe_parts], 0)
-            for kk in ("load", "dropped", "capacity")}
+            for kk in MOE_STATS}
 
     out = x
     if logits_mode == "last":

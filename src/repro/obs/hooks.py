@@ -3,7 +3,8 @@
 The engine, scheduler, and pool do not talk to the registry or tracer
 directly on timed paths -- they call lifecycle hooks on a ``ServingObs``
 (``on_submit`` / ``on_admit`` / ``on_token`` / ``on_preempt`` /
-``on_finish`` / ``on_step`` / ``on_dispatch`` / ``on_tables``), which owns:
+``on_finish`` / ``on_step`` / ``on_dispatch`` / ``on_tables`` /
+``on_moe`` / ``on_moe_rows``), which owns:
 
 * a :class:`~repro.obs.metrics.MetricsRegistry` (shared with the pool
   and scheduler, so every counter lives in ONE namespace),
@@ -59,14 +60,19 @@ __all__ = ["ServingObs", "NULL_OBS"]
 @dataclasses.dataclass
 class Profiled:
     """What the step loop did while a profiler trace recorded: per span
-    name, ``[count, host seconds]``; and the block-table entries its
-    paged dispatches walked, of those the entries that held a
-    sequence's blocks, and the walked entries of dispatches whose
-    paged attention took the head-batched decode body."""
+    name, ``[count, host seconds]``; the block-table entries its paged
+    dispatches walked, of those the entries that held a sequence's
+    blocks, and the walked entries of dispatches whose paged attention
+    took the head-batched decode body; and, summed over MoE layer
+    calls, the live routed assignments, the rows the grouped expert
+    kernel computed for them, and the experts that held a live row."""
     spans: dict = dataclasses.field(default_factory=dict)
     entries_live: int = 0
     entries_walked: int = 0
     entries_decode_body: int = 0
+    moe_live: int = 0
+    moe_computed: int = 0
+    moe_touched: int = 0
 
 
 class ServingObs:
@@ -149,6 +155,18 @@ class ServingObs:
         self._g_moe_util = r.gauge(
             "repro_moe_capacity_utilization",
             "kept assignments / dispatch slots over the last forward")
+        c_assign = r.counter(
+            "repro_moe_assignments",
+            "routed assignments of live tokens (live) and the rows the "
+            "grouped expert kernel computes for them, tile padding "
+            "included (computed), summed over MoE layer calls",
+            labelnames=("kind",))
+        self._c_moe_live = c_assign.labels(kind="live")
+        self._c_moe_computed = c_assign.labels(kind="computed")
+        self._c_moe_touched = r.counter(
+            "repro_moe_experts_touched",
+            "experts holding at least one live row, summed over MoE "
+            "layer calls")
 
     # -- clock ---------------------------------------------------------------
     def t(self) -> float:
@@ -290,21 +308,40 @@ class ServingObs:
     def on_moe(self, stats: Any) -> None:
         """Record one forward pass's MoE capacity telemetry: ``stats``
         is the :func:`repro.models.model.forward` dict -- ``load``
-        ``(L_moe, E)`` kept tokens per expert, ``dropped (L_moe,)``
-        assignments lost to the capacity bound, ``capacity (L_moe,)``
-        dispatch slots -- device arrays; the host transfer happens
-        here, off the jitted step."""
+        ``(L_moe, E)`` kept assignments per expert, ``dropped
+        (L_moe,)`` assignments lost to the capacity bound, ``capacity
+        (L_moe,)`` dispatch slots -- as host or device arrays."""
         if stats is None:
             return
         load = np.asarray(stats["load"])
-        for v in load.reshape(-1):
-            self._h_moe_load.observe(float(v))
+        h = self._h_moe_load
+        # every (layer, expert) load in one update: bucket i holds the
+        # values at or under its edge (searchsorted, left)
+        h.observe_bucketed(
+            np.bincount(np.searchsorted(h.buckets, load.reshape(-1)),
+                        minlength=len(h.buckets) + 1),
+            float(load.sum()))
         dropped = int(np.asarray(stats["dropped"]).sum())
         if dropped:
             self._c_moe_dropped.inc(dropped)
         cap = int(np.asarray(stats["capacity"]).sum())
         if cap:
             self._g_moe_util.set(float(load.sum()) / cap)
+
+    def on_moe_rows(self, *, live: int, computed: int,
+                    touched: int) -> None:
+        """One dispatch's MoE work, summed over its MoE layers: ``live``
+        routed assignments of live tokens, ``computed`` rows of the
+        grouped expert kernel's live tiles, ``touched`` experts with a
+        live row.  Kept in ``profiled`` too while a trace records."""
+        self._c_moe_live.inc(live)
+        self._c_moe_computed.inc(computed)
+        self._c_moe_touched.inc(touched)
+        from jax.profiler import TraceAnnotation
+        if TraceAnnotation.is_enabled():
+            self.profiled.moe_live += live
+            self.profiled.moe_computed += computed
+            self.profiled.moe_touched += touched
 
 
 class _NoSpan:
@@ -401,6 +438,9 @@ class _NullObs:
         return _NO_SPAN
 
     def on_moe(self, stats):
+        pass
+
+    def on_moe_rows(self, **kw):
         pass
 
 

@@ -191,6 +191,16 @@ class Histogram(_Metric):
                 return
         self.counts[-1] += 1
 
+    def observe_bucketed(self, counts: Sequence[int], total: float) -> None:
+        """Add observations bucketed by the caller: ``counts[i]`` values
+        at or under bucket ``i``'s edge and above the one before (the
+        last entry: above every edge), summing to ``total`` -- the same
+        state as observing each value."""
+        for i, c in enumerate(counts):
+            self.counts[i] += int(c)
+        self.count += int(sum(counts))
+        self.sum += total
+
     def percentile(self, q: float) -> float:
         """Approximate percentile: upper edge of the bucket holding the
         q-quantile observation (``inf`` if it lands in the overflow

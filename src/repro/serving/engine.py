@@ -147,7 +147,27 @@ def prefill_step(params, batch: dict, caches, cfg: ModelConfig,
     return logits, caches
 
 
-@partial(jax.jit, static_argnames=("cfg", "quant", "moe_stats"))
+@partial(jax.jit, static_argnames=("cfg", "quant"))
+def bucketed_prefill_program(params, batch: dict, caches, cfg: ModelConfig,
+                             quant: Optional[QuantConfig] = None):
+    """The compiled program of :func:`prefill_step_bucketed`:
+    ``(logits, caches, moe)``, ``moe`` the per-MoE-layer telemetry
+    (``None`` for a stack without MoE layers)."""
+    out = M.forward(
+        params, batch["tokens"], cfg,
+        positions=batch.get("positions"),
+        patch_embeds=batch.get("patch_embeds"),
+        frames=batch.get("frames"),
+        caches=caches, quant=quant, remat=False, logits_mode="none",
+        collect_moe_stats=True)
+    x, caches = out[0], out[1]
+    idx = batch["last_idx"].astype(jnp.int32)           # (B,)
+    xl = jnp.take_along_axis(
+        x, idx[:, None, None].astype(jnp.int32), axis=1)  # (B, 1, d)
+    logits = M._logits(params, xl, cfg, quant)
+    return logits[:, 0], caches, out[3]
+
+
 def prefill_step_bucketed(params, batch: dict, caches, cfg: ModelConfig,
                           quant: Optional[QuantConfig] = None,
                           moe_stats: bool = False):
@@ -156,27 +176,30 @@ def prefill_step_bucketed(params, batch: dict, caches, cfg: ModelConfig,
     at ``batch["last_idx"]`` (B,) -- the last *real* token -- instead of
     the last padded position.  Jits once per bucket, not per length.
 
-    ``moe_stats=True`` (static) appends the per-MoE-layer capacity
-    telemetry dict (:func:`repro.models.model.forward`) to the return.
+    ``moe_stats=True`` appends the per-MoE-layer telemetry dict
+    (:func:`repro.models.model.forward`) to the return.  It is computed
+    either way, so a step with metrics on runs the same compiled
+    program (:func:`bucketed_prefill_program`) as one with them off.
     """
+    logits, caches, moe = bucketed_prefill_program(params, batch, caches,
+                                                   cfg, quant)
+    return (logits, caches, moe) if moe_stats else (logits, caches)
+
+
+@partial(jax.jit, static_argnames=("cfg", "quant"))
+def serve_program(params, batch: dict, caches, cfg: ModelConfig,
+                  quant: Optional[QuantConfig] = None):
+    """The compiled program of :func:`serve_step`: ``(logits, caches,
+    moe)``, ``moe`` the per-MoE-layer telemetry (``None`` for a stack
+    without MoE layers)."""
     out = M.forward(
         params, batch["tokens"], cfg,
-        positions=batch.get("positions"),
-        patch_embeds=batch.get("patch_embeds"),
-        frames=batch.get("frames"),
-        caches=caches, quant=quant, remat=False, logits_mode="none",
-        collect_moe_stats=moe_stats)
-    x, caches = out[0], out[1]
-    idx = batch["last_idx"].astype(jnp.int32)           # (B,)
-    xl = jnp.take_along_axis(
-        x, idx[:, None, None].astype(jnp.int32), axis=1)  # (B, 1, d)
-    logits = M._logits(params, xl, cfg, quant)
-    if moe_stats:
-        return logits[:, 0], caches, out[3]
-    return logits[:, 0], caches
+        positions=batch["positions"],
+        caches=caches, quant=quant, remat=False, logits_mode="last",
+        collect_moe_stats=True)
+    return out[0], out[1], out[3]
 
 
-@partial(jax.jit, static_argnames=("cfg", "quant", "moe_stats"))
 def serve_step(params, batch: dict, caches, cfg: ModelConfig,
                quant: Optional[QuantConfig] = None,
                moe_stats: bool = False):
@@ -184,16 +207,12 @@ def serve_step(params, batch: dict, caches, cfg: ModelConfig,
 
     ``batch``: tokens (B, 1), positions (B, 1) (or (3, B, 1) M-RoPE).
     Returns ``(logits (B, V), caches)`` -- plus the per-MoE-layer
-    capacity telemetry dict when ``moe_stats=True`` (static).
+    telemetry dict when ``moe_stats=True``, computed either way, so
+    metrics on and off run one compiled program
+    (:func:`serve_program`).
     """
-    out = M.forward(
-        params, batch["tokens"], cfg,
-        positions=batch["positions"],
-        caches=caches, quant=quant, remat=False, logits_mode="last",
-        collect_moe_stats=moe_stats)
-    if moe_stats:
-        return out[0], out[1], out[3]
-    return out[0], out[1]
+    logits, caches, moe = serve_program(params, batch, caches, cfg, quant)
+    return (logits, caches, moe) if moe_stats else (logits, caches)
 
 
 def kv_cache_bytes(caches, *, payload_only: bool = False) -> int:
@@ -496,10 +515,9 @@ class Engine:
                 f"metrics: expected None/bool/MetricsRegistry/"
                 f"ServingObs, got {type(metrics).__name__}")
         self._deadlines = False     # fast-path: no deadline submitted yet
-        # MoE capacity telemetry: only worth a distinct jit specialization
-        # (and host transfers) when observability is on AND the stack has
-        # MoE layers; with NULL_OBS the steps compile without the stats
-        # outputs and the hot path is untouched
+        # MoE telemetry: the steps compute it either way (one compiled
+        # program); it is read back to the host only when observability
+        # is on AND the stack has MoE layers
         self._moe_telemetry = bool(
             self.obs.enabled
             and any(cfg.ffn_kind(i) == "moe" for i in range(cfg.n_layers)))
@@ -1115,16 +1133,27 @@ class Engine:
         caches = self.pool.step_caches(
             tables, np.asarray([start], np.int32), slots=slots)
         quant = self._quant_for(getattr(seq, "precision", None))
+        out = prefill_step_bucketed(self.params, batch, caches, self.cfg,
+                                    quant, moe_stats=self._moe_telemetry)
+        logits, caches = out[0], out[1]
         if self._moe_telemetry:
-            logits, caches, mst = prefill_step_bucketed(
-                self.params, batch, caches, self.cfg, quant,
-                moe_stats=True)
-            self.obs.on_moe(mst)
-        else:
-            logits, caches = prefill_step_bucketed(
-                self.params, batch, caches, self.cfg, quant)
+            self._report_moe(out[2])
         self.pool.absorb(caches)
         return logits
+
+    def _report_moe(self, stats) -> None:
+        """Hand one dispatch's MoE telemetry (``moe_stats=True``) to the
+        hooks -- the host transfer, then ``on_moe`` and ``on_moe_rows`` --
+        under the span ``engine.moe_stats``; the dispatches call it once
+        their logits are fetched, so the span holds no wait for the
+        device."""
+        obs = self.obs
+        with obs.span("engine.moe_stats"):
+            stats = jax.device_get(stats)
+            obs.on_moe(stats)
+            obs.on_moe_rows(live=int(stats["load"].sum()),
+                            computed=int(stats["computed"].sum()),
+                            touched=int(stats["touched"].sum()))
 
     def _attn_body(self, sq: int) -> str:
         """The paged attention body a dispatch of ``sq`` query tokens a
@@ -1302,17 +1331,15 @@ class Engine:
                     slots=slot_ids if self.pool.slots is not None else None)
             quant = self._quant_for(running[0].precision)
         with obs.span("engine.dispatch"):
-            if self._moe_telemetry:
-                logits, caches, mst = serve_step(self.params, batch, caches,
-                                                 self.cfg, quant,
-                                                 moe_stats=True)
-                obs.on_moe(mst)
-            else:
-                logits, caches = serve_step(self.params, batch, caches,
-                                            self.cfg, quant)
+            out = serve_step(self.params, batch, caches, self.cfg, quant,
+                             moe_stats=self._moe_telemetry)
+            logits, caches = out[0], out[1]
             self.pool.absorb(caches)
         with obs.span("engine.fetch", bytes=logits.nbytes):
-            return np.asarray(logits, np.float32)
+            logits = np.asarray(logits, np.float32)
+        if self._moe_telemetry:
+            self._report_moe(out[2])
+        return logits
 
     def _fused_forward(self, plan) -> list:
         """Fused decode + chunk-prefill forward, grouped per served
@@ -1381,17 +1408,15 @@ class Engine:
                                                block_offsets=offsets)
             quant = self._quant_for(plan[0][0].precision)
         with obs.span("engine.dispatch"):
-            if self._moe_telemetry:
-                logits, caches, mst = prefill_step_bucketed(
-                    self.params, batch, caches, self.cfg, quant,
-                    moe_stats=True)
-                obs.on_moe(mst)
-            else:
-                logits, caches = prefill_step_bucketed(
-                    self.params, batch, caches, self.cfg, quant)
+            out = prefill_step_bucketed(self.params, batch, caches,
+                                        self.cfg, quant,
+                                        moe_stats=self._moe_telemetry)
+            logits, caches = out[0], out[1]
             self.pool.absorb(caches)
         with obs.span("engine.fetch", bytes=logits.nbytes):
             logits = np.asarray(logits, np.float32)
+        if self._moe_telemetry:
+            self._report_moe(out[2])
         return [logits[i] for i in range(len(plan))]
 
     def _advance(self, plan, rows, t_fwd0: float = 0.0,

@@ -221,3 +221,59 @@ def test_single_group_matches_multi_group_live_rows():
     for e in range(E):
         n_live = int(live[e].sum())
         np.testing.assert_array_equal(y1[e, :n_live], y2[e][live[e]])
+
+
+# expert tiles of the dropless dispatch: 8 rows each; expert 2 holds two
+# tiles, expert 1 one partial tile, a dead tile sits between live ones
+# and the last two tiles are the layout's unused tail
+TILE_ROWS = [8, 3, 0, 5, 1, 0, 0]
+TILE_EXPERT = [2, 2, 0, 1, 0, 2, 2]
+
+
+def _tile_oracle(w, x, a_bits, w2=None):
+    """Each row through its tile's expert on the legacy batched path."""
+    q = types.SimpleNamespace(a_bits=a_bits)
+    t, bc = len(TILE_ROWS), x.shape[0] // len(TILE_ROWS)
+    rows = []
+    for i, e in enumerate(TILE_EXPERT):
+        xe = jnp.broadcast_to(x[i * bc:(i + 1) * bc][None],
+                              (E, bc, x.shape[1]))
+        y = (_legacy_dual(w, w2, xe, a_bits) if w2 is not None
+             else L._expert_matmul(w, xe, q))
+        rows.append(np.asarray(y)[e])
+    return np.concatenate(rows)
+
+
+def _tile_live():
+    return np.concatenate([np.arange(8) < n for n in TILE_ROWS])
+
+
+@pytest.mark.parametrize("impl", ["reference", "interpret"])
+@pytest.mark.parametrize("dual", [False, True], ids=["single", "dual"])
+def test_expert_tiles_match_legacy_and_skip_dead_tiles(impl, dual):
+    a_bits, w_bits = 8, 2
+    w, w2 = _weights(w_bits, seed=21), _weights(w_bits, seed=22)
+    x = jnp.asarray(RNG.standard_normal((8 * len(TILE_ROWS), K)),
+                    jnp.bfloat16)
+    y, live = ops.ap_moe_tiles_linear(
+        x, w, w2=w2 if dual else None, act="silu" if dual else "none",
+        tile_rows=jnp.asarray(TILE_ROWS), tile_expert=jnp.asarray(
+            TILE_EXPERT), a_bits=a_bits, impl=impl, with_stats=True)
+    y = np.asarray(y)
+    rows = _tile_live()
+    oracle = _tile_oracle(w, x, a_bits, w2 if dual else None)
+    np.testing.assert_array_equal(y[rows], oracle[rows])
+    assert not y[~rows].any(), "rows past a tile's count must be zeros"
+    np.testing.assert_array_equal(np.asarray(live),
+                                  np.asarray(TILE_ROWS) > 0)
+
+
+def test_col_block_fits_whole_expert_rows_where_it_can():
+    from repro.kernels import moe as moe_kernel
+    # DeepSeek-MoE widths at 2 bits: one block holds a whole expert row
+    assert moe_kernel.col_block(1408, 64, 2, dual=True) == 1408
+    assert moe_kernel.col_block(2048, 44, 2, dual=False) == 2048
+    # Mixtral's 14336-wide experts at 8 bits split into column blocks
+    bn = moe_kernel.col_block(14336, 128, 8, dual=True)
+    assert 14336 % bn == 0 and bn % 128 == 0 and bn < 14336
+    assert bn * 8 * 128 * 4 * 2 * 2 <= moe_kernel.WEIGHT_BLOCK_BYTES

@@ -148,10 +148,95 @@ def test_paged_decode_step_fits_beside_its_pool_on_v5e(one_chip, batch):
     tok = jax.ShapeDtypeStruct((batch, 1), jnp.int32)
     ops.set_impl("pallas")
     try:
-        compiled = E.serve_step.lower(
+        compiled = E.serve_program.lower(
             sds(params), sds({"tokens": tok, "positions": tok}),
             sds(caches), cfg, quant).compile()
     finally:
         ops.set_impl(None)
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+
+
+# DeepSeek-MoE-16B: 64 routed experts of width 1408, top 6, over d 2048
+MOE_E, MOE_K, MOE_D, MOE_F = 64, 6, 2048, 1408
+MOE_LINEARS = {"gate_up": (MOE_D, MOE_F, True), "down": (MOE_F, MOE_D, False)}
+
+
+@pytest.mark.parametrize("tokens", [16, 256], ids=["decode", "chunk256"])
+@pytest.mark.parametrize("linear", sorted(MOE_LINEARS))
+def test_grouped_moe_kernel_compiles_for_v5e(one_chip, linear, tokens):
+    """The grouped expert kernel over the dropless dispatch's expert
+    tiles of one step, 2-bit experts, as the serving call makes it (no
+    live map)."""
+    from repro.models.layers import moe_tile_rows
+    k, n, dual = MOE_LINEARS[linear]
+    bc = moe_tile_rows(tokens, MOE_K, MOE_E)
+    n_t = min(tokens * MOE_K, -(-(tokens * MOE_K + MOE_E * (bc - 1)) // bc))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(x, wp, ws, rows, experts):
+        w = BipolarTensor(packed=wp, scale=ws, n_bits=2,
+                          shape=(MOE_E, n, k), pack_axis=2)
+        return ops.ap_moe_tiles_linear(
+            x, w, w2=w if dual else None, act="silu" if dual else "none",
+            tile_rows=rows, tile_expert=experts, a_bits=8, impl="pallas")
+
+    text = _compile(
+        fn, sds((n_t * bc, k), jnp.bfloat16),
+        sds((2, MOE_E, n, bipolar.packed_words(k)), jnp.uint32),
+        sds((MOE_E, n, 1), jnp.float32), sds((n_t,), jnp.int32),
+        sds((n_t,), jnp.int32))
+    assert "moe_expert_linear" in text
+
+
+def test_moe_decode_step_fits_beside_its_pool_on_v5e(one_chip):
+    """DeepSeek-MoE-16B's decode step at published widths, depth cut to
+    its dense layer and one expert layer, 16 lanes over the decode-heavy
+    pool: it compiles with the grouped kernel, and its temporaries --
+    one expert layer's working set, whatever the depth -- stay under
+    the pool the whole 28-layer model holds."""
+    import dataclasses
+    import numpy as np
+    from repro.configs import get_config
+    from repro.models import model as M
+    from repro.models.config import QuantConfig
+    from repro.serving import engine as E
+
+    cfg = dataclasses.replace(get_config("deepseek-moe-16b"), n_layers=2)
+    quant = QuantConfig(w_bits=2, a_bits=8, kv_bits=KV_BITS)
+    n_blocks, table, batch = 1281, 64, 16
+
+    def sds(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = jax.eval_shape(lambda k: M.quantize_params(
+        M.init_params(cfg, k), quant), jax.random.PRNGKey(0))
+    pool = jax.eval_shape(lambda: M.init_caches(
+        cfg, n_blocks, BLOCK_SIZE, quant=quant, paged=True))
+    pool_bytes = sum(np.prod(x.shape) * x.dtype.itemsize
+                     for x in jax.tree.leaves(pool))
+
+    def step_cache(c, lead):
+        return dict(c, block_tables=jax.ShapeDtypeStruct(
+                        lead + (batch, table), jnp.int32),
+                    length=jax.ShapeDtypeStruct(lead + (batch,), jnp.int32),
+                    block_offset=jax.ShapeDtypeStruct(lead + (batch,),
+                                                      jnp.int32))
+
+    caches = {"prelude": [step_cache(c, ()) for c in pool["prelude"]],
+              "blocks": [step_cache(c, (c["k"].shape[0],))
+                         for c in pool["blocks"]]}
+    tok = jax.ShapeDtypeStruct((batch, 1), jnp.int32)
+    ops.set_impl("pallas")
+    try:
+        compiled = E.serve_program.lower(
+            sds(params), sds({"tokens": tok, "positions": tok}),
+            sds(caches), cfg, quant).compile()
+    finally:
+        ops.set_impl(None)
+    assert "moe_expert_linear" in compiled.as_text()
+    full_pool = pool_bytes * 28 // 2
+    assert compiled.memory_analysis().temp_size_in_bytes < full_pool

@@ -133,3 +133,77 @@ def test_legacy_fallback_unquantized_params():
     params = M.init_params(cfg, jax.random.PRNGKey(1))
     out, _ = _decode(params, cfg, qcfg, _prompts(cfg))
     assert all(len(o) == 5 for o in out)
+
+
+def _moe_layer(name="deepseek-moe-16b", **over):
+    cfg = dataclasses.replace(get_config(name).reduced(n_layers=2), **over)
+    qcfg = dataclasses.replace(cfg.quant, kv_bits=8)
+    p = M.quantize_params(L.moe_init(jax.random.PRNGKey(3), cfg), qcfg)
+    return cfg, qcfg, p
+
+
+def test_serving_dispatch_is_dropless_and_skips_pad_rows():
+    """A fused step's rows: lane 0 is a chunk of 8 real tokens, lanes 1-3
+    decode lanes holding one real token ahead of 7 pads (position -1).
+    With ``live`` given, every real token reaches its top-k experts,
+    pads reach none and add no load, and the real rows' outputs are
+    those of the real tokens dispatched alone."""
+    cfg, qcfg, p = _moe_layer(capacity_factor=0.25)
+    e, k = cfg.n_experts, cfg.top_k
+    x = jnp.asarray(np.random.default_rng(1).standard_normal(
+        (4, 8, cfg.d_model)), jnp.bfloat16)
+    live = np.zeros((4, 8), bool)
+    live[0] = True
+    live[1:, 0] = True
+    y, _, st = L.moe_apply(p, x, cfg, quant=qcfg, live=jnp.asarray(live))
+    n_live = int(live.sum())
+    assert int(st["dropped"]) == 0
+    assert int(st["load"].sum()) == k * n_live
+    assert 0 < int(st["touched"]) <= e
+    assert k * n_live <= int(st["computed"]) <= int(st["capacity"])
+    # the real tokens alone, one lane, all live
+    xr = x[jnp.asarray(live)][None]
+    yr, _, str_ = L.moe_apply(p, xr, cfg, quant=qcfg,
+                              live=jnp.ones(xr.shape[:2], bool))
+    np.testing.assert_array_equal(np.asarray(y[jnp.asarray(live)]),
+                                  np.asarray(yr[0]))
+    np.testing.assert_array_equal(np.asarray(st["load"]),
+                                  np.asarray(str_["load"]))
+    # the capacity dispatch of the same step drops at this factor
+    _, _, capped = L.moe_apply(p, x, cfg, quant=qcfg)
+    assert int(capped["dropped"]) > 0
+
+
+def test_dropless_rows_match_the_capacity_dispatch_where_it_drops_none():
+    """Where capacity holds every assignment, both dispatches compute the
+    same rows: same routing, same per-row quantization, same combine."""
+    for name in MOE_SMOKE:
+        cfg, qcfg, p = _moe_layer(name, capacity_factor=16.0)
+        x = jnp.asarray(np.random.default_rng(2).standard_normal(
+            (3, 5, cfg.d_model)), jnp.bfloat16)
+        y_cap, _, st_cap = L.moe_apply(p, x, cfg, quant=qcfg)
+        y, _, st = L.moe_apply(p, x, cfg, quant=qcfg,
+                               live=jnp.ones((3, 5), bool))
+        assert int(st_cap["dropped"]) == int(st["dropped"]) == 0
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(y_cap))
+        np.testing.assert_array_equal(np.asarray(st["load"]),
+                                      np.asarray(st_cap["load"]))
+
+
+def test_moe_counters_count_live_assignments_and_kernel_rows():
+    """``repro_moe_assignments_total{kind=live}`` is every token the
+    model was fed, times top-k, times the MoE layers -- pads never
+    count -- and the kernel computes at least those rows."""
+    cfg, qcfg, params = _setup("deepseek-moe-16b")
+    prompts = _prompts(cfg)
+    outs, eng = _decode(params, cfg, qcfg, prompts, metrics=True,
+                        chunk_tokens=4)
+    snap = eng.obs.registry.snapshot()
+    n_moe = cfg.n_layers - cfg.first_dense
+    fed = sum(len(p) + len(o) - 1 for p, o in zip(prompts, outs))
+    live = snap["repro_moe_assignments_total{kind=\"live\"}"]
+    assert live == n_moe * cfg.top_k * fed
+    assert snap["repro_moe_assignments_total{kind=\"computed\"}"] >= live
+    touched = snap["repro_moe_experts_touched_total"]
+    assert 0 < touched <= n_moe * cfg.n_experts * eng.steps
+    assert snap.get("repro_moe_dropped_tokens_total", 0.0) == 0.0

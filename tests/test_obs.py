@@ -120,6 +120,33 @@ def test_snapshot_flattens_all_kinds():
     assert snap["repro_test_h_count"] == 1
 
 
+@pytest.mark.parametrize("seed", [0, 1])
+def test_on_moe_is_one_vectorized_update_with_the_same_snapshot(
+        seed, monkeypatch):
+    """``on_moe`` takes a forward's ``(L_moe, E)`` expert loads in one
+    histogram update, observing no value alone; the registry renders
+    and snapshots exactly as if each load had been observed alone."""
+    rng = np.random.default_rng(seed)
+    load = rng.integers(0, 1500, (27, 64)).astype(np.int32)
+    load[0, :6] = [0, 1, 2, 1024, 1025, 7]       # edges and the overflow
+    stats = {"load": load, "dropped": np.zeros(27, np.int32),
+             "capacity": np.full(27, 64 * 1024, np.int32)}
+    want = ServingObs()
+    for v in load.reshape(-1):
+        want._h_moe_load.observe(float(v))
+    want._g_moe_util.set(float(load.sum()) / (27 * 64 * 1024))
+
+    calls = []
+    observe = Histogram.observe
+    monkeypatch.setattr(Histogram, "observe", lambda self, v: (
+        calls.append(v), observe(self, v)))
+    got = ServingObs()
+    got.on_moe(stats)
+    assert calls == []
+    assert got.registry.render() == want.registry.render()
+    assert got.registry.snapshot() == want.registry.snapshot()
+
+
 # ---------------------------------------------------------------------------
 # Span discipline and trace validation
 # ---------------------------------------------------------------------------

@@ -188,6 +188,36 @@ def test_body_counter_splits_decode_from_fused_dispatches(gqa_model,
     assert eng.obs.profiled.entries_decode_body == walked["decode"]
 
 
+@pytest.fixture(scope="module")
+def moe_model():
+    """DeepSeek-MoE's block at toy sizes: a dense layer, then experts."""
+    from repro.models import model as M
+    cfg = get_config("deepseek-moe-16b").reduced(n_layers=3, vocab=256)
+    return cfg, M.init_params(cfg, jax.random.PRNGKey(3))
+
+
+def test_moe_stats_span_and_profiled_totals(moe_model, tmp_path):
+    """Each dispatch of a MoE stack hands its telemetry to the hooks in
+    one ``engine.moe_stats`` span, inside its step and after its fetch;
+    while the trace records, ``profiled`` keeps the same totals as the
+    registry's MoE counters."""
+    _, eng, spans = _traced_run(moe_model, True, tmp_path)
+    steps = [s for s in spans if s[0] == "engine.step"]
+    fetches = [s for s in spans if s[0] == "engine.fetch"]
+    moe = [s for s in spans if s[0] == "engine.moe_stats"]
+    assert len(moe) == len(fetches) > 0
+    for m, f in zip(moe, fetches):
+        assert f[2] <= m[1]
+        assert any(_inside(m, st) for st in steps)
+    reg, prof = eng.obs.registry, eng.obs.profiled
+    assert prof.spans["engine.moe_stats"][0] == len(moe)
+    assert prof.moe_live == reg.value("repro_moe_assignments",
+                                      kind="live") > 0
+    assert prof.moe_computed == reg.value("repro_moe_assignments",
+                                          kind="computed") >= prof.moe_live
+    assert prof.moe_touched == reg.value("repro_moe_experts_touched") > 0
+
+
 def test_profiled_totals_count_only_while_a_trace_records(model):
     tables: list = []
     _, eng, _ = _traced_run(model, True, None, tables=tables)

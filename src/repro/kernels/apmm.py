@@ -96,6 +96,7 @@ DEFAULT_BN = 256
 VMEM_LIMIT_BYTES = 100 * 2**20
 
 _NT = (((1,), (1,)), ((), ()))  # contract last dims (A @ B^T)
+_NN = (((1,), (0,)), ((), ()))  # B arrives (K, N): plain A @ B
 
 
 def _bits(words, b):
@@ -136,14 +137,17 @@ def _recover_int8(planes, lo: int, size: int):
     return (acc - bipolar.max_value(size)).astype(jnp.int8)
 
 
-def _accumulate(a_u, bpl, acc_ref, *, n_a: int, n_b: int, variant: str):
+def _accumulate(a_u, bpl, acc_ref, *, n_a: int, n_b: int, variant: str,
+                dims=_NT):
     """Add one bit position's products to the accumulator(s).
 
     ``a_u`` is the activations' unsigned bit field ``(bm, Kw)`` (one
     integer per K element of the subset), ``bpl`` the weight planes
-    ``(n_b, bn, Kw)`` in {0,1}.  ``fused``: operand-level recovery, one
-    int8 MXU GEMM per <=7-bit plane-group pair.  ``bitserial``: one GEMM
-    per bit pair into its own accumulator (paper §3.2/§4.2)."""
+    ``(n_b, bn, Kw)`` in {0,1} -- or ``(n_b, Kw, bn)`` with ``dims=_NN``
+    when the kernel reads its weights with N on the lanes.  ``fused``:
+    operand-level recovery, one int8 MXU GEMM per <=7-bit plane-group
+    pair.  ``bitserial``: one GEMM per bit pair into its own accumulator
+    (paper §3.2/§4.2)."""
     if variant == "fused":
         for lo_a, sz_a in ref.plane_groups(n_a):
             mask = (1 << sz_a) - 1
@@ -152,7 +156,7 @@ def _accumulate(a_u, bpl, acc_ref, *, n_a: int, n_b: int, variant: str):
             for lo_b, sz_b in ref.plane_groups(n_b):
                 b8 = _recover_int8(bpl, lo_b, sz_b)
                 y = jax.lax.dot_general(
-                    va, b8, _NT, preferred_element_type=jnp.int32)
+                    va, b8, dims, preferred_element_type=jnp.int32)
                 acc_ref[...] += y << (lo_a + lo_b)
     else:
         for i in range(n_a):                        # §4.2 ④ loop order:
@@ -160,7 +164,7 @@ def _accumulate(a_u, bpl, acc_ref, *, n_a: int, n_b: int, variant: str):
             for j in range(n_b):                    # ... x all B planes
                 b8 = (2 * bpl[j] - 1).astype(jnp.int8)
                 acc_ref[i * n_b + j] += jax.lax.dot_general(
-                    a8, b8, _NT, preferred_element_type=jnp.int32)
+                    a8, b8, dims, preferred_element_type=jnp.int32)
 
 
 def _init_acc(n_a: int, n_b: int, n_pad: int, variant: str, bm: int,

@@ -43,6 +43,23 @@ load-bearing choice: a native-bf16 chain changes bits under XLA's
 excess-precision convert elision depending on the surrounding jit
 graph.  Rows at or beyond a tile's live count are exactly zero.
 
+The expert planes are read in the order the TPU stores them.  The
+stored array is ``(n_bits, E, N, Kw)``, but XLA's default TPU layout puts
+``Kw`` on the 128 lanes only where it fills them (``Kw % 128 == 0``);
+otherwise N takes the lanes, and ``Kw`` -- or E, where ``Kw`` does not
+fill the 8 sublanes -- takes the sublanes.  ``ops`` hands the kernel a
+view whose row-major order is that stored order (``layout``: the axes
+after the plane axis, ``"ENK"``, ``"EKN"`` or ``"KEN"``), which XLA
+compiles to a bitcast: a block a step reads is a slice of the array as
+it lies in HBM, and no call relays the planes out.  With N on the lanes
+the body recovers ``(n_b, Kw, bn)`` planes on full lanes, and each MXU
+pass is a plain ``(bc, per * Kw) @ (per * Kw, bn)`` over ``per``
+consecutive bit positions stacked along K (:func:`_bits_per_pass`).
+With E on the sublanes a DMA cannot pick one expert's sublane, so a
+block holds a group of 8 experts (fetched once for the consecutive
+tiles of its experts) and each tile copies its expert's planes out of
+it once.
+
 Like the dense APMM kernels, this kernel is width-agnostic: nested-
 precision serving slices the ``(n_bits, E, N, Kw)`` packed expert
 weights to their leading ``k`` planes in ``ops`` (``w_bits=k``), so the
@@ -64,9 +81,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import bipolar
 from repro.kernels import ref
-from repro.kernels.apmm import (VMEM_LIMIT_BYTES, _accumulate, _bit_major,
-                                _bits, _init_acc, _quantize_tile,
-                                _recover_acc, _word_base)
+from repro.kernels.apmm import (_NN, _NT, VMEM_LIMIT_BYTES, _accumulate,
+                                _bit_major, _bits, _init_acc,
+                                _quantize_tile, _recover_acc, _word_base)
 
 # double-buffered weight blocks of one launch (both operands in dual
 # mode) stay under this, so a whole expert row block fits at DeepSeek-
@@ -74,20 +91,57 @@ from repro.kernels.apmm import (VMEM_LIMIT_BYTES, _accumulate, _bit_major,
 WEIGHT_BLOCK_BYTES = 32 * 2**20
 
 
-def col_block(n: int, kw: int, n_b: int, dual: bool) -> int:
+# the orders an expert plane array may be read in: the axes after the
+# plane axis, the last on the lanes
+LAYOUTS = ("ENK", "EKN", "KEN")
+_E_GROUP = 8                              # experts of one sublane tile
+MXU_DEPTH = 128                           # K rows one MXU pass contracts
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def col_block(n: int, kw: int, n_b: int, dual: bool,
+              layout: str = "ENK") -> int:
     """Output columns per block for an ``N = n`` expert weight of ``kw``
     packed words a row: the widest multiple of 128 dividing ``n`` whose
-    double-buffered planes (lanes padded to 128) fit
-    ``WEIGHT_BLOCK_BYTES``; 128 when none does."""
-    per_col = n_b * (-(-kw // 128) * 128) * 4 * (1 + dual) * 2
+    double-buffered planes fit ``WEIGHT_BLOCK_BYTES``; 128 when none
+    does.  With ``Kw`` on the lanes (``"ENK"``) a column's planes take
+    ``Kw`` padded to 128 lanes.  With N on the lanes they take ``Kw``
+    padded to 8 sublanes (``"EKN"``), or ``Kw`` rows of a group of 8
+    experts and the tile's own ``Kw`` rows (``"KEN"``), and the body's
+    recovered int8 operand its rows padded to 32."""
+    if layout == "ENK":
+        per_col = n_b * _round_up(kw, 128) * 4 * (1 + dual) * 2
+    else:
+        rows = (2 * _round_up(kw, 8) if layout == "EKN"
+                else (2 * _E_GROUP + 1) * kw)
+        per_col = (n_b * rows * 4 + _round_up(
+            _bits_per_pass(kw, layout) * kw, 32)) * (1 + dual)
     fit = [b for b in range(128, n + 1, 128)
            if n % b == 0 and b * per_col <= WEIGHT_BLOCK_BYTES]
     return max(fit, default=128)
 
 
+def _bits_per_pass(kw: int, layout: str) -> int:
+    """Bit positions one MXU pass contracts.  With N on the lanes the
+    recovered planes of consecutive bit positions stack along the
+    sublanes into one ``(per * Kw, bn)`` operand of at most
+    ``MXU_DEPTH`` rows of K: fewer, fuller passes (on a v5e at
+    DeepSeek-MoE's widths, Kw 64 and 44, two bit positions a pass cut
+    the passes' time by about 30%).  Kw on the lanes keeps one a pass."""
+    per = 1
+    if layout != "ENK":
+        while per < bipolar.PACK_WIDTH and 2 * per * kw <= MXU_DEPTH:
+            per *= 2
+    return per
+
+
 def _moe_kernel(rows_ref, src_ref, exp_ref, *refs, n_a: int, n_b: int,
                 bc: int, bn: int, kw: int, k_orig: int, n_pad: int,
-                variant: str, act: str, dual: bool, stats: bool):
+                variant: str, act: str, dual: bool, stats: bool,
+                layout: str):
     it = iter(refs)
     x_ref, as_ref = next(it), next(it)
     wp_ref, ws_ref = next(it), next(it)
@@ -95,10 +149,14 @@ def _moe_kernel(rows_ref, src_ref, exp_ref, *refs, n_a: int, n_b: int,
     w2s_ref = next(it) if dual else None
     out_ref = next(it)
     live_ref = next(it) if stats else None
-    accs = list(it)                       # 1 or 2 scratch accumulators
+    accs = [next(it) for _ in range(1 + dual)]   # scratch accumulators
+    sels = list(it)              # "KEN": the tile's expert's planes
 
     cnt = rows_ref[pl.program_id(0)]      # live rows of this tile
     live = cnt > 0
+    # under "KEN" a block holds the planes of the 8 experts that share a
+    # sublane tile: this tile's expert is one sublane of each row
+    sub = exp_ref[pl.program_id(0)] % _E_GROUP if layout == "KEN" else None
     if stats:
         live_ref[pl.program_id(0)] = live.astype(jnp.int32)
 
@@ -107,22 +165,35 @@ def _moe_kernel(rows_ref, src_ref, exp_ref, *refs, n_a: int, n_b: int,
         init = _init_acc(n_a, n_b, n_pad, variant, bc, bn)
         for aref in accs:
             aref[...] = init
-        streams = [(wp_ref, accs[0])] \
-            + ([(wp2_ref, accs[1])] if dual else [])
+        wrefs = [wp_ref] + ([wp2_ref] if dual else [])
+        if layout == "KEN":
+            # pick the expert's sublane out of its group once per tile;
+            # the bit loop then reads dense (n_b, Kw, bn) planes
+            for wref, sref in zip(wrefs, sels):
+                sref[...] = wref[:, :, sub, :]
+            wrefs = sels
+        streams = list(zip(wrefs, accs))
         base = _word_base(bc, kw)
+        dims = _NT if layout == "ENK" else _NN
+        per = _bits_per_pass(kw, layout)
 
-        def bit_step(b, carry):
-            # prologue: quantize + bit-decompose this bit position's
+        def cat(parts):
+            return parts[0] if per == 1 else jnp.concatenate(parts, 1)
+
+        def bit_step(i, carry):
+            # prologue: quantize + bit-decompose these bit positions'
             # float columns in VMEM.  x_ref holds the tile's whole-K row
             # block, fetched once per tile
-            a_u = _quantize_tile(x_ref[b], as_ref[...], n_a, base + b,
-                                 k_orig)
-            for bref, aref in streams:
-                _accumulate(a_u, _bits(bref[:, 0], b), aref, n_a=n_a,
-                            n_b=n_b, variant=variant)
+            bs = [i * per + t for t in range(per)]
+            a_u = cat([_quantize_tile(x_ref[b], as_ref[...], n_a, base + b,
+                                      k_orig) for b in bs])
+            for wref, aref in streams:
+                w = wref[...]
+                _accumulate(a_u, cat([_bits(w, b) for b in bs]), aref,
+                            n_a=n_a, n_b=n_b, variant=variant, dims=dims)
             return carry
 
-        jax.lax.fori_loop(0, bipolar.PACK_WIDTH, bit_step, 0)
+        jax.lax.fori_loop(0, bipolar.PACK_WIDTH // per, bit_step, 0)
 
         # dequant + epilogue in f32 with ONE output-dtype cast -- the
         # same cast point as the legacy f32 composition in moe_apply
@@ -148,14 +219,15 @@ def _moe_kernel(rows_ref, src_ref, exp_ref, *refs, n_a: int, n_b: int,
 @functools.partial(
     jax.jit,
     static_argnames=("n_a", "n_b", "k_orig", "variant", "act", "block",
-                     "out_dtype", "stats", "interpret"))
+                     "layout", "out_dtype", "stats", "interpret"))
 def moe_expert_linear(x: jax.Array, a_scale: jax.Array,
                       tile_rows: jax.Array, tile_expert: jax.Array,
                       wp: jax.Array, w_scale: jax.Array, *, wp2=None,
                       w2_scale=None, n_a: int, n_b: int, k_orig: int,
                       variant: str = "fused", act: str = "none",
-                      block: tuple = (8, 128), out_dtype=jnp.bfloat16,
-                      stats: bool = False, interpret: bool = False):
+                      block: tuple = (8, 128), layout: str = "ENK",
+                      out_dtype=jnp.bfloat16, stats: bool = False,
+                      interpret: bool = False):
     """Grouped quantized expert GEMM over expert tiles, one launch.
 
     Args:
@@ -167,18 +239,23 @@ def moe_expert_linear(x: jax.Array, a_scale: jax.Array,
         ``bc`` rows); rows at or beyond it produce exact zeros, and a
         tile with none is skipped.
       tile_expert: ``(T,)`` int32 expert of each tile.
-      wp: ``(n_b, E, Np, Kw)`` uint32 packed expert weight planes.
+      wp: uint32 packed expert weight planes, ``(n_b, E, Np, Kw)``
+        under ``layout="ENK"``, ``(n_b, E, Kw, Np)`` under ``"EKN"`` and
+        ``(n_b, Kw, E, Np)`` under ``"KEN"``.
       w_scale: ``(E, 1, Np)`` f32 per-(expert, out-channel) scales.
       wp2/w2_scale: optional second expert weight (dual gate/up mode);
         the epilogue writes ``act(Y1) * Y2``.
       k_orig: unpadded reduction length (closed-form pad correction).
       block: ``(bc, bn)`` rows of a tile and output columns of a block.
+      layout: the order of ``wp``'s axes after the plane axis.
 
     Returns ``y (R, Np)`` in ``out_dtype``; with ``stats`` also the
     ``(T,)`` int32 map of tiles that did MXU work.
     """
     r, kp = x.shape
-    n_b_, _, n, kw = wp.shape
+    assert layout in LAYOUTS, layout
+    size = dict(zip("P" + layout, wp.shape))
+    n_b_, n, kw = size["P"], size["N"], size["K"]
     assert n_b_ == n_b and kp == kw * bipolar.PACK_WIDTH, (x.shape, wp.shape)
     bc, bn = block
     if r % bc or n % bn:
@@ -201,9 +278,19 @@ def moe_expert_linear(x: jax.Array, a_scale: jax.Array,
         return jnp.where(rows[i] > 0, j, n_j - 1)
 
     operands = [_bit_major(x), a_scale, wp, w_scale]
-    w_spec = pl.BlockSpec(
-        (n_b, 1, bn, kw),
-        lambda i, j, rows, src, ex: (0, ex[i], col(i, j, rows), 0))
+    # one expert's planes per block, squeezed to (n_b, bn, Kw) or, with N
+    # on the lanes, (n_b, Kw, bn); with E on the sublanes the expert's
+    # group of 8
+    w_block = {"E": _E_GROUP if layout == "KEN" else None, "N": bn,
+               "K": kw}
+
+    def w_index(i, j, rows, src, ex):
+        e = ex[i] // _E_GROUP if layout == "KEN" else ex[i]
+        at = {"E": e, "N": col(i, j, rows), "K": 0}
+        return (0,) + tuple(at[a] for a in layout)
+
+    w_spec = pl.BlockSpec((n_b,) + tuple(w_block[a] for a in layout),
+                          w_index)
     s_spec = pl.BlockSpec(
         (1, 1, bn), lambda i, j, rows, src, ex: (ex[i], 0, col(i, j, rows)))
     in_specs = [
@@ -225,14 +312,17 @@ def moe_expert_linear(x: jax.Array, a_scale: jax.Array,
     acc_shape = ((bc, bn) if variant == "fused" else (n_a * n_b, bc, bn))
     kernel = functools.partial(
         _moe_kernel, n_a=n_a, n_b=n_b, bc=bc, bn=bn, kw=kw, k_orig=k_orig,
-        n_pad=kp - k_orig, variant=variant, act=act, dual=dual, stats=stats)
+        n_pad=kp - k_orig, variant=variant, act=act, dual=dual, stats=stats,
+        layout=layout)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(n_t, n_j),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[pltpu.VMEM(acc_shape, jnp.int32)
-                        for _ in range(1 + dual)],
+                        for _ in range(1 + dual)]
+        + [pltpu.VMEM((n_b, kw, bn), jnp.uint32)
+           for _ in range((1 + dual) * (layout == "KEN"))],
     )
     # the live map is one SMEM array written by every step: keep the
     # grid on one core when it is asked for

@@ -448,6 +448,27 @@ def _moe_operand(x, a_bits: int):
                                    keepdims=True)
 
 
+def expert_plane_layout(e: int, n: int, kw: int) -> str:
+    """The order in which XLA's default TPU layout stores an ``(n_bits,
+    E, N, Kw)`` packed expert plane array, as the axes after the plane
+    axis, the last on the 128 lanes (:data:`moe_kernel.LAYOUTS`).  For
+    output widths N a multiple of 128: ``Kw`` keeps the lanes where it
+    fills them; otherwise N takes them, and ``Kw`` the 8 sublanes where
+    it fills those, else E where it does."""
+    if kw % 128 == 0:
+        return "ENK"
+    if kw % 8 and e % 8 == 0:
+        return "KEN"
+    return "EKN"
+
+
+def _expert_planes(wp: jax.Array, layout: str) -> jax.Array:
+    """``(n_b, E, N, Kw)`` planes viewed in ``layout``'s order: a bitcast
+    of the stored array wherever ``layout`` is the stored order."""
+    return jnp.transpose(wp, (0,) + tuple(1 + "ENK".index(a)
+                                          for a in layout))
+
+
 def ap_moe_tiles_linear(x: jax.Array, w: BipolarTensor, *,
                         tile_rows: jax.Array, tile_expert: jax.Array,
                         a_bits: int, w2: BipolarTensor | None = None,
@@ -498,23 +519,28 @@ def ap_moe_tiles_linear(x: jax.Array, w: BipolarTensor, *,
     # --- pad to tile multiples (kernel masks the K pad in-prologue) -----
     wp = w.packed
     w2p = w2.packed if w2 is not None else None
+    layout = expert_plane_layout(*wp.shape[1:])
     kw = max(bipolar.packed_words(k), wp.shape[-1],
              w2p.shape[-1] if w2p is not None else 0)
     np_ = _round_up(n, 128)
-    bn = moe_kernel.col_block(np_, kw, w.n_bits, w2 is not None)
+    bn = moe_kernel.col_block(np_, kw, w.n_bits, w2 is not None, layout)
+
+    def planes(p):
+        return _expert_planes(
+            _pad_dim(_pad_dim(p, 2, np_), 3, kw, 0xFFFFFFFF), layout)
+
     xp = _pad_dim(x, 1, kw * bipolar.PACK_WIDTH)
-    wp = _pad_dim(_pad_dim(wp, 2, np_), 3, kw, 0xFFFFFFFF)
     ws = _pad_dim(w.scale.reshape(e, 1, n).astype(jnp.float32), 2, np_, 1.0)
     kw_args: dict = {}
     if w2p is not None:
-        kw_args["wp2"] = _pad_dim(_pad_dim(w2p, 2, np_), 3, kw, 0xFFFFFFFF)
+        kw_args["wp2"] = planes(w2p)
         kw_args["w2_scale"] = _pad_dim(
             w2.scale.reshape(e, 1, n).astype(jnp.float32), 2, np_, 1.0)
     out = moe_kernel.moe_expert_linear(
-        xp, a_scale.astype(jnp.float32), tile_rows, tile_expert, wp, ws,
-        n_a=a_bits, n_b=w.n_bits, k_orig=k, variant=variant, act=act,
-        block=(bc, bn), out_dtype=out_dtype, stats=with_stats,
-        interpret=(impl == "interpret"), **kw_args)
+        xp, a_scale.astype(jnp.float32), tile_rows, tile_expert, planes(wp),
+        ws, n_a=a_bits, n_b=w.n_bits, k_orig=k, variant=variant, act=act,
+        block=(bc, bn), layout=layout, out_dtype=out_dtype,
+        stats=with_stats, interpret=(impl == "interpret"), **kw_args)
     if with_stats:
         return out[0][:, :n], out[1]
     return out[:, :n]

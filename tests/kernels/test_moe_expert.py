@@ -9,6 +9,7 @@ impl's analytic one -- the proof that ``pl.when`` actually skipped the
 empty capacity tiles rather than computing zeros the hard way.
 """
 
+import dataclasses
 import types
 
 import jax
@@ -16,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import bipolar
 from repro.kernels import ops
 from repro.models import layers as L
 from repro.models.config import QuantConfig
@@ -34,9 +36,9 @@ BIT_PAIRS = [(1, 1), (2, 2), (3, 4), (4, 4), (5, 6), (7, 7), (8, 3), (8, 8)]
 INTERP_BITS = [(1, 1), (3, 4), (8, 8)]
 
 
-def _weights(nb, *, seed, n=N, k=K):
+def _weights(nb, *, seed, n=N, k=K, e=E):
     w = np.asarray(
-        np.random.default_rng(seed).standard_normal((E, n, k)) / np.sqrt(k),
+        np.random.default_rng(seed).standard_normal((e, n, k)) / np.sqrt(k),
         np.float32)
     return _quantize_leaf(jnp.asarray(w), QuantConfig(w_bits=nb),
                           stacked=False)
@@ -228,44 +230,89 @@ def test_single_group_matches_multi_group_live_rows():
 # and the last two tiles are the layout's unused tail
 TILE_ROWS = [8, 3, 0, 5, 1, 0, 0]
 TILE_EXPERT = [2, 2, 0, 1, 0, 2, 2]
+# the same fills over tiles of 32 rows, as the fused chunk steps cut them
+TILE_ROWS_32 = [32, 13, 0, 21, 1, 0, 0]
+
+# tiles over 16 experts, two sublane groups of 8: experts 9 and 7 share
+# no group, expert 15 follows a dead tile, the tail is dead
+WIDE_TILE_EXPERT = [9, 9, 0, 7, 15, 15, 15]
 
 
-def _tile_oracle(w, x, a_bits, w2=None):
+def _tile_oracle(w, x, a_bits, tile_rows, tile_expert, w2=None):
     """Each row through its tile's expert on the legacy batched path."""
     q = types.SimpleNamespace(a_bits=a_bits)
-    t, bc = len(TILE_ROWS), x.shape[0] // len(TILE_ROWS)
+    bc = x.shape[0] // len(tile_rows)
     rows = []
-    for i, e in enumerate(TILE_EXPERT):
-        xe = jnp.broadcast_to(x[i * bc:(i + 1) * bc][None],
-                              (E, bc, x.shape[1]))
-        y = (_legacy_dual(w, w2, xe, a_bits) if w2 is not None
-             else L._expert_matmul(w, xe, q))
-        rows.append(np.asarray(y)[e])
+    for i, ex in enumerate(tile_expert):
+        we, xe = bipolar.take_experts(w, jnp.asarray([ex])), \
+            x[None, i * bc:(i + 1) * bc]
+        y = (_legacy_dual(we, bipolar.take_experts(w2, jnp.asarray([ex])),
+                          xe, a_bits) if w2 is not None
+             else L._expert_matmul(we, xe, q))
+        rows.append(np.asarray(y)[0])
     return np.concatenate(rows)
 
 
-def _tile_live():
-    return np.concatenate([np.arange(8) < n for n in TILE_ROWS])
+# (E, K, N, stored bits, served bits, tile rows) of the interpret-only
+# cases at published widths.  By ops.expert_plane_layout the kernel reads
+# DeepSeek-MoE's gate/up planes (Kw 64) with Kw on the sublanes, its down
+# planes (Kw 44, K padded 1400 -> 1408) with E on the sublanes, and Kw 128
+# on the lanes as stored
+WIDE_TILES = {
+    "gate_up-bc8": (16, 2048, 1408, 2, 2, 8),
+    "gate_up-bc32": (16, 2048, 1408, 2, 2, 32),
+    "gate_up-nested3to2": (16, 2048, 1408, 3, 2, 8),
+    "down-bc8": (16, 1400, 2048, 2, 2, 8),
+    "down-bc32": (16, 1400, 2048, 2, 2, 32),
+    "down-w3": (16, 1400, 2048, 3, 3, 8),
+    "down-nested3to2": (16, 1400, 2048, 3, 2, 32),
+    "kw128-lanes": (3, 4096, 128, 2, 2, 8),
+}
+TILE_CASES = (
+    [pytest.param(impl, dual, None, id=f"{d}-{impl}")
+     for d, dual in (("single", False), ("dual", True))
+     for impl in ("reference", "interpret")]
+    + [pytest.param("interpret", name.startswith("gate_up"), name,
+                    id=f"{name}-interpret") for name in WIDE_TILES])
 
 
-@pytest.mark.parametrize("impl", ["reference", "interpret"])
-@pytest.mark.parametrize("dual", [False, True], ids=["single", "dual"])
-def test_expert_tiles_match_legacy_and_skip_dead_tiles(impl, dual):
-    a_bits, w_bits = 8, 2
-    w, w2 = _weights(w_bits, seed=21), _weights(w_bits, seed=22)
-    x = jnp.asarray(RNG.standard_normal((8 * len(TILE_ROWS), K)),
+@pytest.mark.parametrize("impl,dual,wide", TILE_CASES)
+def test_expert_tiles_match_legacy_and_skip_dead_tiles(impl, dual, wide):
+    a_bits = 8
+    e, k, n, stored, served, bc = (wide and WIDE_TILES[wide]) \
+        or (E, K, N, 2, 2, 8)
+    tile_rows = TILE_ROWS if bc == 8 else TILE_ROWS_32
+    tile_expert = (TILE_EXPERT if e < 16 else WIDE_TILE_EXPERT)
+    if wide:
+        # absmax scales: the clip search is slow at these sizes, and the
+        # kernel reads the planes the same way whatever the scales
+        w, w2 = (ops.quantize_rows(jnp.asarray(
+            np.random.default_rng(seed).standard_normal((e * n, k)),
+            jnp.float32), stored, pad_bit=1, impl="reference")
+            for seed in (21, 22))
+        w, w2 = (dataclasses.replace(
+            v, packed=v.packed.reshape(stored, e, n, -1),
+            scale=v.scale.reshape(e, n, 1), shape=(e, n, k))
+            for v in (w, w2))
+    else:
+        w = _weights(stored, seed=21, n=n, k=k, e=e)
+        w2 = _weights(stored, seed=22, n=n, k=k, e=e)
+    x = jnp.asarray(RNG.standard_normal((bc * len(tile_rows), k)),
                     jnp.bfloat16)
     y, live = ops.ap_moe_tiles_linear(
         x, w, w2=w2 if dual else None, act="silu" if dual else "none",
-        tile_rows=jnp.asarray(TILE_ROWS), tile_expert=jnp.asarray(
-            TILE_EXPERT), a_bits=a_bits, impl=impl, with_stats=True)
+        tile_rows=jnp.asarray(tile_rows), tile_expert=jnp.asarray(
+            tile_expert), a_bits=a_bits, impl=impl, with_stats=True,
+        w_bits=None if served == stored else served)
     y = np.asarray(y)
-    rows = _tile_live()
-    oracle = _tile_oracle(w, x, a_bits, w2 if dual else None)
+    rows = np.concatenate([np.arange(bc) < r for r in tile_rows])
+    ws = [bipolar.nested_slice(v, served) for v in (w, w2)]
+    oracle = _tile_oracle(ws[0], x, a_bits, tile_rows, tile_expert,
+                          ws[1] if dual else None)
     np.testing.assert_array_equal(y[rows], oracle[rows])
     assert not y[~rows].any(), "rows past a tile's count must be zeros"
     np.testing.assert_array_equal(np.asarray(live),
-                                  np.asarray(TILE_ROWS) > 0)
+                                  np.asarray(tile_rows) > 0)
 
 
 def test_col_block_fits_whole_expert_rows_where_it_can():
@@ -277,3 +324,15 @@ def test_col_block_fits_whole_expert_rows_where_it_can():
     bn = moe_kernel.col_block(14336, 128, 8, dual=True)
     assert 14336 % bn == 0 and bn % 128 == 0 and bn < 14336
     assert bn * 8 * 128 * 4 * 2 * 2 <= moe_kernel.WEIGHT_BLOCK_BYTES
+    # read in their stored order, N on the lanes: DeepSeek-MoE's gate/up
+    # (Kw 64 on the sublanes) and down (Kw 44, E on the sublanes, a block
+    # holding 8 experts) still fit whole rows
+    assert moe_kernel.col_block(1408, 64, 2, True, "EKN") == 1408
+    assert moe_kernel.col_block(2048, 44, 2, False, "KEN") == 2048
+    # Kw is padded to 8 sublanes there, not 128 lanes: a Kw of 64 takes
+    # half the bytes a column, so the block is wider than on the lanes
+    bn_s = moe_kernel.col_block(14336, 64, 8, True, "EKN")
+    assert bn_s == 3584 > moe_kernel.col_block(14336, 64, 8, True) == 2048
+    assert bn_s * (8 * 64 * 4 * 2 + 128) * 2 <= moe_kernel.WEIGHT_BLOCK_BYTES
+    # a group of 8 experts a block: 44 words -> 8 x 44 rows, not 48
+    assert moe_kernel.col_block(2048, 44, 8, True, "KEN") == 512

@@ -12,6 +12,7 @@ worker imports every test file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -189,6 +190,89 @@ def test_grouped_moe_kernel_compiles_for_v5e(one_chip, linear, tokens):
         sds((MOE_E, n, 1), jnp.float32), sds((n_t,), jnp.int32),
         sds((n_t,), jnp.int32))
     assert "moe_expert_linear" in text
+
+
+# a relayout of packed planes: a u32 copy or transpose of four or more dims
+_PLANE_RELAYOUT = re.compile(r"= u32\[\d+(?:,\d+){3,}\]\S* (copy|transpose)\(")
+
+
+@pytest.mark.parametrize("tokens", [16, 256], ids=["decode", "fused"])
+def test_grouped_moe_reads_stored_expert_planes_on_v5e(one_chip, tokens):
+    """Two DeepSeek-MoE expert layers in a ``lax.scan`` over stacked
+    2-bit planes, gate/up then down, as the serving step runs them.  XLA
+    stores ``(2, 64, 1408, 64)`` planes with N on the lanes and Kw on the
+    sublanes, and ``(2, 64, 2048, 44)`` with E on the sublanes; the
+    kernel reads both in that order, so no call copies or transposes a
+    plane array (each was relaid out whole, Kw padded onto the lanes)."""
+    from repro.models.layers import moe_tile_rows
+    bc = moe_tile_rows(tokens, MOE_K, MOE_E)
+    n_t = min(tokens * MOE_K, -(-(tokens * MOE_K + MOE_E * (bc - 1)) // bc))
+    n_layers = 2
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def planes(n, k):
+        return sds((n_layers, 2, MOE_E, n, bipolar.packed_words(k)),
+                   jnp.uint32), sds((n_layers, MOE_E, n, 1), jnp.float32)
+
+    def fn(x, gate, up, down, rows, experts):
+        def layer(h, w):
+            (gp, gs), (up_, us), (dp, ds) = w
+            g, u, d = (BipolarTensor(packed=p, scale=sc, n_bits=2,
+                                     shape=(MOE_E, n, k), pack_axis=2)
+                       for p, sc, n, k in ((gp, gs, MOE_F, MOE_D),
+                                           (up_, us, MOE_F, MOE_D),
+                                           (dp, ds, MOE_D, MOE_F)))
+            y = ops.ap_moe_tiles_linear(
+                h, g, w2=u, act="silu", tile_rows=rows, tile_expert=experts,
+                a_bits=8, impl="pallas")
+            return ops.ap_moe_tiles_linear(
+                y, d, tile_rows=rows, tile_expert=experts, a_bits=8,
+                impl="pallas"), None
+        return jax.lax.scan(layer, x, (gate, up, down))[0]
+
+    text = _compile(
+        fn, sds((n_t * bc, MOE_D), jnp.bfloat16), planes(MOE_F, MOE_D),
+        planes(MOE_F, MOE_D), planes(MOE_D, MOE_F),
+        sds((n_t,), jnp.int32), sds((n_t,), jnp.int32))
+    assert text.count("moe_expert_linear") >= 2
+    assert not [m.group(0) for m in _PLANE_RELAYOUT.finditer(text)]
+
+
+def _moe_plane_shapes():
+    """``(config, matrix, (n_bits, E, N, Kw))`` of the packed expert
+    planes of every MoE configuration, at 2 and 8 bits."""
+    from repro.configs import ARCHS, get_config
+    out = []
+    for name in ARCHS:
+        cfg = get_config(name)
+        if not cfg.n_experts:
+            continue
+        for n_bits in (2, 8):
+            for mat, (n, k) in (("gate_up", (cfg.expert_d_ff, cfg.d_model)),
+                                ("down", (cfg.d_model, cfg.expert_d_ff))):
+                out.append((name, f"{mat}-w{n_bits}", (
+                    n_bits, cfg.n_experts, n, bipolar.packed_words(k))))
+    return out
+
+
+def test_expert_plane_layout_matches_xla_for_moe_configs(one_chip):
+    """``ops.expert_plane_layout`` names the order XLA's default TPU
+    layout stores each MoE configuration's expert planes in, so the
+    kernel's view is a bitcast; where ``Kw`` fills the lanes (Mixtral's
+    and Jamba's gate/up) the stored order, and the kernel's, stays
+    ``(E, N, Kw)``."""
+    seen = set()
+    for name, mat, shape in _moe_plane_shapes():
+        arr = jax.ShapeDtypeStruct(shape, jnp.uint32, sharding=one_chip)
+        compiled = jax.jit(lambda w: w[0, 0].sum()).lower(arr).compile()
+        order = tuple(compiled.input_formats[0][0].layout.major_to_minor)
+        layout = ops.expert_plane_layout(*shape[1:])
+        seen.add(layout)
+        assert order == (0,) + tuple(1 + "ENK".index(a) for a in layout), \
+            (name, mat, shape, order, layout)
+    assert seen == {"ENK", "EKN", "KEN"}
 
 
 def test_moe_decode_step_fits_beside_its_pool_on_v5e(one_chip):
